@@ -3,24 +3,29 @@
 The reference leaned on assembly inside Go libraries for its hot paths
 (klauspost/reedsolomon AVX2 GF(2^8), stdlib SSE4.2 CRC32C, asm MD5 —
 SURVEY.md §2.2). Here those CPU paths are C++ (`seaweedfs_tpu/native/src`),
-compiled on first use into `_seaweed_native.so` and exposed through ctypes.
-They serve as (a) the CPU fallback when no TPU is attached and (b) the
-baseline the TPU kernels are benchmarked against.
+compiled on first use into `_seaweed_native.<key>.so` and exposed through
+ctypes. The key names the sources' content and the machine the file was
+built on (`_build_key`), so a file built from other sources or for another
+CPU is never loaded: the library is built where it runs. They serve as (a)
+the CPU path when no TPU is attached and (b) the baseline the TPU kernels
+are measured against.
 
-If compilation fails (no toolchain), callers fall back to numpy paths —
-correctness is preserved, only throughput drops.
+If the build or the load fails, `lib` is None, the cause is logged and kept
+in `load_info`, and callers use the numpy paths — correctness is preserved,
+only throughput drops.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "src")
-_SO_PATH = os.path.join(_HERE, "_seaweed_native.so")
 
 _lock = threading.Lock()
 
@@ -441,42 +446,92 @@ class NativeLib:
         return out
 
 
-def _build() -> bool:
-    srcs = [os.path.join(_SRC, f) for f in sorted(os.listdir(_SRC)) if f.endswith(".cpp")]
+def _build_key() -> str:
+    """Names the one built file this checkout may load on this machine: a
+    digest of every source's name and content, the compiler's version and —
+    because the build uses -march=native — this CPU's feature flags."""
+    h = hashlib.sha256()
+    for name in _sources():
+        h.update(name.encode() + b"\0")
+        with open(os.path.join(_SRC, name), "rb") as f:
+            h.update(f.read())
+    try:
+        h.update(subprocess.run(
+            ["g++", "-dumpfullversion", "-dumpmachine"],
+            capture_output=True, timeout=30,
+        ).stdout)
+    except (OSError, subprocess.SubprocessError):
+        pass  # no compiler: the build below reports it
+    h.update(platform.machine().encode())
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    h.update(line.encode())
+                    break
+    except OSError:
+        pass
+    return h.hexdigest()[:16]
+
+
+def _sources() -> list[str]:
+    return sorted(f for f in os.listdir(_SRC) if f.endswith(".cpp"))
+
+
+def _build(so_path: str) -> str | None:
+    """Compile every source into so_path (written under a temporary name,
+    renamed into place). Returns None, or why it failed."""
+    tmp = f"{so_path}.{os.getpid()}.tmp"
     cmd = [
         "g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-        "-o", _SO_PATH, *srcs,
+        "-o", tmp, *(os.path.join(_SRC, f) for f in _sources()),
     ]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return True
-    except Exception:
-        try:  # retry without -march=native for odd toolchains
-            cmd.remove("-march=native")
-            subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-            return True
-        except Exception:
-            return False
+    error = None
+    for attempt in (cmd, [a for a in cmd if a != "-march=native"]):
+        try:
+            subprocess.run(attempt, check=True, capture_output=True, timeout=300)
+            os.replace(tmp, so_path)
+            return None
+        except subprocess.CalledProcessError as e:
+            error = f"g++ exit {e.returncode}: {e.stderr.decode(errors='replace')[-400:]}"
+        except (OSError, subprocess.SubprocessError) as e:
+            error = f"{type(e).__name__}: {e}"
+    return error
+
+
+# What happened when the library was loaded: the built file, whether this
+# process built it, and why the build or the load failed (None if neither).
+load_info: dict = {"path": None, "built_here": False, "error": None}
 
 
 def _load() -> NativeLib | None:
+    from seaweedfs_tpu.util import glog
+
     with _lock:
-        if not os.path.exists(_SO_PATH) or any(
-            os.path.getmtime(os.path.join(_SRC, f)) > os.path.getmtime(_SO_PATH)
-            for f in os.listdir(_SRC)
-            if f.endswith(".cpp")
-        ):
-            if not _build():
+        so_path = os.path.join(_HERE, f"_seaweed_native.{_build_key()}.so")
+        load_info["path"] = so_path
+        if not os.path.exists(so_path):
+            error = _build(so_path)
+            if error is not None:
+                load_info["error"] = "build failed: " + error
+                glog.warning("native library %s", load_info["error"])
                 return None
+            load_info["built_here"] = True
+            for name in os.listdir(_HERE):  # built for other sources or CPUs
+                if name.startswith("_seaweed_native") and name.endswith(".so") \
+                        and os.path.join(_HERE, name) != so_path:
+                    try:
+                        os.unlink(os.path.join(_HERE, name))
+                    except OSError:
+                        pass
         try:
-            return NativeLib(ctypes.CDLL(_SO_PATH))
-        except OSError:
+            return NativeLib(ctypes.CDLL(so_path))
+        except (OSError, AttributeError) as e:
+            load_info["error"] = f"load failed: {type(e).__name__}: {e}"
+            glog.warning("native library %s", load_info["error"])
             return None
 
 
 lib: NativeLib | None = None
 if os.environ.get("SEAWEEDFS_TPU_DISABLE_NATIVE") != "1":
-    try:
-        lib = _load()
-    except Exception:
-        lib = None
+    lib = _load()

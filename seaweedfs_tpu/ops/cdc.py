@@ -19,6 +19,8 @@ import functools
 
 import numpy as np
 
+from . import device
+
 WINDOW = 32
 
 # deterministic gear table (fixed seed so fingerprints are stable across runs)
@@ -49,8 +51,8 @@ def _bucket(n: int) -> int:
 
 @functools.lru_cache(maxsize=8)
 def _compiled_hashes(n: int):
-    import jax
-    import jax.numpy as jnp
+    jax = device.jax()
+    jnp = jax.numpy
 
     gear = jnp.asarray(_GEAR)
 

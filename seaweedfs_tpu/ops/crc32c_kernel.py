@@ -21,6 +21,8 @@ import numpy as np
 
 from seaweedfs_tpu.storage import crc as crc_cpu
 
+from . import device
+
 # --- GF(2) 32-bit state algebra (host-side, numpy bool) ---------------------
 _POLY = 0x82F63B78
 
@@ -73,8 +75,8 @@ def _zero_crc(length: int) -> int:
 # --- device batch kernel ----------------------------------------------------
 @functools.lru_cache(maxsize=16)
 def _compiled_batch(length: int):
-    import jax
-    import jax.numpy as jnp
+    jax = device.jax()
+    jnp = jax.numpy
 
     m = jnp.asarray(
         np.frombuffer(_block_matrix(length), dtype=np.uint8).reshape(length * 8, 32),

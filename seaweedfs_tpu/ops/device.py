@@ -1,0 +1,134 @@
+"""The one door through which every device path gets jax.
+
+`jax()` imports jax on first use and places the persistent compile cache
+before anything compiles: where `JAX_COMPILATION_CACHE_DIR` is set jax
+reads it itself and no directory is set here; where it is not, the cache
+goes to `.jax_cache/` at the root of the checkout (git-ignored; the path is
+part of the cache key, so it never moves). The kernels compile in a second
+or two, below jax's default threshold for keeping an entry, so the
+threshold is dropped to zero.
+
+The module also holds what a process knows about its device side, for
+`GET /status` on the volume server and for `chip_smoke.py` to read:
+the devices jax sees (only once jax has been started), the compile
+counters, and every failure that made a backend selection skip a candidate.
+"""
+
+from __future__ import annotations
+
+import os
+import threading
+
+from seaweedfs_tpu.util import glog
+
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
+_lock = threading.Lock()
+_jax = None
+_cache: dict = {}
+_compiles = {"requests": 0, "seconds": 0.0, "cache_hits": 0}
+_selection_failures: dict[str, str] = {}
+
+
+def cache_dir() -> tuple[str, str]:
+    """(directory, "env" | "checkout") — where this process's compile cache
+    lives and who placed it."""
+    env = os.environ.get(CACHE_ENV, "")
+    return (env, "env") if env else (CHECKOUT_CACHE_DIR, "checkout")
+
+
+def cache_entries(path: str) -> int:
+    try:
+        return sum(1 for name in os.listdir(path) if not name.startswith("."))
+    except OSError:
+        return 0
+
+
+def _on_duration(event: str, seconds: float, **_kw) -> None:
+    if event == "/jax/core/compile/backend_compile_duration":
+        with _lock:
+            _compiles["requests"] += 1
+            _compiles["seconds"] += seconds
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event == "/jax/compilation_cache/cache_hits":
+        with _lock:
+            _compiles["cache_hits"] += 1
+
+
+def jax():
+    """The jax module, imported once with the compile cache configured."""
+    global _jax
+    if _jax is not None:
+        return _jax
+    with _lock:
+        if _jax is None:
+            import jax as jax_mod
+
+            path, source = cache_dir()
+            entries = cache_entries(path)
+            if source == "checkout":
+                jax_mod.config.update("jax_compilation_cache_dir", path)
+            jax_mod.config.update(
+                "jax_persistent_cache_min_compile_time_secs", 0.0
+            )
+            jax_mod.monitoring.register_event_duration_secs_listener(
+                _on_duration
+            )
+            jax_mod.monitoring.register_event_listener(_on_event)
+            _cache.update(
+                dir=path, source=source, entries_at_start=entries,
+                warm=entries > 0,
+            )
+            _jax = jax_mod
+    return _jax
+
+
+def started() -> bool:
+    """Whether anything in this process has started jax through this door."""
+    return _jax is not None
+
+
+def platform() -> str:
+    """The platform jax computes on ("tpu", "cpu", ...). Starts jax."""
+    return jax().default_backend()
+
+
+def note_selection_failure(where: str, exc: BaseException) -> None:
+    """A backend selection skipped a candidate because of `exc`: log it once
+    per place with its cause, and keep it for the status report — a device
+    that was dropped must not look like one that was never there."""
+    cause = f"{type(exc).__name__}: {exc}"
+    with _lock:
+        first = where not in _selection_failures
+        _selection_failures[where] = cause
+    if first:
+        glog.warning("backend selection: %s failed: %s", where, cause)
+
+
+def report() -> dict:
+    """What this process knows about its device side. `jax` is absent, not
+    guessed, when nothing in the process has started jax."""
+    with _lock:
+        out: dict = {"selection_failures": dict(_selection_failures)}
+        compiles = dict(_compiles)
+    if not started():
+        return out
+    devices = _jax.devices()
+    out["jax"] = {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+    out["compile_cache"] = dict(_cache)
+    out["compiles"] = {
+        "requests": compiles["requests"],
+        "cache_hits": compiles["cache_hits"],
+        "seconds": round(compiles["seconds"], 3),
+    }
+    return out

@@ -17,6 +17,8 @@ import functools
 
 import numpy as np
 
+from . import device
+
 _K = np.array(
     [int(abs(__import__("math").sin(i + 1)) * (1 << 32)) & 0xFFFFFFFF for i in range(64)],
     dtype=np.uint32,
@@ -34,8 +36,8 @@ def _pad_len(blob_len: int) -> int:
 
 @functools.lru_cache(maxsize=16)
 def _compiled_batch(blob_len: int):
-    import jax
-    import jax.numpy as jnp
+    jax = device.jax()
+    jnp = jax.numpy
 
     padded = _pad_len(blob_len)
     n_blocks = padded // 64

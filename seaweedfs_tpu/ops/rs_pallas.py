@@ -4,8 +4,7 @@ One grid step processes a (cols, TILE) byte block entirely in VMEM:
 unpack to bit planes (VPU) -> (8*rows, 8*cols)x(8*cols, TILE) int8 matmul
 (MXU) -> mod-2 + byte pack (VPU) -> (rows, TILE) output. The 8x bit
 expansion never touches HBM — that's the difference from the pure-jnp path
-in rs_kernel (XLA materializes the bits tensor), worth ~10x measured on
-v5e (~20 GB/s vs ~2 GB/s for RS(10,4) encode).
+in rs_kernel, where XLA materializes the bits tensor.
 
 Bit-matrix row order here is (k, c) — plane-major — because the kernel
 builds the bit tensor by concatenating whole shifted planes along the
@@ -13,8 +12,9 @@ sublane axis (cheap block moves); gf256.bit_matrix's (c, k) order is
 permuted accordingly on the host.
 
 Works for any coefficient matrix (parity rows for encode, inverted
-sub-matrix rows for reconstruct/decode). TPU-only; callers fall back to
-rs_kernel.gf_matmul_jax elsewhere.
+sub-matrix rows for reconstruct/decode). TPU-only: rs_kernel.gf_matmul_jax
+is the one place that decides, by platform, between this kernel and the
+XLA form.
 """
 
 from __future__ import annotations
@@ -23,7 +23,7 @@ import functools
 
 import numpy as np
 
-from . import gf256
+from . import device, gf256
 
 TILE = 8192
 
@@ -42,8 +42,8 @@ def _plane_major_bits(matrix_bytes: bytes, rows: int, cols: int) -> bytes:
 
 @functools.lru_cache(maxsize=64)
 def _compiled(rows: int, cols: int, at_bytes: bytes, tile: int):
-    import jax
-    import jax.numpy as jnp
+    jax = device.jax()
+    jnp = jax.numpy
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -96,7 +96,7 @@ def gf_matmul_pallas(matrix: np.ndarray, shards, tile: int = TILE):
     host). n is padded to a tile multiple internally (zero bytes encode to
     zero parity, so the tail slice is exact). Returns device (rows, n).
     """
-    import jax.numpy as jnp
+    jnp = device.jax().numpy
 
     matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
     rows, cols = matrix.shape
@@ -110,11 +110,3 @@ def gf_matmul_pallas(matrix: np.ndarray, shards, tile: int = TILE):
     out = fn(shards)
     return out[:, :n] if pad else out
 
-
-def is_available() -> bool:
-    try:
-        import jax
-
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False

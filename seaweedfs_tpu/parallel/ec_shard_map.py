@@ -16,25 +16,15 @@ import functools
 
 import numpy as np
 
-from seaweedfs_tpu.ops import gf256
+from seaweedfs_tpu.ops import device, gf256
 from seaweedfs_tpu.ops.crc32c_kernel import _block_matrix, _zero_crc
 from seaweedfs_tpu.ops.rs_kernel import DATA_SHARDS, PARITY_SHARDS
-
-
-def _shard_map():
-    """Version-tolerant shard_map import: jax >= 0.4.44 exports it at the
-    top level, the pinned 0.4.37 only under jax.experimental."""
-    try:
-        from jax import shard_map  # jax >= 0.4.44
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    return shard_map
 
 
 def _bitplane_encode(jnp, jax, shards, a):
     """shards (10, n) uint8, a (80, 32) int8 -> parity (4, n) uint8.
 
-    The single-chip flagship kernel body — also reused by __graft_entry__.
+    The XLA form of the single-chip transform (ops/rs_kernel.py), per volume.
     """
     n = shards.shape[1]
     k = jnp.arange(8, dtype=jnp.uint8)
@@ -57,11 +47,9 @@ def _parity_bit_matrix_bytes() -> bytes:
 
 @functools.lru_cache(maxsize=64)
 def _encode_fn(mesh, n_volumes: int, n: int):
-    import jax
-    import jax.numpy as jnp
+    jax = device.jax()
+    jnp = jax.numpy
     from jax.sharding import PartitionSpec as P
-
-    shard_map = _shard_map()
 
     a = jnp.asarray(
         np.frombuffer(_parity_bit_matrix_bytes(), dtype=np.uint8).reshape(80, 32),
@@ -72,7 +60,7 @@ def _encode_fn(mesh, n_volumes: int, n: int):
         return jax.vmap(lambda s: _bitplane_encode(jnp, jax, s, a))(vols)
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             per_chip, mesh=mesh, in_specs=P("dp", None, None),
             out_specs=P("dp", None, None),
         )
@@ -82,8 +70,8 @@ def _encode_fn(mesh, n_volumes: int, n: int):
 def sharded_encode(mesh, volumes):
     """volumes: (V, 10, n) uint8, V divisible by mesh size. Returns
     (V, 4, n) parity, computed with each chip owning V/num_devices volumes."""
-    import jax
-    import jax.numpy as jnp
+    jax = device.jax()
+    jnp = jax.numpy
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     volumes = jnp.asarray(volumes, dtype=jnp.uint8)
@@ -94,25 +82,23 @@ def sharded_encode(mesh, volumes):
 
 @functools.lru_cache(maxsize=64)
 def _crc_fn(mesh, length: int):
-    import jax
-    import jax.numpy as jnp
+    jax = device.jax()
+    jnp = jax.numpy
     from jax.sharding import PartitionSpec as P
-
-    shard_map = _shard_map()
 
     from seaweedfs_tpu.ops.crc32c_kernel import _compiled_batch
 
     inner = _compiled_batch(length)
     return jax.jit(
-        shard_map(lambda b: inner(b), mesh=mesh, in_specs=P("dp", None),
+        jax.shard_map(lambda b: inner(b), mesh=mesh, in_specs=P("dp", None),
                   out_specs=P("dp"))
     )
 
 
 def sharded_crc32c(mesh, blocks):
     """blocks: (N, L) uint8, N divisible by mesh size -> (N,) uint32."""
-    import jax
-    import jax.numpy as jnp
+    jax = device.jax()
+    jnp = jax.numpy
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     blocks = jnp.asarray(blocks, dtype=jnp.uint8)
@@ -123,24 +109,22 @@ def sharded_crc32c(mesh, blocks):
 
 @functools.lru_cache(maxsize=64)
 def _md5_fn(mesh, length: int):
-    import jax
+    jax = device.jax()
     from jax.sharding import PartitionSpec as P
-
-    shard_map = _shard_map()
 
     from seaweedfs_tpu.ops.md5_kernel import _compiled_batch
 
     inner = _compiled_batch(length)
     return jax.jit(
-        shard_map(lambda b: inner(b), mesh=mesh, in_specs=P("dp", None),
+        jax.shard_map(lambda b: inner(b), mesh=mesh, in_specs=P("dp", None),
                   out_specs=P("dp", None))
     )
 
 
 def sharded_md5(mesh, blobs):
     """blobs: (N, L) uint8, N divisible by mesh size -> (N, 16) uint8."""
-    import jax
-    import jax.numpy as jnp
+    jax = device.jax()
+    jnp = jax.numpy
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     blobs = jnp.asarray(blobs, dtype=jnp.uint8)
@@ -152,7 +136,7 @@ def sharded_md5(mesh, blobs):
 def pipeline_step(mesh, volumes, blobs):
     """One full data-plane step over the mesh: encode a sharded volume batch
     AND hash a sharded blob batch (CRC32C + MD5) — the storage framework's
-    'training step' analog used by dryrun_multichip."""
+    'training step' analog (tests/test_parallel.py)."""
     parity = sharded_encode(mesh, volumes)
     crcs = sharded_crc32c(mesh, blobs)
     digests = sharded_md5(mesh, blobs)

@@ -7,7 +7,9 @@ def make_mesh(n_devices: int | None = None, axis: str = "dp"):
     """1-D mesh over the first n devices (default: all). Storage workloads
     shard the volume-batch dimension only, so a single `dp` axis suffices;
     multi-host meshes lay DCN on the outer factor automatically."""
-    import jax
+    from seaweedfs_tpu.ops import device
+
+    jax = device.jax()
     from jax.sharding import Mesh
 
     devices = jax.devices()

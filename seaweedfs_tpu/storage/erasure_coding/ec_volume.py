@@ -14,10 +14,12 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 
 import numpy as np
 
 from seaweedfs_tpu.ops.rs_kernel import RSCodec
+from seaweedfs_tpu.stats import trace
 from seaweedfs_tpu.storage import idx as idx_mod
 from seaweedfs_tpu.storage.needle import Needle, get_actual_size
 from seaweedfs_tpu.storage.types import (
@@ -223,7 +225,14 @@ class EcVolume:
         data = self._fetch_remote(shard_id, off, interval.size)
         if data is not None:
             return data
-        return self._recover_interval(shard_id, off, interval.size)
+        t0 = time.perf_counter()
+        data = self._recover_interval(shard_id, off, interval.size)
+        # which kernel reconstructs degraded reads here, and how many bytes
+        trace.observe_kernel(
+            trace.EC_DECODE_SECONDS, "reconstruct-" + self.codec.kernel_label,
+            time.perf_counter() - t0, interval.size,
+        )
+        return data
 
     def _recover_interval(self, missing_shard: int, off: int, size: int) -> bytes:
         """Reconstruct one interval from >= 10 surviving shards, local first

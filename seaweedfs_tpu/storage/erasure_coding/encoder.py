@@ -19,8 +19,9 @@ stages overlap:
   parity bytes into the 14 shard files with positional pwrite.
 
 The pipeline backend is chosen by measured end-to-end rate
-(ops.rs_kernel.pick_pipeline_backend), so a chip behind a slow relay loses
-to the host GFNI path instead of silently dragging the verb down.
+(ops.rs_kernel.pick_pipeline_backend). The kernel-span label says what
+carried the bytes: "fused" (native single pass), or "pipeline-" /
+"rebuild-" plus RSCodec.kernel_label ("pallas", "xla", "native", "numpy").
 """
 
 from __future__ import annotations
@@ -446,8 +447,8 @@ def write_ec_files(
     if batch is None:
         batch = _default_batch(codec.backend)
     with trace.kernel_span(
-        "ec.encode", trace.EC_ENCODE_SECONDS, "pipeline-" + codec.backend,
-        nbytes=total,
+        "ec.encode", trace.EC_ENCODE_SECONDS,
+        "pipeline-" + codec.kernel_label, nbytes=total,
     ):
         _write_ec_files_pipeline(
             base_file_name, codec, large_block_size, small_block_size,
@@ -552,21 +553,21 @@ def rebuild_ec_files(
     (`ec_encoder.go:61,237-291`), through the same three-stage pipeline —
     the GF transform is the inverted-submatrix product on the pipeline
     backend (BASELINE config 2). Returns the rebuilt shard ids."""
+    codec = codec or RSCodec(backend=pick_pipeline_backend())
     with trace.kernel_span(
-        "ec.rebuild", trace.EC_DECODE_SECONDS, "rebuild"
+        "ec.rebuild", trace.EC_DECODE_SECONDS, "rebuild-" + codec.kernel_label
     ) as sp:
         return _rebuild_ec_files(base_file_name, codec, chunk, sp)
 
 
 def _rebuild_ec_files(
     base_file_name: str,
-    codec: RSCodec | None,
+    codec: RSCodec,
     chunk: int | None,
     sp,
 ) -> list[int]:
     from seaweedfs_tpu.ops import gf256
 
-    codec = codec or RSCodec(backend=pick_pipeline_backend())
     if chunk is None:
         chunk = _default_batch(codec.backend)
     present_fds: dict[int, int] = {}

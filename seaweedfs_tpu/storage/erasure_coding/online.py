@@ -5,9 +5,8 @@ until then durability costs a full 2x replica fan-out, and the seal pays
 a second full read+encode of everything ever written — the
 replica->EC double-storage window arXiv:1709.05365 measures on SSD
 arrays. That study's conclusion (online EC is viable whenever the
-encoder keeps up with ingest) holds here with margin: the fused GFNI
-host path encodes at ~4.5 GB/s (BENCH_r05), far above any single
-volume's ingest. RapidRAID (arXiv:1207.6744) supplies the shape:
+encoder keeps up with ingest) is the premise here: the fused GFNI host
+path has to encode faster than a single volume's ingest. RapidRAID (arXiv:1207.6744) supplies the shape:
 pipeline the coding work so it overlaps the stream instead of trailing
 it.
 
@@ -173,9 +172,9 @@ class OnlineEcWriter:
         oe = dict(info.get("ec_online") or {})
         self.block = int(block_size or oe.get("block_size") or _DEFAULT_BLOCK)
         self.stripe = self.block * DATA_SHARDS_COUNT
-        # native/numpy only: the device relay must never sit on the ack
-        # path of a live write (pick_pipeline_backend may choose jax for
-        # the offline verb, where latency is free)
+        # native/numpy only: a host->device round trip must never sit on
+        # the ack path of a live write (pick_pipeline_backend may choose
+        # jax for the offline verb, where latency is free)
         self.codec = codec or RSCodec(
             backend="native" if _native_ok() else "numpy"
         )

@@ -762,7 +762,11 @@ class TestStreamHopKilledChunksInFlight:
                 t.start()
             post_json(f"{master.url}/maintenance/enable",
                       {"rebuildMode": "pipelined"})
-            wait_until(lambda: shard_count() == 14, timeout=40,
+            # a deadline, not an expectation: alone the heal takes a few
+            # seconds, but every chunk is an HTTP round trip and under
+            # loaded workers those have measured 1-4 s each, before and
+            # after the chain restart. The wait returns when healed.
+            wait_until(lambda: shard_count() == 14, timeout=240,
                        msg="streamed heal through the dead hop")
             time.sleep(0.5)  # let the storm read across the remount
             stop.set()

@@ -181,25 +181,46 @@ class TestDisabledOverhead:
             emit("degraded_read")
         after = tracemalloc.take_snapshot()
         tracemalloc.stop()
+        # tracemalloc sees every thread of the process, and this worker's
+        # earlier tests left servers' daemon threads running: count only
+        # what the emit path itself can allocate — lines of events.py and
+        # of this file
+        own = (events.__file__, __file__)
         grew = sum(
             s.size_diff for s in after.compare_to(before, "filename")
-            if s.size_diff > 0
+            if s.size_diff > 0 and s.traceback[0].filename in own
         )
         assert grew < 16 * 1024, f"disabled emit allocated {grew} bytes"
 
-        def best_of_3(fn, n=200_000):
+        class Off:
+            enabled = False
+
+        off = Off()
+
+        def one_attribute_check(type_, **kw):
+            if not off.enabled:
+                return None
+            raise AssertionError("unreachable")
+
+        def best_of_5(fn, n=200_000):
             best = float("inf")
-            for _ in range(3):
+            for _ in range(5):
                 t0 = time.perf_counter()
                 for _ in range(n):
                     fn("degraded_read")
                 best = min(best, time.perf_counter() - t0)
             return best
 
-        t = best_of_3(emit)
-        # generous absolute guard (microVM): 200k disabled emits well
-        # under a second means ~<5us/call worst case — no real overhead
-        assert t < 1.0, f"200k disabled emits took {t:.3f}s"
+        # no fixed bound on a shared host: the disabled emit must cost what
+        # a function that does one attribute check costs, timed here, in
+        # the same loop, under the same load (3x leaves room for the
+        # module-global lookup and for noise between the two timings)
+        base = best_of_5(one_attribute_check)
+        t = best_of_5(emit)
+        assert t < 3 * base + 0.02, (
+            f"200k disabled emits took {t:.3f}s,"
+            f" 200k one-attribute-check calls {base:.3f}s"
+        )
 
 
 class TestTaskLifecycleEvents:

@@ -1,0 +1,71 @@
+"""The Pallas kernel body of ops/rs_pallas.py, executed.
+
+On the CPU `gf_matmul_jax` takes the XLA form, so nothing else in the suite
+runs the kernel. Here the test wraps `pl.pallas_call` with `interpret=True`
+(the program has no switch for it) and holds the kernel, bit for bit, to the
+numpy oracle `ops.gf256.gf_matmul_bytes` for every matrix shape the served
+path hands it.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import numpy as np
+import pytest
+
+from seaweedfs_tpu.ops import gf256, rs_pallas
+
+DATA, PARITY = 10, 4
+TILE = 512  # interpret mode walks the grid in Python: keep the steps few
+
+
+@pytest.fixture()
+def interpreted(monkeypatch):
+    from jax.experimental import pallas as pl
+
+    monkeypatch.setattr(
+        pl, "pallas_call", functools.partial(pl.pallas_call, interpret=True)
+    )
+    rs_pallas._compiled.cache_clear()  # traces made without the wrapper
+    yield
+    rs_pallas._compiled.cache_clear()  # and the ones made with it
+
+
+def _rebuild_matrix(missing: tuple[int, ...]) -> np.ndarray:
+    present = tuple(i for i in range(DATA + PARITY) if i not in missing)
+    return gf256.decode_matrix(DATA, PARITY, present, missing)
+
+
+def _partial_sum_matrix(k: int) -> np.ndarray:
+    """What a repair hop holding k of the ten `use` shards passes to
+    RSCodec.apply_matrix: those k columns of the decode matrix."""
+    return np.ascontiguousarray(_rebuild_matrix((3,))[:, :k])
+
+
+CASES = [
+    ("encode-4x10", gf256.parity_rows(DATA, PARITY), 4 * TILE),
+    ("encode-4x10-padded", gf256.parity_rows(DATA, PARITY), 3 * TILE + 77),
+    ("encode-4x10-short", gf256.parity_rows(DATA, PARITY), 5),
+    *[
+        (f"rebuild-shard-{m:02d}", _rebuild_matrix((m,)), 2 * TILE)
+        for m in range(DATA + PARITY)
+    ],
+    ("rebuild-2-shards", _rebuild_matrix((0, 11)), 2 * TILE),
+    ("rebuild-4-shards", _rebuild_matrix((1, 5, 10, 13)), TILE + 1),
+    ("partial-sum-1x3", _partial_sum_matrix(3), 2 * TILE),
+    ("partial-sum-1x1", _partial_sum_matrix(1), TILE + 9),
+    ("partial-sum-1x7", _partial_sum_matrix(7), TILE),
+]
+
+
+@pytest.mark.parametrize(
+    "matrix,n", [c[1:] for c in CASES], ids=[c[0] for c in CASES]
+)
+def test_kernel_matches_numpy_oracle(interpreted, matrix, n):
+    rng = np.random.RandomState(n + matrix.shape[0] * 31 + matrix.shape[1])
+    shards = rng.randint(0, 256, size=(matrix.shape[1], n), dtype=np.uint8)
+    got = np.asarray(rs_pallas.gf_matmul_pallas(matrix, shards, tile=TILE))
+    want = gf256.gf_matmul_bytes(matrix, shards)
+    assert got.shape == want.shape and got.dtype == np.uint8
+    assert np.array_equal(got, want)

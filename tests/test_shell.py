@@ -31,6 +31,33 @@ def cluster(tmp_path):
     master.stop()
 
 
+@pytest.fixture()
+def own_alerts():
+    """`cluster.check` reports the alert engine of the PROCESS, which every
+    test of this worker shares: what earlier tests left in the metrics
+    history (5xx storms, requests slowed by a loaded host) would fire
+    `slo_burn_fast` on a cluster that is healthy. Give the test a history
+    of its own, and take the host's speed out of the latency SLOs by
+    moving their thresholds to the histogram's last bound (the burn is
+    still computed; only a request slower than 10 s can spend budget).
+    Availability SLOs and every other rule stay as they are."""
+    from seaweedfs_tpu.stats import alerts as alerts_mod
+    from seaweedfs_tpu.stats import history as history_mod
+
+    eng = alerts_mod.engine()
+    was = eng.params["slos"]
+    eng.configure(slos=tuple(
+        alerts_mod.Slo(s.name, s.role, s.kind, s.objective,
+                       threshold_s=10.0 if s.kind == "latency" else 0.0,
+                       description=s.description)
+        for s in was
+    ))
+    history_mod.default_history().clear()
+    eng.evaluate()
+    yield
+    eng.configure(slos=was)
+
+
 def write_blobs(master_url, n=10, size=500, **params):
     """Write n blobs; returns {url: data} and the vid of the first one."""
     out = {}
@@ -60,7 +87,7 @@ class TestBasicCommands:
         ps = run_command(env, "cluster.ps")
         assert "volumeServer" in ps and "master" in ps
 
-    def test_cluster_check_healthy(self, cluster):
+    def test_cluster_check_healthy(self, cluster, own_alerts):
         master, volumes, env = cluster
         write_blobs(master.url, 3)
         for vs in volumes:
@@ -74,7 +101,7 @@ class TestBasicCommands:
         assert "disk" in out and "heartbeat" in out
         assert "fastlane native" in out
 
-    def test_cluster_check_fail_mode_on_readonly(self, cluster):
+    def test_cluster_check_fail_mode_on_readonly(self, cluster, own_alerts):
         """Acceptance: a read-only volume makes `cluster.check -fail` exit
         nonzero; without -fail the problems render but the verb returns."""
         master, volumes, env = cluster

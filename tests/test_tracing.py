@@ -275,7 +275,7 @@ class TestKernelSpans:
         decode_sum = [
             line for line in text.splitlines()
             if line.startswith("SeaweedFS_volume_ec_decode_seconds_sum")
-            and 'kernel="rebuild"' in line
+            and 'kernel="rebuild-numpy"' in line
         ]
         assert decode_sum and float(decode_sum[0].rsplit(" ", 1)[1]) > 0
 
