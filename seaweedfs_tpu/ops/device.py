@@ -111,9 +111,26 @@ def note_selection_failure(where: str, exc: BaseException) -> None:
         glog.warning("backend selection: %s failed: %s", where, cause)
 
 
+def _fullest_memory(devices) -> dict | None:
+    """`memory_stats()` of the local device whose peak is highest, cut to
+    the three numbers a reader sizes a deployment by; None where the backend
+    reports none (the CPU's)."""
+    best = None
+    for d in devices:
+        stats = d.memory_stats()
+        if stats and (best is None or stats.get("peak_bytes_in_use", 0)
+                      > best.get("peak_bytes_in_use", 0)):
+            best = stats
+    if best is None:
+        return None
+    return {k: int(best[k]) for k in
+            ("bytes_in_use", "peak_bytes_in_use", "bytes_limit") if k in best}
+
+
 def report() -> dict:
-    """What this process knows about its device side. `jax` is absent, not
-    guessed, when nothing in the process has started jax."""
+    """What this process knows about its device side. `jax` and `memory`
+    are absent, not guessed, when nothing in the process has started jax;
+    `memory` also where the backend reports none."""
     with _lock:
         out: dict = {"selection_failures": dict(_selection_failures)}
         compiles = dict(_compiles)
@@ -125,6 +142,9 @@ def report() -> dict:
         "device_kind": devices[0].device_kind,
         "count": len(devices),
     }
+    memory = _fullest_memory(_jax.local_devices())
+    if memory is not None:
+        out["memory"] = memory
     out["compile_cache"] = dict(_cache)
     out["compiles"] = {
         "requests": compiles["requests"],

@@ -25,6 +25,8 @@ import time
 
 import numpy as np
 
+from seaweedfs_tpu.stats import trace
+
 from . import device, gf256
 
 DATA_SHARDS = 10
@@ -102,6 +104,14 @@ def gf_matmul_jax(matrix: np.ndarray, shards, chunk: int = DEFAULT_CHUNK):
     return jnp.concatenate(outs, axis=1)
 
 
+def _dispatch(matrix: np.ndarray, shards):
+    """`gf_matmul_jax` as the codec calls it, with the host's seconds in the
+    call (pad, kernel and slice enqueued; the put too, where `shards` is
+    still on the host) counted under `dispatch`."""
+    with trace.phase("rs.dispatch", trace.EC_DEVICE_SECONDS, "dispatch"):
+        return gf_matmul_jax(matrix, shards)
+
+
 class RSCodec:
     """RS(data, parity) codec with pluggable execution backends.
 
@@ -157,7 +167,7 @@ class RSCodec:
 
     def _apply(self, matrix: np.ndarray, shards: np.ndarray) -> np.ndarray:
         if self.backend == "jax":
-            return np.asarray(gf_matmul_jax(matrix, shards))
+            return _JaxHandle(_dispatch(matrix, shards)).result()
         if self.backend == "native":
             from seaweedfs_tpu.native import lib
 
@@ -209,7 +219,7 @@ class RSCodec:
     def apply2d_async(self, matrix: np.ndarray, data: np.ndarray):
         """data: C-contiguous (cols, n) uint8. Handle yields (rows, n)."""
         if self.backend == "jax":
-            return _JaxHandle(gf_matmul_jax(matrix, _device_put_2d(data)))
+            return _JaxHandle(_dispatch(matrix, _device_put_2d(data)))
         if self.backend == "native":
             from seaweedfs_tpu.native import lib
 
@@ -230,9 +240,10 @@ class RSCodec:
             jax = device.jax()
             jnp = jax.numpy
             x = _device_put_1d(buf)
-            x = x.reshape(row_count, self.data_shards, block)
-            x = jnp.transpose(x, (1, 0, 2)).reshape(self.data_shards, -1)
-            return _JaxHandle(gf_matmul_jax(m, x))
+            with jax.named_scope("rs.rows_transpose"):
+                x = x.reshape(row_count, self.data_shards, block)
+                x = jnp.transpose(x, (1, 0, 2)).reshape(self.data_shards, -1)
+            return _JaxHandle(_dispatch(m, x))
         if self.backend == "native":
             from seaweedfs_tpu.native import lib
 
@@ -262,7 +273,14 @@ class _JaxHandle:
         self._dev = dev
 
     def result(self) -> np.ndarray:
-        return np.asarray(self._dev)
+        """The host's copy: blocks until the device has drained what was
+        enqueued before it and the bytes have come back (`d2h-wait`)."""
+        with trace.phase(
+            "rs.d2h_wait", trace.EC_DEVICE_SECONDS, "d2h-wait"
+        ) as ph:
+            out = np.asarray(self._dev)
+            ph.nbytes = out.nbytes
+        return out
 
 
 # Host arrays above this size are put on the device in pieces of this size
@@ -274,18 +292,22 @@ def _device_put_1d(buf: np.ndarray):
     jax = device.jax()
     jnp = jax.numpy
     flat = buf.reshape(-1)
-    if flat.nbytes <= H2D_CHUNK:
-        return jax.device_put(flat)
-    pieces = [
-        jax.device_put(flat[i : i + H2D_CHUNK])
-        for i in range(0, flat.nbytes, H2D_CHUNK)
-    ]
-    return jnp.concatenate(pieces)
+    # `h2d`: the host's seconds in the puts and in dispatching the concat
+    with trace.phase("rs.h2d", trace.EC_DEVICE_SECONDS, "h2d", flat.nbytes):
+        if flat.nbytes <= H2D_CHUNK:
+            return jax.device_put(flat)
+        pieces = [
+            jax.device_put(flat[i : i + H2D_CHUNK])
+            for i in range(0, flat.nbytes, H2D_CHUNK)
+        ]
+        with jax.named_scope("rs.h2d_concat"):
+            return jnp.concatenate(pieces)
 
 
 def _device_put_2d(data: np.ndarray):
     if data.nbytes <= H2D_CHUNK:
-        return device.jax().device_put(data)
+        with trace.phase("rs.h2d", trace.EC_DEVICE_SECONDS, "h2d", data.nbytes):
+            return device.jax().device_put(data)
     return _device_put_1d(data).reshape(data.shape)
 
 

@@ -68,11 +68,14 @@ def _compiled(rows: int, cols: int, at_bytes: bytes, tile: int):
             out_rows.append(acc.reshape(1, -1))
         o_ref[:] = jnp.concatenate(out_rows, axis=0).astype(jnp.uint8)
 
+    # one name for the jitted program and for the kernel inside it, so the
+    # device trace calls them the same after any refactor
     @jax.jit
-    def run(x):  # (cols, n) with n % tile == 0
+    def rs_gf_matmul(x):  # (cols, n) with n % tile == 0
         n = x.shape[1]
         return pl.pallas_call(
             kernel,
+            name="rs_gf_matmul",
             out_shape=jax.ShapeDtypeStruct((rows, n), jnp.uint8),
             grid=(n // tile,),
             in_specs=[
@@ -86,7 +89,7 @@ def _compiled(rows: int, cols: int, at_bytes: bytes, tile: int):
             ),
         )(jnp.asarray(at_np), x)
 
-    return run
+    return rs_gf_matmul
 
 
 def gf_matmul_pallas(matrix: np.ndarray, shards, tile: int = TILE):
@@ -105,6 +108,9 @@ def gf_matmul_pallas(matrix: np.ndarray, shards, tile: int = TILE):
     shards = jnp.asarray(shards, dtype=jnp.uint8)
     n = shards.shape[1]
     pad = (-n) % tile
+    # no named scope around the pad and the slice: this is the per-read
+    # path, where two scopes cost the read cell a percent (PERF.md, PR 26);
+    # the trace knows them as `jit__pad` and `jit_dynamic_slice`
     if pad:
         shards = jnp.pad(shards, ((0, 0), (0, pad)))
     out = fn(shards)

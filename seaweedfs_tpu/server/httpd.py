@@ -160,6 +160,12 @@ class HTTPService:
             "SeaweedFS_http_request_seconds", "request latency",
             ("role", "method"), exemplars=True,
         )
+        # the handler thread's own CPU beside its wall seconds: whether a
+        # slow request computed or waited (for the interpreter, a lock, I/O)
+        self._m_cpu = reg.counter(
+            "SeaweedFS_http_request_cpu_seconds_total",
+            "thread CPU seconds of request handlers", ("role", "method"),
+        )
         if serve_route:
             @self.route("GET", r"/metrics")
             def metrics(req: Request) -> Response:
@@ -229,6 +235,7 @@ class HTTPService:
         import time as _time
 
         start = _time.monotonic()
+        cpu_start = _time.thread_time()
         path = urllib.parse.urlparse(handler.path).path
         span = None
         if self.trace_role is not None:
@@ -292,6 +299,9 @@ class HTTPService:
                 self._m_seconds.labels(
                     self.metrics_role, handler.command
                 ).observe(_time.monotonic() - start)
+                self._m_cpu.labels(
+                    self.metrics_role, handler.command
+                ).inc(_time.thread_time() - cpu_start)
         if span is not None:
             from seaweedfs_tpu.stats import trace as _trace
 

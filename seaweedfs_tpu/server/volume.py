@@ -8,6 +8,7 @@ JSON admin endpoints here), `volume_grpc_client_to_master.go:50` (heartbeat).
 
 from __future__ import annotations
 
+import functools
 import json
 import queue
 import re
@@ -19,6 +20,7 @@ import numpy as np
 
 from seaweedfs_tpu.security import Guard, SecurityConfig
 from seaweedfs_tpu.security.jwt import token_from_request, verify_file_jwt
+from seaweedfs_tpu.stats import trace
 from seaweedfs_tpu.storage import crc as crc_mod
 from seaweedfs_tpu.storage.erasure_coding import decoder as ec_decoder
 from seaweedfs_tpu.storage.erasure_coding import encoder as ec_encoder
@@ -31,6 +33,25 @@ from seaweedfs_tpu.util import faults
 from seaweedfs_tpu.util.retry import READ_POLICY
 
 from .httpd import HTTPService, Request, Response, get_json, http_request, post_json, peer_url
+
+
+def _ec_step(op: str) -> trace.phase:
+    """One timed stretch of a shell EC verb inside this server, under
+    SeaweedFS_volume_ec_admin_seconds{op}: a whole handler (`generate`), or
+    a step nested in one under a dotted op (`generate.encode`)."""
+    return trace.phase("admin." + op, trace.EC_ADMIN_SECONDS, op)
+
+
+def _ec_admin(op: str):
+    """Times every call of the `/admin/...` handler it decorates."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def timed(req: "Request") -> "Response":
+            with _ec_step(op):
+                return fn(req)
+        return timed
+    return deco
+
 
 FID_RE = r"/(\d+),([0-9a-fA-F_]+)(?:\.[^/]*)?"
 _SAFE_EXT_RE = re.compile(r"\.(dat|idx|vif|ecx|ecj|ec\d\d)")
@@ -1006,6 +1027,7 @@ class VolumeServer:
             return Response({"ok": True, "garbage_was": garbage})
 
         @svc.route("POST", r"/admin/volume/readonly")
+        @_ec_admin("readonly")
         def readonly(req: Request) -> Response:
             p = req.json()
             self.store.mark_readonly(int(p["volume"]), bool(p.get("readonly", True)))
@@ -1122,54 +1144,61 @@ class VolumeServer:
 
         # --- EC verbs (volume_grpc_erasure_coding.go) ---
         @svc.route("POST", r"/admin/ec/generate")
+        @_ec_admin("generate")
         def ec_generate(req: Request) -> Response:
             p = req.json()
             vid = int(p["volume"])
             v = self.store.get_volume(vid)
             if v is None:
                 return Response({"error": f"volume {vid} not found"}, 404)
-            v.readonly = True
-            # a native append already past the engine's readonly check could
-            # still be mid-pwrite; unregister waits it out so the encoder
-            # reads a quiescent .dat/.idx
-            self._fl_unregister(vid)
+            with _ec_step("generate.quiesce"):
+                v.readonly = True
+                # a native append already past the engine's readonly check
+                # could still be mid-pwrite; unregister waits it out so the
+                # encoder reads a quiescent .dat/.idx
+                self._fl_unregister(vid)
             sealed_online = False
             try:
                 base = v.base_name
-                if v.online_ec is not None and v.online_ec.active:
-                    # ingest already paid the GF math: the seal flushes
-                    # the tail row and materializes data shards with a
-                    # sequential copy — no re-encode
-                    try:
-                        v.online_ec.seal()
-                        sealed_online = True
-                    except RuntimeError:
-                        pass  # degraded mid-seal: classic encode below
-                if not sealed_online:
-                    ec_encoder.write_ec_files(base)
-                ec_encoder.write_sorted_file_from_idx(base)
+                with _ec_step("generate.encode"):
+                    if v.online_ec is not None and v.online_ec.active:
+                        # ingest already paid the GF math: the seal flushes
+                        # the tail row and materializes data shards with a
+                        # sequential copy — no re-encode
+                        try:
+                            v.online_ec.seal()
+                            sealed_online = True
+                        except RuntimeError:
+                            pass  # degraded mid-seal: classic encode below
+                    if not sealed_online:
+                        ec_encoder.write_ec_files(base)
+                with _ec_step("generate.ecx"):
+                    ec_encoder.write_sorted_file_from_idx(base)
             finally:
                 self._fl_register(vid)  # readonly: native reads, proxied writes
-            if not sealed_online:
-                # classic path: the shards now belong to the EC volume —
-                # detach any (degraded) stripe writer so a later destroy
-                # can't mistake .ec10-.ec13 for its partial parity, and
-                # write a plain .vif (seal() writes the online one,
-                # recording the uniform stripe geometry)
-                if v.online_ec is not None:
-                    v.online_ec.close()
-                    v.online_ec = None
-                    import os as _os
+            with _ec_step("generate.vif"):
+                if not sealed_online:
+                    # classic path: the shards now belong to the EC volume —
+                    # detach any (degraded) stripe writer so a later destroy
+                    # can't mistake .ec10-.ec13 for its partial parity, and
+                    # write a plain .vif (seal() writes the online one,
+                    # recording the uniform stripe geometry)
+                    if v.online_ec is not None:
+                        v.online_ec.close()
+                        v.online_ec = None
+                        import os as _os
 
-                    try:
-                        _os.unlink(base + ".ecp")
-                    except OSError:
-                        pass
-                ec_encoder.save_volume_info(base + ".vif", version=v.version())
+                        try:
+                            _os.unlink(base + ".ecp")
+                        except OSError:
+                            pass
+                    ec_encoder.save_volume_info(
+                        base + ".vif", version=v.version())
             return Response({"ok": True, "shards": list(range(14)),
                              "online": sealed_online})
 
         @svc.route("POST", r"/admin/ec/mount")
+        @_ec_admin("mount")
         def ec_mount(req: Request) -> Response:
             p = req.json()
             vid = int(p["volume"])
@@ -1190,6 +1219,7 @@ class VolumeServer:
             return Response({"ok": True})
 
         @svc.route("POST", r"/admin/ec/rebuild")
+        @_ec_admin("rebuild")
         def ec_rebuild(req: Request) -> Response:
             p = req.json()
             vid = int(p["volume"])
@@ -1205,7 +1235,8 @@ class VolumeServer:
                 if any(
                     os.path.exists(base + geometry.to_ext(i)) for i in range(14)
                 ):
-                    rebuilt = ec_encoder.rebuild_ec_files(base)
+                    with _ec_step("rebuild.encode"):
+                        rebuilt = ec_encoder.rebuild_ec_files(base)
                     return Response({"ok": True, "rebuilt": rebuilt})
             return Response({"error": f"no shards for volume {vid}"}, 404)
 
@@ -1236,6 +1267,7 @@ class VolumeServer:
             })
 
         @svc.route("POST", r"/admin/ec/delete_volume")
+        @_ec_admin("delete_volume")
         def ec_delete(req: Request) -> Response:
             """Delete the original volume files after EC spread
             (`command_ec_encode.go` deletes source replicas)."""
@@ -1933,6 +1965,7 @@ class VolumeServer:
             return Response({"ok": True})
 
         @svc.route("POST", r"/admin/ec/copy")
+        @_ec_admin("copy")
         def ec_copy(req: Request) -> Response:
             """Pull EC shard files (+ .ecx/.vif) from a source server
             (`VolumeEcShardsCopy`)."""
@@ -1978,6 +2011,7 @@ class VolumeServer:
             return Response({"ok": True, "copied": copied, "bytes": pulled})
 
         @svc.route("POST", r"/admin/ec/delete_shards")
+        @_ec_admin("delete_shards")
         def ec_delete_shards(req: Request) -> Response:
             """Remove local shard files after they moved elsewhere
             (`VolumeEcShardsDelete`)."""

@@ -4,6 +4,8 @@ from __future__ import annotations
 
 import sys
 
+from seaweedfs_tpu.stats import trace
+
 from .env import CommandEnv, ShellError
 from .registry import run_command
 
@@ -49,7 +51,10 @@ def run_shell(
         if not line or line.startswith("#"):
             return
         try:
-            result = run_command(env, line)
+            # one root span a verb: every RPC of the verb carries its trace
+            # id, so the servers' spans are this span's children
+            with trace.span("shell " + line.split(None, 1)[0], role="shell"):
+                result = run_command(env, line)
             if result:
                 print(result, file=out)
         except ShellError as e:

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import threading
 import time
+from bisect import bisect_left
 from typing import Iterable
 
 DEFAULT_BUCKETS = (
@@ -72,9 +73,17 @@ class Counter(_Metric):
     def __init__(self, name, help_text="", label_names=()):
         super().__init__(name, help_text, label_names)
         self._values: dict[tuple, float] = {}
+        self._children: dict[tuple, _CounterChild] = {}
 
     def labels(self, *values) -> "_CounterChild":
-        return _CounterChild(self, tuple(str(v) for v in values))
+        # one child per label set, kept: a hot path asks for the same one
+        # on every request, and building it costs more than the increment
+        # (values that compare equal, as 1 and True, share the first's)
+        child = self._children.get(values)
+        if child is None:
+            child = self._children[values] = _CounterChild(
+                self, tuple(str(v) for v in values))
+        return child
 
     def inc(self, amount: float = 1.0) -> None:
         self.labels().inc(amount)
@@ -183,7 +192,12 @@ class Histogram(_Metric):
                  buckets=DEFAULT_BUCKETS, exemplars=False):
         super().__init__(name, help_text, label_names)
         self.buckets = tuple(sorted(buckets))
+        # per label set: how many values fell into each bucket itself (the
+        # last slot is the overflow past the largest bound); render() adds
+        # them up to the exposition's cumulative counts, so an observation
+        # is one bisect and one increment whatever the number of buckets
         self._counts: dict[tuple, list[int]] = {}
+        self._children: dict[tuple, _HistogramChild] = {}
         self._sums: dict[tuple, float] = {}
         self._totals: dict[tuple, int] = {}
         # exemplars: most recent (trace_id, value, ts) per upper bucket —
@@ -194,7 +208,11 @@ class Histogram(_Metric):
         self._exemplars: dict[tuple, dict[float, tuple]] = {}
 
     def labels(self, *values) -> "_HistogramChild":
-        return _HistogramChild(self, tuple(str(v) for v in values))
+        child = self._children.get(values)  # kept, as Counter.labels
+        if child is None:
+            child = self._children[values] = _HistogramChild(
+                self, tuple(str(v) for v in values))
+        return child
 
     def observe(self, value: float) -> None:
         self.labels().observe(value)
@@ -205,20 +223,19 @@ class Histogram(_Metric):
             ctx = _exemplar_source()
             if ctx is not None:
                 ex = (ctx[0], value, time.time())
+        at = bisect_left(self.buckets, value)  # first bound >= value
         with self._lock:
-            counts = self._counts.setdefault(key, [0] * len(self.buckets))
-            for i, ub in enumerate(self.buckets):
-                if value <= ub:
-                    counts[i] += 1
-            self._sums[key] = self._sums.get(key, 0.0) + value
-            self._totals[key] = self._totals.get(key, 0) + 1
+            counts = self._counts.get(key)
+            if counts is None:
+                counts = self._counts[key] = [0] * (len(self.buckets) + 1)
+                self._sums[key] = 0.0
+                self._totals[key] = 0
+            counts[at] += 1
+            self._sums[key] += value
+            self._totals[key] += 1
             if ex is not None:
-                for ub in self.buckets:
-                    if value <= ub:
-                        bound = ub
-                        break
-                else:
-                    bound = float("inf")
+                bound = (self.buckets[at] if at < len(self.buckets)
+                         else float("inf"))
                 self._exemplars.setdefault(key, {})[bound] = ex
 
     def exemplars(self) -> list[dict]:
@@ -245,11 +262,13 @@ class Histogram(_Metric):
     def render(self) -> list[str]:
         out = [f"# HELP {self.name} {self.help}", f"# TYPE {self.name} {self.kind}"]
         with self._lock:
-            items = sorted(self._counts.items())
+            items = sorted((k, list(v)) for k, v in self._counts.items())
             sums = dict(self._sums)
             totals = dict(self._totals)
         for key, counts in items:
-            for ub, c in zip(self.buckets, counts):
+            c = 0
+            for ub, fell_in in zip(self.buckets, counts):
+                c += fell_in
                 le = 'le="{:g}"'.format(ub)
                 out.append(
                     f"{self.name}_bucket"
@@ -420,6 +439,23 @@ class Registry:
 
 
 _default = Registry()
+
+PROCESS_FAMILIES = ("SeaweedFS_process_cpu_seconds_total",)
+
+
+def _process_lines() -> list[str]:
+    """CPU seconds of the whole process (user + system, every thread), read
+    when a page is rendered: over an interval, its growth is the share of
+    one core this interpreter and its native threads held."""
+    return [
+        "# HELP SeaweedFS_process_cpu_seconds_total user and system CPU"
+        " seconds of this process",
+        "# TYPE SeaweedFS_process_cpu_seconds_total counter",
+        f"SeaweedFS_process_cpu_seconds_total {time.process_time()!r}",
+    ]
+
+
+_default.register_collector(_process_lines, names=PROCESS_FAMILIES)
 
 
 _SAMPLE_RE = None  # compiled lazily: most processes never parse exposition

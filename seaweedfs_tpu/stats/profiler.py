@@ -16,8 +16,9 @@ exceeds `max_overhead` (10% by default) of wall time — a deep 200-thread
 process degrades to a lower effective Hz instead of stealing the GIL.
 
 `device_trace` wraps `jax.profiler` trace capture for the device side
-(kernel/transfer timelines) and degrades to DeviceProfilerUnavailable —
-HTTP 501 — when jax is not importable; the host-side sampler never
+(kernel/transfer timelines, with the program's own spans and phases as
+host events on the same clock) and degrades to DeviceProfilerUnavailable
+— HTTP 501 — when jax is not importable; the host-side sampler never
 imports jax.
 
 Motivation follows RapidRAID (arXiv:1207.6744 — pipelined erasure coding
@@ -33,6 +34,7 @@ import threading
 import time
 
 from seaweedfs_tpu.stats.metrics import default_registry
+from seaweedfs_tpu.util import glog
 
 MIN_HZ, MAX_HZ = 1, 500
 MIN_SECONDS, MAX_SECONDS = 0.05, 120.0
@@ -261,11 +263,19 @@ def device_trace(seconds: float = 2.0) -> bytes:
     """Capture a jax.profiler trace for `seconds` and return it as a
     .tar.gz (TensorBoard/Perfetto-loadable). Raises
     DeviceProfilerUnavailable when jax is absent (the HTTP route turns
-    that into a 501) — the sampler above never takes this dependency."""
+    that into a 501) — the sampler above never takes this dependency.
+
+    While it runs, every span and phase of `stats.trace` is written into
+    the trace as a host event of its own name, on the device events' clock.
+    jax's Python tracer is off: it records every Python call of every
+    thread (tens of MB and many seconds to stop for a few seconds of a busy
+    server), and `/debug/pprof/profile` is the route for Python stacks."""
     try:
         import jax
 
-        jax.profiler.start_trace  # attribute probe before any side effect
+        options = jax.profiler.ProfileOptions()  # probe before side effects
+        options.python_tracer_level = 0
+        annotation = jax.profiler.TraceAnnotation
     except Exception as e:  # jax missing or too old
         raise DeviceProfilerUnavailable(f"jax profiler unavailable: {e}")
     if not _device_lock.acquire(blocking=False):
@@ -275,14 +285,24 @@ def device_trace(seconds: float = 2.0) -> bytes:
     import tarfile
     import tempfile
 
+    from seaweedfs_tpu.stats import trace
+
     tmpdir = tempfile.mkdtemp(prefix="sw-jax-trace-")
     try:
-        jax.profiler.start_trace(tmpdir)
-        time.sleep(clamp_seconds(seconds))
-        jax.profiler.stop_trace()
+        jax.profiler.start_trace(tmpdir, profiler_options=options)
+        trace._annotation = annotation
+        try:
+            time.sleep(clamp_seconds(seconds))
+        finally:
+            trace._annotation = None
+            t0 = time.perf_counter()
+            jax.profiler.stop_trace()
         buf = io.BytesIO()
         with tarfile.open(fileobj=buf, mode="w:gz") as tf:
             tf.add(tmpdir, arcname="jax-trace")
+        # what a trace costs the server it is taken from
+        glog.info("device trace of %.2fs: %.2fs to stop and archive, %d bytes",
+                  seconds, time.perf_counter() - t0, buf.tell())
         return buf.getvalue()
     finally:
         _device_lock.release()

@@ -14,7 +14,16 @@ encode/decode and MD5/CRC32C hash kernels and feed Prometheus histograms
 (`SeaweedFS_volume_ec_encode_seconds`, `..._decode_seconds`,
 `SeaweedFS_filer_hash_seconds`) plus bytes-throughput counters, so a
 BENCH run can compute GB/s per kernel from `/metrics` alone:
-`rate = <family>_bytes_total / <family>_seconds_sum`.
+`rate = <family>_bytes_total / <family>_seconds_sum`. `phase` is the same
+for paths too hot for a ring span: seconds, bytes and, where asked, the
+calling thread's CPU seconds.
+
+While `stats.profiler.device_trace` runs, every span and phase also enters
+a `jax.profiler.TraceAnnotation` of its own name, so the program's spans
+lie in the `.xplane.pb` on the device events' clock. This module never
+imports jax: the profiler hands the annotation class in (`_annotation`)
+for as long as its trace runs, and the rest of the time a span pays one
+read of that attribute.
 
 The motivation follows arXiv:1709.05365 (per-stage EC cost attribution
 across the I/O path) and arXiv:1202.3669 (measure the offload boundary
@@ -41,6 +50,24 @@ KERNEL_BUCKETS = DEFAULT_BUCKETS + (30.0, 60.0)
 EC_ENCODE_SECONDS = "SeaweedFS_volume_ec_encode_seconds"
 EC_DECODE_SECONDS = "SeaweedFS_volume_ec_decode_seconds"
 FILER_HASH_SECONDS = "SeaweedFS_filer_hash_seconds"
+# the volume server's /admin/ec/* handlers and their steps, label `op`
+EC_ADMIN_SECONDS = "SeaweedFS_volume_ec_admin_seconds"
+EC_ADMIN_OPS = (
+    "readonly", "generate", "delete_shards", "mount", "delete_volume",
+    "rebuild", "copy",
+    # a step nested in a handler: <handler>.<step>
+    "generate.quiesce", "generate.encode", "generate.ecx", "generate.vif",
+    "rebuild.encode",
+)
+# the host's side of the jax backend's transfers and dispatch (ops/rs_kernel)
+EC_DEVICE_SECONDS = "SeaweedFS_volume_ec_device_seconds"
+EC_DEVICE_KERNELS = ("h2d", "dispatch", "d2h-wait")
+# families of phases whose label is not `kernel` and that count no bytes
+_FAMILY_LABEL = {EC_ADMIN_SECONDS: "op"}
+
+# jax.profiler.TraceAnnotation between a device trace's start and stop
+# (set by stats.profiler.device_trace), else None
+_annotation = None
 
 _local = threading.local()
 
@@ -89,10 +116,21 @@ def with_trace_headers(headers: dict | None) -> dict | None:
     return out
 
 
+def _enter_annotation(name: str):
+    """The entered device-trace annotation of one span or phase, or None
+    when no device trace runs. Whoever gets one leaves it on this thread."""
+    cls = _annotation
+    if cls is None:
+        return None
+    ann = cls(name)
+    ann.__enter__()
+    return ann
+
+
 class Span:
     __slots__ = (
         "trace_id", "span_id", "parent_id", "name", "role",
-        "start", "duration", "status", "attrs", "_prev_ctx",
+        "start", "duration", "status", "attrs", "_prev_ctx", "_ann",
     )
 
     def __init__(self, trace_id: str, span_id: str, parent_id: str | None,
@@ -107,6 +145,7 @@ class Span:
         self.status = ""
         self.attrs = dict(attrs) if attrs else {}
         self._prev_ctx = None
+        self._ann = None  # device-trace annotation, see start_span
 
     def to_dict(self) -> dict:
         return {
@@ -181,7 +220,8 @@ class TraceCollector:
         (e.g. from incoming headers), the thread's active span becomes the
         parent; a thread with no context starts a fresh trace. With
         activate=True the new span becomes the thread's context until
-        finish_span restores the previous one."""
+        finish_span restores the previous one. The thread that opens a
+        span also finishes it (the device-trace annotation is per thread)."""
         ctx = getattr(_local, "ctx", None)
         if trace_id is None:
             if parent_id is None and ctx is not None:
@@ -189,6 +229,7 @@ class TraceCollector:
             else:
                 trace_id = _new_id()
         sp = Span(trace_id, _new_id(), parent_id, name, role, attrs)
+        sp._ann = _enter_annotation(name)  # finish_span leaves it
         with self._lock:
             self._inflight[sp.span_id] = sp
         if activate:
@@ -199,6 +240,9 @@ class TraceCollector:
     def finish_span(self, span: Span, status: str = "ok") -> None:
         span.duration = time.time() - span.start
         span.status = status
+        if span._ann is not None:
+            span._ann.__exit__(None, None, None)
+            span._ann = None
         # a span marked noise=True only enters the ring when it joined a
         # caller's trace — periodic chatter (unsampled heartbeats) must
         # not churn real request traces out of the bounded buffer
@@ -310,9 +354,19 @@ def annotate(**attrs) -> None:
 
 # --- span helpers -------------------------------------------------------------
 @contextmanager
-def span(name: str, role: str | None = None, **attrs):
-    """Generic traced section; nested client calls become children."""
-    sp = _collector.start_span(name, role=role, attrs=attrs)
+def span(name: str, role: str | None = None,
+         parent: tuple[str, str] | None = None, **attrs):
+    """Generic traced section; nested client calls become children. With
+    `parent`, a `current()` taken on another thread, the span joins that
+    trace as its child without becoming this thread's context: for worker
+    threads, which carry no context of their own."""
+    if parent is None:
+        sp = _collector.start_span(name, role=role, attrs=attrs)
+    else:
+        sp = _collector.start_span(
+            name, role=role, trace_id=parent[0], parent_id=parent[1],
+            attrs=attrs, activate=False,
+        )
     try:
         yield sp
     except BaseException:
@@ -358,11 +412,13 @@ def end_server_span(span: Span, status_code: int) -> None:
 
 # --- kernel profiling ---------------------------------------------------------
 _kernel_metrics_cache: dict[str, tuple] = {}
+_cpu_counters: dict = {}
 _kernel_metrics_lock = threading.Lock()
 
 
 def _kernel_metrics(family: str) -> tuple:
-    """(seconds histogram, bytes counter) for one kernel metric family."""
+    """(seconds histogram, bytes counter or None) for one kernel metric
+    family."""
     pair = _kernel_metrics_cache.get(family)  # lock-free hot path (GIL-
     if pair is not None:  # atomic dict read); lock only for registration
         return pair
@@ -370,18 +426,41 @@ def _kernel_metrics(family: str) -> tuple:
         pair = _kernel_metrics_cache.get(family)
         if pair is None:
             reg = default_registry()
+            label = _FAMILY_LABEL.get(family, "kernel")
             hist = reg.histogram(
-                family, "kernel execution seconds", ("kernel",),
+                family, "kernel execution seconds", (label,),
                 buckets=KERNEL_BUCKETS,
             )
-            ctr = reg.counter(
-                family[: -len("_seconds")] + "_bytes_total"
-                if family.endswith("_seconds") else family + "_bytes_total",
-                "bytes processed by the kernel", ("kernel",),
-            )
+            ctr = None
+            if family not in _FAMILY_LABEL:
+                ctr = reg.counter(
+                    _sibling(family, "_bytes_total"),
+                    "bytes processed by the kernel", (label,),
+                )
             pair = (hist, ctr)
             _kernel_metrics_cache[family] = pair
         return pair
+
+
+def _sibling(family: str, suffix: str) -> str:
+    """`<family without _seconds><suffix>`: the counters beside a seconds
+    histogram."""
+    stem = family[: -len("_seconds")] if family.endswith("_seconds") else family
+    return stem + suffix
+
+
+def _cpu_counter(family: str):
+    """`<family>_cpu_seconds_total`: thread CPU seconds beside the wall
+    seconds of `family`, for phases that ask for them."""
+    ctr = _cpu_counters.get(family)
+    if ctr is None:
+        ctr = default_registry().counter(  # get-or-create under its lock
+            _sibling(family, "_cpu_seconds_total"),
+            "thread CPU seconds spent in the kernel's host code",
+            (_FAMILY_LABEL.get(family, "kernel"),),
+        )
+        _cpu_counters[family] = ctr
+    return ctr
 
 
 def observe_kernel(family: str, kernel: str, seconds: float, nbytes: int = 0) -> None:
@@ -389,8 +468,45 @@ def observe_kernel(family: str, kernel: str, seconds: float, nbytes: int = 0) ->
     call would flood the ring buffer."""
     hist, ctr = _kernel_metrics(family)
     hist.labels(kernel).observe(seconds)
-    if nbytes:
+    if nbytes and ctr is not None:
         ctr.labels(kernel).inc(nbytes)
+
+
+class phase:
+    """Metrics-only timed section for hot paths, beside `kernel_span`: no
+    ring span. On a clean exit it observes the wall seconds (and `nbytes`)
+    under `family{kernel}` through `observe_kernel`, and with `cpu=True`
+    adds the calling thread's CPU seconds to `<family>_cpu_seconds_total`.
+    `kernel` and `nbytes` may be set on the yielded object before the exit,
+    where they are only known mid-flight. With `family=None` it counts
+    nothing. Either way, while a device trace runs the section is in the
+    trace under `name`."""
+
+    __slots__ = ("name", "family", "kernel", "nbytes", "_cpu", "_t0", "_c0",
+                 "_ann")
+
+    def __init__(self, name: str, family: str | None = None, kernel: str = "",
+                 nbytes: int = 0, cpu: bool = False) -> None:
+        self.name, self.family, self.kernel = name, family, kernel
+        self.nbytes, self._cpu = nbytes, cpu
+
+    def __enter__(self) -> "phase":
+        self._ann = _enter_annotation(self.name)
+        if self._cpu:
+            self._c0 = time.thread_time()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        dt = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        if exc_type is not None or self.family is None:
+            return
+        observe_kernel(self.family, self.kernel, dt, self.nbytes)
+        if self._cpu:
+            _cpu_counter(self.family).labels(self.kernel).inc(
+                time.thread_time() - self._c0)
 
 
 @contextmanager

@@ -14,7 +14,6 @@ from __future__ import annotations
 
 import os
 import threading
-import time
 
 import numpy as np
 
@@ -225,13 +224,14 @@ class EcVolume:
         data = self._fetch_remote(shard_id, off, interval.size)
         if data is not None:
             return data
-        t0 = time.perf_counter()
-        data = self._recover_interval(shard_id, off, interval.size)
-        # which kernel reconstructs degraded reads here, and how many bytes
-        trace.observe_kernel(
-            trace.EC_DECODE_SECONDS, "reconstruct-" + self.codec.kernel_label,
-            time.perf_counter() - t0, interval.size,
-        )
+        # wall and this thread's CPU seconds of the reconstruction, and how
+        # many bytes, under the kernel that reconstructs degraded reads here
+        with trace.phase(
+            "ec.reconstruct", trace.EC_DECODE_SECONDS, nbytes=interval.size,
+            cpu=True,
+        ) as ph:
+            data = self._recover_interval(shard_id, off, interval.size)
+            ph.kernel = "reconstruct-" + self.codec.kernel_label
         return data
 
     def _recover_interval(self, missing_shard: int, off: int, size: int) -> bytes:

@@ -71,7 +71,8 @@ _QUEUE_DEPTH = 2
 # wait observation (blocked on the bounded queues / buffer freelist), so
 # /metrics alone answers which stage is the bottleneck and at what
 # utilization (busy_sum / (busy_sum + wait_sum)). The write stage's busy
-# time includes blocking on the encode handle's parity (device drain).
+# time includes blocking on the encode handle's parity (device drain):
+# that half is SeaweedFS_volume_ec_device_seconds{kernel="d2h-wait"}.
 # The fused single-pass engine has no stages; it reports stage="fused".
 EC_PIPELINE_SECONDS = "SeaweedFS_volume_ec_pipeline_seconds"
 
@@ -237,12 +238,30 @@ class _ShardWriters:
                 pass
 
 
-def _run_pipeline(jobs, read_job, encode_job, write_job) -> None:
+def _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes=None) -> None:
     """reader thread -> encode (caller thread) -> writer thread, with
     bounded queues, a shared buffer freelist for backpressure, and a stop
     flag so a failure in any stage unwinds the other two instead of
     deadlocking on a full/empty queue. Every batch feeds the per-stage
-    busy/wait histograms (EC_PIPELINE_SECONDS above)."""
+    busy/wait histograms (EC_PIPELINE_SECONDS above) and, where the caller
+    runs under a span, leaves one ring span per stage as that span's child
+    (`ec.pipeline.read`, `.encode`, `.write`; attrs `batch`, `thread` and,
+    with `job_bytes(job)`, `bytes`), whichever thread did the work."""
+    parent = trace.current()  # worker threads carry no context of their own
+    batches = {"read": 0, "encode": 0, "write": 0}  # each its own thread's
+
+    def staged(stage: str, fn, job, *args):
+        if parent is None:
+            return fn(job, *args)
+        attrs = {"batch": batches[stage],
+                 "thread": threading.current_thread().name}
+        batches[stage] += 1
+        if job_bytes is not None:
+            attrs["bytes"] = job_bytes(job)
+        with trace.span("ec.pipeline." + stage, role="volume", parent=parent,
+                        **attrs):
+            return fn(job, *args)
+
     read_q: queue.Queue = queue.Queue(maxsize=_QUEUE_DEPTH)
     write_q: queue.Queue = queue.Queue(maxsize=_QUEUE_DEPTH)
     free: queue.Queue = queue.Queue()
@@ -272,7 +291,7 @@ def _run_pipeline(jobs, read_job, encode_job, write_job) -> None:
                 t0 = perf()
                 slot = free.get()
                 t1 = perf()
-                buf = read_job(job, slot)
+                buf = staged("read", read_job, job, slot)
                 t2 = perf()
                 ok = _put(read_q, (job, buf))
                 o_wait.observe((t1 - t0) + (perf() - t2))
@@ -296,7 +315,7 @@ def _run_pipeline(jobs, read_job, encode_job, write_job) -> None:
                 if item is None:
                     return
                 job, buf, handle = item
-                write_job(job, buf, handle)
+                staged("write", write_job, job, buf, handle)
                 o_wait.observe(t1 - t0)
                 o_busy.observe(perf() - t1)
                 free.put(buf)
@@ -323,7 +342,7 @@ def _run_pipeline(jobs, read_job, encode_job, write_job) -> None:
             if item is None:
                 break
             job, buf = item
-            handle = encode_job(job, buf)
+            handle = staged("encode", encode_job, job, buf)
             t2 = perf()
             write_q.put((job, buf, handle))
             o_wait.observe((t1 - t0) + (perf() - t2))
@@ -502,39 +521,49 @@ def _write_ec_files_pipeline(
             )
 
         def write_job(job, buf, handle):
-            parity = handle.result()
-            if job[0] == "rows":
-                _, _, shard_off, block, nrows = job
-                span = nrows * block
-                for p in range(PARITY_SHARDS_COUNT):
-                    writers.pwrite(
-                        DATA_SHARDS_COUNT + p, parity[p, :span], shard_off
-                    )
-                view = buf[: span * DATA_SHARDS_COUNT].reshape(
-                    nrows, DATA_SHARDS_COUNT, block
-                )
-                for c in range(DATA_SHARDS_COUNT):
-                    if nrows == 1:
-                        writers.pwrite(c, view[0, c], shard_off)
-                    else:
-                        writers.pwritev(
-                            c,
-                            [view[r, c] for r in range(nrows)],
-                            shard_off,
+            # the two halves of the write stage's busy time: the wait for
+            # the device (kernel drain and D2H, counted as `d2h-wait` where
+            # the handle is the device's) and the shard writes
+            with trace.phase("ec.pipeline.write.drain"):
+                parity = handle.result()
+            with trace.phase("ec.pipeline.write.pwrite"):
+                if job[0] == "rows":
+                    _, _, shard_off, block, nrows = job
+                    span = nrows * block
+                    for p in range(PARITY_SHARDS_COUNT):
+                        writers.pwrite(
+                            DATA_SHARDS_COUNT + p, parity[p, :span], shard_off
                         )
-            else:
-                _, _, shard_off, block, done, width = job
-                view = buf[: width * DATA_SHARDS_COUNT].reshape(
-                    DATA_SHARDS_COUNT, width
-                )
-                for c in range(DATA_SHARDS_COUNT):
-                    writers.pwrite(c, view[c], shard_off + done)
-                for p in range(PARITY_SHARDS_COUNT):
-                    writers.pwrite(
-                        DATA_SHARDS_COUNT + p, parity[p, :width], shard_off + done
+                    view = buf[: span * DATA_SHARDS_COUNT].reshape(
+                        nrows, DATA_SHARDS_COUNT, block
                     )
+                    for c in range(DATA_SHARDS_COUNT):
+                        if nrows == 1:
+                            writers.pwrite(c, view[0, c], shard_off)
+                        else:
+                            writers.pwritev(
+                                c,
+                                [view[r, c] for r in range(nrows)],
+                                shard_off,
+                            )
+                else:
+                    _, _, shard_off, block, done, width = job
+                    view = buf[: width * DATA_SHARDS_COUNT].reshape(
+                        DATA_SHARDS_COUNT, width
+                    )
+                    for c in range(DATA_SHARDS_COUNT):
+                        writers.pwrite(c, view[c], shard_off + done)
+                    for p in range(PARITY_SHARDS_COUNT):
+                        writers.pwrite(
+                            DATA_SHARDS_COUNT + p, parity[p, :width],
+                            shard_off + done,
+                        )
 
-        _run_pipeline(jobs, read_job, encode_job, write_job)
+        def job_bytes(job) -> int:
+            per_shard = job[3] * job[4] if job[0] == "rows" else job[5]
+            return per_shard * DATA_SHARDS_COUNT
+
+        _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes)
     except BaseException:
         writers.abort()
         raise
@@ -665,11 +694,16 @@ def _rebuild_ec_files(
 
             def write_job(job, buf, handle):
                 off, width = job
-                out = handle.result()
-                for i, sid in enumerate(missing):
-                    writers.pwrite(sid, out[i, :width], off)
+                with trace.phase("ec.pipeline.write.drain"):
+                    out = handle.result()
+                with trace.phase("ec.pipeline.write.pwrite"):
+                    for i, sid in enumerate(missing):
+                        writers.pwrite(sid, out[i, :width], off)
 
-            _run_pipeline(jobs, read_job, encode_job, write_job)
+            _run_pipeline(
+                jobs, read_job, encode_job, write_job,
+                lambda job: job[1] * DATA_SHARDS_COUNT,
+            )
         except BaseException:
             writers.abort()
             raise

@@ -305,6 +305,44 @@ class TestPrometheusExposition:
         assert ("rt_seconds_bucket", {"le": "+Inf"}, 1.0) in bucket
 
 
+class TestHistogramBuckets:
+    """An observation is one bisect and one increment; the page still shows
+    cumulative `le` counts."""
+
+    @pytest.mark.parametrize("values,want", [
+        ([], None),
+        ([0.05, 0.1], [2, 2, 2, 2]),            # a bound belongs to its bucket
+        ([0.1000001, 1.0, 4.9], [0, 2, 3, 3]),
+        ([7.0, 1e9], [0, 0, 0, 2]),             # past the largest bound
+        ([0.05, 0.5, 2.0, 7.0], [1, 2, 3, 4]),
+    ])
+    def test_rendered_counts_are_cumulative(self, values, want):
+        from seaweedfs_tpu.stats.metrics import Registry, parse_exposition
+
+        reg = Registry()
+        h = reg.histogram("t_seconds", "", ("k",), buckets=(0.1, 1.0, 5.0))
+        for v in values:
+            h.labels("a").observe(v)
+        got = [v for n, lab, v in parse_exposition(reg.render())
+               if n == "t_seconds_bucket"]
+        assert got == (want or [])
+        if values:
+            page = {(n, lab.get("k")): v for n, lab, v in
+                    parse_exposition(reg.render()) if "bucket" not in n}
+            assert page[("t_seconds_count", "a")] == len(values)
+            assert page[("t_seconds_sum", "a")] == pytest.approx(sum(values))
+
+    def test_label_children_are_kept_and_stringified(self):
+        from seaweedfs_tpu.stats.metrics import Registry
+
+        reg = Registry()
+        c = reg.counter("t_total", "", ("code",))
+        assert c.labels(200) is c.labels(200)
+        c.labels(200).inc()
+        c.labels("200").inc(2)
+        assert 't_total{code="200"} 3' in reg.render()
+
+
 class TestMetricNameLint:
     """tools/check_metric_names.py — the namespace cannot drift (tier-1)."""
 
@@ -425,6 +463,34 @@ class TestMetricNameLint:
         assert "SeaweedFS_qos_limit_rps" in collector_names
         assert "SeaweedFS_qos_gate" in collector_names
         assert tool.qos_violations() == []
+        # PR-26: the phase families (a verb's handlers and steps, the jax
+        # backend's transfers and dispatch), the CPU counters beside wall
+        # seconds, and their closed label sets
+        assert kinds["SeaweedFS_volume_ec_admin_seconds"] == "histogram"
+        assert "SeaweedFS_volume_ec_admin_bytes_total" not in kinds
+        assert kinds["SeaweedFS_volume_ec_device_seconds"] == "histogram"
+        assert kinds["SeaweedFS_volume_ec_device_bytes_total"] == "counter"
+        assert kinds["SeaweedFS_volume_ec_decode_cpu_seconds_total"] \
+            == "counter"
+        assert kinds["SeaweedFS_http_request_cpu_seconds_total"] == "counter"
+        assert "SeaweedFS_process_cpu_seconds_total" in collector_names
+        assert tool.phase_label_violations() == []
+
+    @pytest.mark.parametrize("attr,value,complaint", [
+        ("EC_ADMIN_OPS", ("generate", "generate"), "duplicate"),
+        ("EC_ADMIN_OPS", ("seal.encode",), "undeclared handler"),
+        ("EC_ADMIN_OPS", ("Generate",), "malformed"),
+        ("EC_ADMIN_OPS", ("generate", "generate.fsync"), "never writes it"),
+        ("EC_DEVICE_KERNELS", ("h2d", "d2h_wait"), "malformed"),
+        ("EC_DEVICE_KERNELS", ("h2d", "copy-back"), "never writes it"),
+    ])
+    def test_phase_label_lint_catches_violations(
+            self, monkeypatch, attr, value, complaint):
+        from seaweedfs_tpu.stats import trace
+
+        tool = self._tool()
+        monkeypatch.setattr(trace, attr, value)
+        assert any(complaint in b for b in tool.phase_label_violations())
 
     def test_qos_lint_catches_violations(self, monkeypatch):
         from seaweedfs_tpu.qos import admission as qos_mod

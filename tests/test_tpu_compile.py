@@ -103,7 +103,9 @@ def test_pallas_transform_compiles(one_chip, matrix, n):
         functools.partial(rs_pallas.gf_matmul_pallas, matrix),
         _u8((matrix.shape[1], n), one_chip),
     )
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "%rs_gf_matmul" in text  # the name the device trace shows
 
 
 def test_pipeline_encode_rows_compiles(one_chip):

@@ -69,8 +69,10 @@ def collect() -> tuple[dict[str, str], list[str]]:
 
     # force the lazily-registered families into the registry
     for fam in (trace.EC_ENCODE_SECONDS, trace.EC_DECODE_SECONDS,
-                trace.FILER_HASH_SECONDS, crc.VOLUME_CRC32C_SECONDS):
+                trace.FILER_HASH_SECONDS, crc.VOLUME_CRC32C_SECONDS,
+                trace.EC_ADMIN_SECONDS, trace.EC_DEVICE_SECONDS):
         trace._kernel_metrics(fam)
+    trace._cpu_counter(trace.EC_DECODE_SECONDS)  # ..._decode_cpu_seconds_total
     ec_encoder._pipeline_hist()  # SeaweedFS_volume_ec_pipeline_seconds
     from seaweedfs_tpu.storage.erasure_coding import online as ec_online
 
@@ -110,10 +112,12 @@ def collect() -> tuple[dict[str, str], list[str]]:
     from seaweedfs_tpu.stats import aggregate as aggregate_mod
     from seaweedfs_tpu.stats import events as events_mod
     from seaweedfs_tpu.stats import heat as heat_mod
+    from seaweedfs_tpu.stats import metrics as metrics_mod
     from seaweedfs_tpu.stats import usage as usage_mod
 
     collector_names = sorted(
         set(MasterServer.MASTER_METRIC_FAMILIES)
+        | set(metrics_mod.PROCESS_FAMILIES)
         | set(VolumeServer.FL_FAMILIES)
         | set(FilerServer.FL_FRONT_FAMILIES)
         | set(S3Server.FL_FRONT_FAMILIES)
@@ -703,6 +707,42 @@ def qos_violations() -> list[str]:
     return bad
 
 
+PHASE_OP_RE = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z][a-z0-9_]*)?$")
+PHASE_KERNEL_RE = re.compile(r"^[a-z][a-z0-9]*(-[a-z0-9]+)*$")
+
+
+def phase_label_violations() -> list[str]:
+    """The closed label sets of the PR-26 phase families: the `op` values of
+    SeaweedFS_volume_ec_admin_seconds (a handler, or `<handler>.<step>` with
+    a declared handler) and the `kernel` values of
+    SeaweedFS_volume_ec_device_seconds — unique, well-formed, and each one
+    written by the module that owns the seam, so a renamed value cannot
+    silently empty the benchmark's per-layer metrics that read it."""
+    from seaweedfs_tpu.stats import trace
+
+    bad: list[str] = []
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for what, names, regex, seam in (
+        ("ec admin op", trace.EC_ADMIN_OPS, PHASE_OP_RE,
+         os.path.join("seaweedfs_tpu", "server", "volume.py")),
+        ("ec device kernel", trace.EC_DEVICE_KERNELS, PHASE_KERNEL_RE,
+         os.path.join("seaweedfs_tpu", "ops", "rs_kernel.py")),
+    ):
+        with open(os.path.join(root, seam)) as f:
+            src = f.read()
+        for name in names:
+            if not regex.match(name):
+                bad.append(f"{what} {name!r}: malformed")
+            if names.count(name) > 1:
+                bad.append(f"{what} {name!r}: duplicate")
+            if "." in name and name.split(".")[0] not in names:
+                bad.append(f"{what} {name!r}: step of an undeclared handler")
+            if f'"{name}"' not in src:
+                bad.append(f"{what} {name!r}: declared but {seam} never"
+                           f" writes it")
+    return bad
+
+
 def violations(kinds: dict[str, str], collector_names: list[str]) -> list[str]:
     bad: list[str] = []
     for name in sorted(set(kinds) | set(collector_names)):
@@ -731,7 +771,8 @@ def main() -> int:
         + stream_lazy_violations() \
         + event_type_violations() + slo_violations() + scrub_violations() \
         + usage_heat_violations() + cluster_telemetry_violations() \
-        + telemetry_violations() + qos_violations()
+        + telemetry_violations() + qos_violations() \
+        + phase_label_violations()
     total = len(set(kinds) | set(collector_names))
     if bad:
         print(f"{len(bad)} metric-name violation(s) in {total} families:")
