@@ -27,7 +27,7 @@ import numpy as np
 
 from seaweedfs_tpu.stats import trace
 
-from . import device, gf256
+from . import device, gf256, rs_pallas
 
 DATA_SHARDS = 10
 PARITY_SHARDS = 4
@@ -82,34 +82,60 @@ def _cached_bit_matrix(matrix_bytes: bytes, rows: int, cols: int) -> np.ndarray:
     return gf256.bit_matrix(m)
 
 
+def _enqueue(matrix: np.ndarray, shards, chunk: int = DEFAULT_CHUNK):
+    """`gf_matmul_jax`, and beside its result the number of device programs
+    the call enqueued. A host array goes to the jitted program as it is,
+    which does its own transfer."""
+    if transform_kernel() == "pallas":
+        return rs_pallas.enqueue(matrix, shards, rs_pallas.TILE)
+    jnp = device.jax().numpy
+    rows, cols = matrix.shape
+    a = _cached_bit_matrix(matrix.tobytes(), rows, cols)
+    fn = _compiled_transform(rows, cols, a.tobytes())
+    on_host = isinstance(shards, np.ndarray)
+    if on_host:
+        shards = np.asarray(shards, dtype=np.uint8)
+    else:
+        shards = jnp.asarray(shards, dtype=jnp.uint8)
+    n = shards.shape[1]
+    if n <= chunk:
+        return fn(shards), 1
+    outs = [fn(shards[:, i : i + chunk]) for i in range(0, n, chunk)]
+    # a device array is cut by a slice program per chunk, a host array by numpy
+    return jnp.concatenate(outs, axis=1), len(outs) * (1 if on_host else 2) + 1
+
+
 def gf_matmul_jax(matrix: np.ndarray, shards, chunk: int = DEFAULT_CHUNK):
     """out[r] = XOR_c matrix[r,c] x shards[c] on the accelerator.
 
     matrix: (rows, cols) uint8 numpy (host). shards: (cols, n) uint8 —
-    numpy or jax array. Returns a jax array (rows, n) uint8 (device).
+    numpy or jax array, any n. Returns a jax array (rows, n) uint8 (device).
     """
-    jnp = device.jax().numpy
-    rows, cols = matrix.shape
-    if transform_kernel() == "pallas":
-        from . import rs_pallas
-
-        return rs_pallas.gf_matmul_pallas(matrix, shards)
-    a = _cached_bit_matrix(matrix.tobytes(), rows, cols)
-    fn = _compiled_transform(rows, cols, a.tobytes())
-    shards = jnp.asarray(shards, dtype=jnp.uint8)
-    n = shards.shape[1]
-    if n <= chunk:
-        return fn(shards)
-    outs = [fn(shards[:, i : i + chunk]) for i in range(0, n, chunk)]
-    return jnp.concatenate(outs, axis=1)
+    return _enqueue(matrix, shards, chunk)[0]
 
 
 def _dispatch(matrix: np.ndarray, shards):
     """`gf_matmul_jax` as the codec calls it, with the host's seconds in the
-    call (pad, kernel and slice enqueued; the put too, where `shards` is
-    still on the host) counted under `dispatch`."""
+    call counted under `dispatch` and the device programs it enqueued under
+    `SeaweedFS_volume_ec_device_programs_total`: the kernel alone where
+    `shards` is a host array of tile-multiple width (`_apply_jax`; the
+    program does the transfer) or a device array of one; pad, kernel and
+    slice where a device array's width is not."""
     with trace.phase("rs.dispatch", trace.EC_DEVICE_SECONDS, "dispatch"):
-        return gf_matmul_jax(matrix, shards)
+        out, programs = _enqueue(matrix, shards)
+    trace.device_programs_counter().inc(programs)
+    return out
+
+
+def _apply_jax(matrix: np.ndarray, rows) -> np.ndarray:
+    """The transform of host bytes — a (cols, n) array or a sequence of cols
+    (n,) arrays — as one device program and one copy back: the width is
+    brought to a multiple of the kernel's tile on the host and taken back on
+    the host, so nothing compiles per length."""
+    n = len(rows[0])
+    if not isinstance(rows, np.ndarray) or n % rs_pallas.TILE:
+        rows = rs_pallas.zero_tailed(rows, rs_pallas.TILE)
+    return _JaxHandle(_dispatch(matrix, rows), n).result()
 
 
 class RSCodec:
@@ -167,7 +193,7 @@ class RSCodec:
 
     def _apply(self, matrix: np.ndarray, shards: np.ndarray) -> np.ndarray:
         if self.backend == "jax":
-            return _JaxHandle(_dispatch(matrix, shards)).result()
+            return _apply_jax(matrix, shards)
         if self.backend == "native":
             from seaweedfs_tpu.native import lib
 
@@ -200,9 +226,14 @@ class RSCodec:
         m = gf256.decode_matrix(
             self.data_shards, self.parity_shards, tuple(present), tuple(targets)
         )
-        use = present[: self.data_shards]
-        stack = np.stack([np.asarray(shards[i], dtype=np.uint8) for i in use])
-        out = self._apply(m, stack)
+        rows = [
+            np.asarray(shards[i], dtype=np.uint8)
+            for i in present[: self.data_shards]
+        ]
+        if self.backend == "jax":  # stacks them itself, with a zero tail
+            out = _apply_jax(m, rows)
+        else:
+            out = self._apply(m, np.stack(rows))
         return {t: out[i] for i, t in enumerate(targets)}
 
     def verify(self, shards: np.ndarray) -> bool:
@@ -219,7 +250,9 @@ class RSCodec:
     def apply2d_async(self, matrix: np.ndarray, data: np.ndarray):
         """data: C-contiguous (cols, n) uint8. Handle yields (rows, n)."""
         if self.backend == "jax":
-            return _JaxHandle(_dispatch(matrix, _device_put_2d(data)))
+            return _JaxHandle(
+                _dispatch(matrix, _device_put_2d(data)), data.shape[1]
+            )
         if self.backend == "native":
             from seaweedfs_tpu.native import lib
 
@@ -243,7 +276,7 @@ class RSCodec:
             with jax.named_scope("rs.rows_transpose"):
                 x = x.reshape(row_count, self.data_shards, block)
                 x = jnp.transpose(x, (1, 0, 2)).reshape(self.data_shards, -1)
-            return _JaxHandle(_dispatch(m, x))
+            return _JaxHandle(_dispatch(m, x), row_count * block)
         if self.backend == "native":
             from seaweedfs_tpu.native import lib
 
@@ -269,18 +302,21 @@ class _ReadyHandle:
 
 
 class _JaxHandle:
-    def __init__(self, dev) -> None:
-        self._dev = dev
+    def __init__(self, dev, n: int) -> None:
+        self._dev = dev  # (rows, >= n): wider by the zero tail of a host input
+        self._n = n
 
     def result(self) -> np.ndarray:
-        """The host's copy: blocks until the device has drained what was
-        enqueued before it and the bytes have come back (`d2h-wait`)."""
+        """The host's copy, (rows, n): blocks until the device has drained
+        what was enqueued before it and the bytes have come back
+        (`d2h-wait`, whose bytes are the ones that crossed), then drops the
+        tail's columns, as a view."""
         with trace.phase(
             "rs.d2h_wait", trace.EC_DEVICE_SECONDS, "d2h-wait"
         ) as ph:
             out = np.asarray(self._dev)
             ph.nbytes = out.nbytes
-        return out
+        return out[:, : self._n]
 
 
 # Host arrays above this size are put on the device in pieces of this size

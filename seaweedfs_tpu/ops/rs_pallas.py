@@ -92,27 +92,67 @@ def _compiled(rows: int, cols: int, at_bytes: bytes, tile: int):
     return rs_gf_matmul
 
 
-def gf_matmul_pallas(matrix: np.ndarray, shards, tile: int = TILE):
-    """out[r] = XOR_c matrix[r,c] x shards[c] — fused TPU kernel.
+def zero_tailed(rows, tile: int) -> np.ndarray:
+    """`rows` — a (cols, n) array or a sequence of cols (n,) arrays — as one
+    C-contiguous (cols, n rounded up to a multiple of `tile`) uint8 host
+    array, zero beyond column n: one copy per row, as `np.stack` makes.
 
-    matrix: (rows, cols) uint8 host array; shards: (cols, n) uint8 (device or
-    host). n is padded to a tile multiple internally (zero bytes encode to
-    zero parity, so the tail slice is exact). Returns device (rows, n).
-    """
+    The copies go through a memoryview and so keep the interpreter lock:
+    numpy gives it up around each copy of more than 500 bytes, and under
+    sixteen reader threads getting it back ten times a read costs several
+    times the copies themselves (PERF.md, PR 27)."""
+    n = len(rows[0])
+    width = n + (-n) % tile
+    buf = bytearray(len(rows) * width)  # zeroed
+    flat = memoryview(buf)
+    for i, row in enumerate(rows):
+        flat[i * width : i * width + n] = row
+    return np.frombuffer(buf, dtype=np.uint8).reshape(len(rows), width)
+
+
+def enqueue(matrix: np.ndarray, shards, tile: int):
+    """`gf_matmul_pallas`, and beside its result the number of device
+    programs the call enqueued: 1 for the kernel, 1 more for a pad on the
+    device, 1 more for a slice on the device."""
     jnp = device.jax().numpy
 
     matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
     rows, cols = matrix.shape
     at = _plane_major_bits(matrix.tobytes(), rows, cols)
     fn = _compiled(rows, cols, at, tile)
-    shards = jnp.asarray(shards, dtype=jnp.uint8)
     n = shards.shape[1]
     pad = (-n) % tile
-    # no named scope around the pad and the slice: this is the per-read
-    # path, where two scopes cost the read cell a percent (PERF.md, PR 26);
-    # the trace knows them as `jit__pad` and `jit_dynamic_slice`
-    if pad:
-        shards = jnp.pad(shards, ((0, 0), (0, pad)))
+    # the zero tail is written where the bytes are. A host array goes to the
+    # jitted program as it is, which does its own transfer: no put, and with
+    # a width that is a tile multiple no pad and no slice either
+    on_host = isinstance(shards, np.ndarray)
+    if on_host:
+        shards = np.asarray(shards, dtype=np.uint8)
+        if pad:
+            shards = zero_tailed(shards, tile)
+    else:
+        shards = jnp.asarray(shards, dtype=jnp.uint8)
+        # no named scope around the pad and the slice: two scopes cost a
+        # read a percent (PERF.md, PR 26); the trace knows the two programs
+        # as `jit__pad` and `jit_dynamic_slice`
+        if pad:
+            shards = jnp.pad(shards, ((0, 0), (0, pad)))
     out = fn(shards)
-    return out[:, :n] if pad else out
+    if not pad:
+        return out, 1
+    return out[:, :n], 2 if on_host else 3
 
+
+def gf_matmul_pallas(matrix: np.ndarray, shards, tile: int = TILE):
+    """out[r] = XOR_c matrix[r,c] x shards[c] — fused TPU kernel.
+
+    matrix: (rows, cols) uint8 host array; shards: (cols, n) uint8, on the
+    device or on the host, any n. The kernel sees tile multiples only: where
+    n is not one, a zero tail is written on the side of the transfer where
+    the bytes are (zero bytes transform to zero bytes, so the result is
+    exact) and taken off again by a slice on the device. A host array whose
+    width is a tile multiple is one device program and no put of its own:
+    that is what the codec's door hands in (`rs_kernel._dispatch`, which
+    takes the tail off on the host). Returns device (rows, n).
+    """
+    return enqueue(matrix, shards, tile)[0]

@@ -62,6 +62,9 @@ EC_ADMIN_OPS = (
 # the host's side of the jax backend's transfers and dispatch (ops/rs_kernel)
 EC_DEVICE_SECONDS = "SeaweedFS_volume_ec_device_seconds"
 EC_DEVICE_KERNELS = ("h2d", "dispatch", "d2h-wait")
+# device programs those dispatches enqueued: 1 a call where the kernel runs
+# alone, 3 where a pad and a slice on the device go with it
+EC_DEVICE_PROGRAMS = "SeaweedFS_volume_ec_device_programs_total"
 # families of phases whose label is not `kernel` and that count no bytes
 _FAMILY_LABEL = {EC_ADMIN_SECONDS: "op"}
 
@@ -412,7 +415,7 @@ def end_server_span(span: Span, status_code: int) -> None:
 
 # --- kernel profiling ---------------------------------------------------------
 _kernel_metrics_cache: dict[str, tuple] = {}
-_cpu_counters: dict = {}
+_counters: dict = {}
 _kernel_metrics_lock = threading.Lock()
 
 
@@ -452,14 +455,25 @@ def _sibling(family: str, suffix: str) -> str:
 def _cpu_counter(family: str):
     """`<family>_cpu_seconds_total`: thread CPU seconds beside the wall
     seconds of `family`, for phases that ask for them."""
-    ctr = _cpu_counters.get(family)
+    ctr = _counters.get(family)
     if ctr is None:
         ctr = default_registry().counter(  # get-or-create under its lock
             _sibling(family, "_cpu_seconds_total"),
             "thread CPU seconds spent in the kernel's host code",
             (_FAMILY_LABEL.get(family, "kernel"),),
         )
-        _cpu_counters[family] = ctr
+        _counters[family] = ctr
+    return ctr
+
+
+def device_programs_counter():
+    """`SeaweedFS_volume_ec_device_programs_total`, registered on first use."""
+    ctr = _counters.get(EC_DEVICE_PROGRAMS)
+    if ctr is None:
+        ctr = _counters[EC_DEVICE_PROGRAMS] = default_registry().counter(
+            EC_DEVICE_PROGRAMS,
+            "device programs enqueued by the jax backend's dispatches",
+        )
     return ctr
 
 

@@ -470,6 +470,7 @@ class TestMetricNameLint:
         assert "SeaweedFS_volume_ec_admin_bytes_total" not in kinds
         assert kinds["SeaweedFS_volume_ec_device_seconds"] == "histogram"
         assert kinds["SeaweedFS_volume_ec_device_bytes_total"] == "counter"
+        assert kinds["SeaweedFS_volume_ec_device_programs_total"] == "counter"
         assert kinds["SeaweedFS_volume_ec_decode_cpu_seconds_total"] \
             == "counter"
         assert kinds["SeaweedFS_http_request_cpu_seconds_total"] == "counter"

@@ -3,8 +3,9 @@
 import numpy as np
 import pytest
 
-from seaweedfs_tpu.ops import gf256
+from seaweedfs_tpu.ops import device, gf256, rs_pallas
 from seaweedfs_tpu.ops.rs_kernel import RSCodec, gf_matmul_jax
+from seaweedfs_tpu.stats import default_registry, trace
 
 
 class TestGF256:
@@ -133,3 +134,69 @@ class TestJaxChunking:
         whole = np.asarray(gf_matmul_jax(m, data))
         chunked = np.asarray(gf_matmul_jax(m, data, chunk=96))
         assert np.array_equal(whole, chunked)
+
+
+# --- the jax door: host bytes in, one device program, host bytes out ----------
+# tests/test_rs_pallas.py runs the same checks through the Pallas form.
+
+
+def door_widths(tile: int) -> tuple[int, ...]:
+    """Around a tile and around the read cell's intervals: 8191, 8192, 8193,
+    27720, 65576 and 73728 at the kernel's own tile."""
+    return (1, tile - 1, tile, tile + 1, 3 * tile + 3144 * tile // 8192,
+            8 * tile + 40, 9 * tile)
+
+
+def device_programs() -> float:
+    page = default_registry().render()
+    return sum(float(line.split()[-1]) for line in page.splitlines()
+               if line.startswith(trace.EC_DEVICE_PROGRAMS))
+
+
+def compile_requests() -> int:
+    return device.report()["compiles"]["requests"]
+
+
+def check_door_against_oracle(n: int, lost: int) -> None:
+    """encode and reconstruct of host bytes through RSCodec(backend="jax"),
+    byte for byte against the numpy oracle."""
+    rng = np.random.RandomState(n * 16 + lost)
+    data = rng.randint(0, 256, size=(10, n), dtype=np.uint8)
+    oracle, codec = RSCodec(backend="numpy"), RSCodec(backend="jax")
+    shards = oracle.encode_all(data)
+    parity = codec.encode(data)
+    assert parity.shape == (4, n) and parity.dtype == np.uint8
+    assert np.array_equal(parity, shards[10:])
+    surviving = {i: shards[i] for i in range(14) if i != lost}
+    got = codec.reconstruct(surviving, targets=[lost])
+    assert list(got) == [lost] and got[lost].shape == (n,)
+    assert np.array_equal(got[lost], shards[lost])
+
+
+def check_one_bucket_one_program(n_first: int, n_second: int) -> None:
+    """Two host-input reconstructs whose lengths share a tile bucket: the
+    second compiles nothing, and each is exactly one device program."""
+    codec = RSCodec(backend="jax")
+    rng = np.random.RandomState(n_first)
+    seen = []
+    for n in (n_first, n_second):
+        shards = RSCodec(backend="numpy").encode_all(
+            rng.randint(0, 256, size=(10, n), dtype=np.uint8))
+        surviving = {i: shards[i] for i in range(14) if i != 3}
+        before = device_programs()
+        got = codec.reconstruct(surviving, targets=[3])
+        assert np.array_equal(got[3], shards[3])
+        assert device_programs() - before == 1
+        seen.append(compile_requests())
+    assert seen[1] == seen[0]
+
+
+@pytest.mark.parametrize("lost", [3, 11], ids=["lost-data", "lost-parity"])
+@pytest.mark.parametrize("n", door_widths(rs_pallas.TILE))
+def test_door_host_bytes_match_the_oracle(n, lost):
+    assert door_widths(8192) == (1, 8191, 8192, 8193, 27720, 65576, 73728)
+    check_door_against_oracle(n, lost)
+
+
+def test_door_lengths_of_one_bucket_share_one_program():
+    check_one_bucket_one_program(8192 + 40, 2 * 8192 - 7)

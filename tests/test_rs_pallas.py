@@ -14,7 +14,13 @@ import functools
 import numpy as np
 import pytest
 
-from seaweedfs_tpu.ops import gf256, rs_pallas
+from seaweedfs_tpu.ops import gf256, rs_kernel, rs_pallas
+from tests.test_rs_codec import (
+    check_door_against_oracle,
+    check_one_bucket_one_program,
+    device_programs,
+    door_widths,
+)
 
 DATA, PARITY = 10, 4
 TILE = 512  # interpret mode walks the grid in Python: keep the steps few
@@ -69,3 +75,48 @@ def test_kernel_matches_numpy_oracle(interpreted, matrix, n):
     want = gf256.gf_matmul_bytes(matrix, shards)
     assert got.shape == want.shape and got.dtype == np.uint8
     assert np.array_equal(got, want)
+
+
+# --- the codec's door over the Pallas form -------------------------------------
+@pytest.fixture()
+def pallas_door(interpreted, monkeypatch):
+    """RSCodec(backend="jax") as it runs on a TPU: the Pallas form (here
+    interpreted), at this file's tile."""
+    monkeypatch.setattr(rs_kernel, "transform_kernel", lambda: "pallas")
+    monkeypatch.setattr(rs_pallas, "TILE", TILE)
+
+
+@pytest.mark.parametrize("lost", [3, 11], ids=["lost-data", "lost-parity"])
+@pytest.mark.parametrize("n", door_widths(TILE))
+def test_door_host_bytes_match_the_oracle(pallas_door, n, lost):
+    check_door_against_oracle(n, lost)
+
+
+def test_door_lengths_of_one_bucket_share_one_program(pallas_door):
+    check_one_bucket_one_program(TILE + 40, 2 * TILE - 7)
+
+
+@pytest.mark.parametrize("form,programs", [("xla", 1), ("pallas", 3)])
+@pytest.mark.parametrize("entry", ["apply2d_async", "encode_rows_async"])
+def test_device_array_path_is_what_it_was(request, entry, form, programs):
+    """The pipelines put their bytes on the device themselves; a width that
+    is no tile multiple is then padded and sliced there, as before: pad,
+    kernel and slice in the Pallas form, the one program of any width in the
+    XLA form."""
+    if form == "pallas":
+        request.getfixturevalue("pallas_door")
+    codec = rs_kernel.RSCodec(backend="jax")
+    rows, block = 3, TILE // 2 + 11
+    rng = np.random.RandomState(block)
+    buf = rng.randint(0, 256, size=rows * DATA * block, dtype=np.uint8)
+    data = np.ascontiguousarray(
+        buf.reshape(rows, DATA, block).transpose(1, 0, 2)).reshape(DATA, -1)
+    assert data.shape[1] % TILE and data.shape[1] % rs_pallas.TILE
+    before = device_programs()
+    if entry == "apply2d_async":
+        got = codec.apply2d_async(gf256.parity_rows(DATA, PARITY), data).result()
+    else:
+        got = codec.encode_rows_async(buf, block, rows).result()
+    assert device_programs() - before == programs
+    assert np.array_equal(
+        got, gf256.gf_matmul_bytes(gf256.parity_rows(DATA, PARITY), data))

@@ -266,6 +266,7 @@ FAMILIES = [
     ("SeaweedFS_volume_ec_device_seconds_count", "kernel",
      {"h2d", "dispatch", "d2h-wait"}),
     ("SeaweedFS_volume_ec_device_bytes_total", "kernel", {"h2d", "d2h-wait"}),
+    ("SeaweedFS_volume_ec_device_programs_total", None, None),
     ("SeaweedFS_volume_ec_decode_cpu_seconds_total", "kernel", None),
     ("SeaweedFS_http_request_cpu_seconds_total", "role", {"volume", "master"}),
     ("SeaweedFS_http_request_cpu_seconds_total", "method", {"GET", "POST"}),

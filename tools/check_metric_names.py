@@ -73,6 +73,7 @@ def collect() -> tuple[dict[str, str], list[str]]:
                 trace.EC_ADMIN_SECONDS, trace.EC_DEVICE_SECONDS):
         trace._kernel_metrics(fam)
     trace._cpu_counter(trace.EC_DECODE_SECONDS)  # ..._decode_cpu_seconds_total
+    trace.device_programs_counter()  # SeaweedFS_volume_ec_device_programs_total
     ec_encoder._pipeline_hist()  # SeaweedFS_volume_ec_pipeline_seconds
     from seaweedfs_tpu.storage.erasure_coding import online as ec_online
 
