@@ -11,7 +11,8 @@ threshold is dropped to zero.
 The module also holds what a process knows about its device side, for
 `GET /status` on the volume server and for `chip_smoke.py` to read:
 the devices jax sees (only once jax has been started), the compile
-counters, and every failure that made a backend selection skip a candidate.
+counters, how many kernel shapes the RS transform has run, and every
+failure that made a backend selection skip a candidate.
 """
 
 from __future__ import annotations
@@ -32,6 +33,10 @@ _jax = None
 _cache: dict = {}
 _compiles = {"requests": 0, "seconds": 0.0, "cache_hits": 0}
 _selection_failures: dict[str, str] = {}
+# (coefficient matrix, rows, cols, width) of every call the RS transform has
+# made of its jitted programs. The bit matrix is baked into the program, so
+# each is one program built (or taken from the compile cache)
+_kernel_shapes: set[tuple[bytes, int, int, int]] = set()
 
 
 def cache_dir() -> tuple[str, str]:
@@ -111,6 +116,13 @@ def note_selection_failure(where: str, exc: BaseException) -> None:
         glog.warning("backend selection: %s failed: %s", where, cause)
 
 
+def note_kernel_shape(matrix: bytes, rows: int, cols: int, width: int) -> None:
+    """The RS transform is about to run the program of its (rows, cols)
+    coefficient matrix at `width` (`ops/rs_kernel`, `ops/rs_pallas`): a set
+    insert, on every call."""
+    _kernel_shapes.add((matrix, rows, cols, width))
+
+
 def _fullest_memory(devices) -> dict | None:
     """`memory_stats()` of the local device whose peak is highest, cut to
     the three numbers a reader sizes a deployment by; None where the backend
@@ -151,4 +163,5 @@ def report() -> dict:
         "cache_hits": compiles["cache_hits"],
         "seconds": round(compiles["seconds"], 3),
     }
+    out["kernel_shapes"] = len(_kernel_shapes)
     return out

@@ -84,25 +84,38 @@ def _cached_bit_matrix(matrix_bytes: bytes, rows: int, cols: int) -> np.ndarray:
 
 def _enqueue(matrix: np.ndarray, shards, chunk: int = DEFAULT_CHUNK):
     """`gf_matmul_jax`, and beside its result the number of device programs
-    the call enqueued. A host array goes to the jitted program as it is,
-    which does its own transfer."""
+    the call enqueued. A host array goes to the jitted program at a rung of
+    the kernel's ladder (`rs_pallas.zero_tailed`, in both forms), and the
+    program does its own transfer."""
     if transform_kernel() == "pallas":
         return rs_pallas.enqueue(matrix, shards, rs_pallas.TILE)
     jnp = device.jax().numpy
     rows, cols = matrix.shape
-    a = _cached_bit_matrix(matrix.tobytes(), rows, cols)
+    matrix_bytes = matrix.tobytes()
+    a = _cached_bit_matrix(matrix_bytes, rows, cols)
     fn = _compiled_transform(rows, cols, a.tobytes())
+    n = shards.shape[1]
     on_host = isinstance(shards, np.ndarray)
     if on_host:
-        shards = np.asarray(shards, dtype=np.uint8)
+        shards = rs_pallas.zero_tailed(shards, rs_pallas.TILE)
     else:
         shards = jnp.asarray(shards, dtype=jnp.uint8)
-    n = shards.shape[1]
-    if n <= chunk:
-        return fn(shards), 1
-    outs = [fn(shards[:, i : i + chunk]) for i in range(0, n, chunk)]
-    # a device array is cut by a slice program per chunk, a host array by numpy
-    return jnp.concatenate(outs, axis=1), len(outs) * (1 if on_host else 2) + 1
+    width = shards.shape[1]
+
+    def run(piece):
+        device.note_kernel_shape(matrix_bytes, rows, cols, piece.shape[1])
+        return fn(piece)
+
+    if width <= chunk:
+        out, programs = run(shards), 1
+    else:
+        outs = [run(shards[:, i : i + chunk]) for i in range(0, width, chunk)]
+        # a device array is cut by a slice program per chunk, a host array by
+        # numpy
+        out = jnp.concatenate(outs, axis=1)
+        programs = len(outs) * (1 if on_host else 2) + 1
+    # a host array's zero tail, taken off on the device
+    return (out, programs) if width == n else (out[:, :n], programs + 1)
 
 
 def gf_matmul_jax(matrix: np.ndarray, shards, chunk: int = DEFAULT_CHUNK):
@@ -118,9 +131,9 @@ def _dispatch(matrix: np.ndarray, shards):
     """`gf_matmul_jax` as the codec calls it, with the host's seconds in the
     call counted under `dispatch` and the device programs it enqueued under
     `SeaweedFS_volume_ec_device_programs_total`: the kernel alone where
-    `shards` is a host array of tile-multiple width (`_apply_jax`; the
-    program does the transfer) or a device array of one; pad, kernel and
-    slice where a device array's width is not."""
+    `shards` is a host array at a rung of the ladder (`_apply_jax`; the
+    program does the transfer) or a device array of tile-multiple width;
+    pad, kernel and slice where a device array's width is not."""
     with trace.phase("rs.dispatch", trace.EC_DEVICE_SECONDS, "dispatch"):
         out, programs = _enqueue(matrix, shards)
     trace.device_programs_counter().inc(programs)
@@ -130,11 +143,10 @@ def _dispatch(matrix: np.ndarray, shards):
 def _apply_jax(matrix: np.ndarray, rows) -> np.ndarray:
     """The transform of host bytes — a (cols, n) array or a sequence of cols
     (n,) arrays — as one device program and one copy back: the width is
-    brought to a multiple of the kernel's tile on the host and taken back on
-    the host, so nothing compiles per length."""
+    brought to a rung of the kernel's ladder (`rs_pallas.LADDER_TILES`) on
+    the host and taken back on the host, so nothing compiles per length."""
     n = len(rows[0])
-    if not isinstance(rows, np.ndarray) or n % rs_pallas.TILE:
-        rows = rs_pallas.zero_tailed(rows, rs_pallas.TILE)
+    rows = rs_pallas.zero_tailed(rows, rs_pallas.TILE)
     return _JaxHandle(_dispatch(matrix, rows), n).result()
 
 

@@ -65,6 +65,9 @@ EC_DEVICE_KERNELS = ("h2d", "dispatch", "d2h-wait")
 # device programs those dispatches enqueued: 1 a call where the kernel runs
 # alone, 3 where a pad and a slice on the device go with it
 EC_DEVICE_PROGRAMS = "SeaweedFS_volume_ec_device_programs_total"
+# bytes of EC read intervals by how each was served (EcVolume._read_interval)
+EC_READ_INTERVAL_BYTES = "SeaweedFS_volume_ec_read_interval_bytes_total"
+EC_READ_INTERVAL_SOURCES = ("local", "remote", "reconstruct")
 # families of phases whose label is not `kernel` and that count no bytes
 _FAMILY_LABEL = {EC_ADMIN_SECONDS: "op"}
 
@@ -466,15 +469,28 @@ def _cpu_counter(family: str):
     return ctr
 
 
-def device_programs_counter():
-    """`SeaweedFS_volume_ec_device_programs_total`, registered on first use."""
-    ctr = _counters.get(EC_DEVICE_PROGRAMS)
+def _plain_counter(name: str, help_text: str, labels: tuple = ()):
+    """A counter of this module, registered on first use."""
+    ctr = _counters.get(name)
     if ctr is None:
-        ctr = _counters[EC_DEVICE_PROGRAMS] = default_registry().counter(
-            EC_DEVICE_PROGRAMS,
-            "device programs enqueued by the jax backend's dispatches",
-        )
+        ctr = _counters[name] = default_registry().counter(
+            name, help_text, labels)
     return ctr
+
+
+def device_programs_counter():
+    """`SeaweedFS_volume_ec_device_programs_total`."""
+    return _plain_counter(
+        EC_DEVICE_PROGRAMS,
+        "device programs enqueued by the jax backend's dispatches")
+
+
+def read_interval_bytes_counter():
+    """`SeaweedFS_volume_ec_read_interval_bytes_total{source}`."""
+    return _plain_counter(
+        EC_READ_INTERVAL_BYTES,
+        "bytes of EC read intervals, by how the interval was served",
+        ("source",))
 
 
 def observe_kernel(family: str, kernel: str, seconds: float, nbytes: int = 0) -> None:

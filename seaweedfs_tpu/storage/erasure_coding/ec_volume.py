@@ -214,15 +214,19 @@ class EcVolume:
 
     def _read_interval(self, interval: Interval) -> bytes:
         """local shard -> remote shard -> reconstruct, the `store_ec.go`
-        readOneEcShardInterval ladder."""
+        readOneEcShardInterval ladder. The interval's bytes are counted
+        under the rung that served it: one counter update an interval."""
         shard_id, off = interval.to_shard_id_and_offset(
             self.large_block_size, self.small_block_size
         )
+        served = trace.read_interval_bytes_counter()
         data = self._pread_shard(shard_id, off, interval.size)
         if data is not None:
+            served.labels("local").inc(interval.size)
             return data
         data = self._fetch_remote(shard_id, off, interval.size)
         if data is not None:
+            served.labels("remote").inc(interval.size)
             return data
         # wall and this thread's CPU seconds of the reconstruction, and how
         # many bytes, under the kernel that reconstructs degraded reads here
@@ -232,6 +236,7 @@ class EcVolume:
         ) as ph:
             data = self._recover_interval(shard_id, off, interval.size)
             ph.kernel = "reconstruct-" + self.codec.kernel_label
+        served.labels("reconstruct").inc(interval.size)
         return data
 
     def _recover_interval(self, missing_shard: int, off: int, size: int) -> bytes:

@@ -475,6 +475,9 @@ class TestMetricNameLint:
             == "counter"
         assert kinds["SeaweedFS_http_request_cpu_seconds_total"] == "counter"
         assert "SeaweedFS_process_cpu_seconds_total" in collector_names
+        # PR-28: bytes of EC read intervals by the rung that served them
+        assert kinds["SeaweedFS_volume_ec_read_interval_bytes_total"] \
+            == "counter"
         assert tool.phase_label_violations() == []
 
     @pytest.mark.parametrize("attr,value,complaint", [
@@ -484,6 +487,8 @@ class TestMetricNameLint:
         ("EC_ADMIN_OPS", ("generate", "generate.fsync"), "never writes it"),
         ("EC_DEVICE_KERNELS", ("h2d", "d2h_wait"), "malformed"),
         ("EC_DEVICE_KERNELS", ("h2d", "copy-back"), "never writes it"),
+        ("EC_READ_INTERVAL_SOURCES", ("local", "local"), "duplicate"),
+        ("EC_READ_INTERVAL_SOURCES", ("local", "page-cache"), "never writes it"),
     ])
     def test_phase_label_lint_catches_violations(
             self, monkeypatch, attr, value, complaint):

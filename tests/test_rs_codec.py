@@ -3,7 +3,7 @@
 import numpy as np
 import pytest
 
-from seaweedfs_tpu.ops import device, gf256, rs_pallas
+from seaweedfs_tpu.ops import device, gf256, rs_kernel, rs_pallas
 from seaweedfs_tpu.ops.rs_kernel import RSCodec, gf_matmul_jax
 from seaweedfs_tpu.stats import default_registry, trace
 
@@ -200,3 +200,143 @@ def test_door_host_bytes_match_the_oracle(n, lost):
 
 def test_door_lengths_of_one_bucket_share_one_program():
     check_one_bucket_one_program(8192 + 40, 2 * 8192 - 7)
+
+
+# --- the ladder: a closed set of kernel widths up to one small block ----------
+# Host bytes reach the kernel at a rung of `rs_pallas.LADDER_TILES`, so a
+# degraded read of any length up to a block compiles nothing beyond the rungs.
+
+RUNGS = rs_pallas.LADDER_TILES
+LOST = 6
+# data shards without the lost one, and the first parity shard: ten survivors
+SURVIVORS = tuple(i for i in range(11) if i != LOST)
+LADDER_MATRIX = gf256.decode_matrix(10, 4, SURVIVORS, (LOST,)).tobytes()
+
+
+def test_ladder_is_small_closed_and_ends_at_one_small_block():
+    from seaweedfs_tpu.storage.erasure_coding.geometry import SMALL_BLOCK_SIZE
+
+    assert list(RUNGS) == sorted(set(RUNGS)) and len(RUNGS) <= 20
+    assert RUNGS[-1] * rs_pallas.TILE == SMALL_BLOCK_SIZE
+    assert RUNGS[:9] == tuple(range(1, 10))  # every width a 64 KiB needle makes
+    for lo, hi in zip(RUNGS[8:], RUNGS[9:]):  # a step up wastes under a third
+        assert (hi - lo) * 3 <= hi
+
+
+@pytest.mark.parametrize("n", [1, 40, 8191, 8192, 8193, 27720, 65536, 65576,
+                               73727, 73728])
+def test_ladder_maps_a_64k_needles_widths_as_before(n):
+    assert rs_pallas.ladder_width(n, 8192) == n + (-n) % 8192
+
+
+@pytest.mark.parametrize("n,want", [
+    (73729, 98304), (98304, 98304), (98305, 131072), (131073, 196608),
+    (700000, 786432), (786433, 1048576), (1048576, 1048576),
+    # beyond one small block (rows of large blocks): tile multiples, as before
+    (1048577, 1048576 + 8192), (4 * 1048576 + 5, 4 * 1048576 + 8192),
+])
+def test_ladder_above_a_64k_needle_and_beyond_a_block(n, want):
+    assert rs_pallas.ladder_width(n, 8192) == want
+
+
+def kernel_shapes() -> int:
+    return device.report()["kernel_shapes"]
+
+
+def ladder_reconstruct(codec: RSCodec, n: int, seed: int) -> None:
+    """Shard LOST of n seeded columns rebuilt through the codec's door from
+    nine data shards and one parity row of the numpy oracle, and held to the
+    bytes that were encoded."""
+    rng = np.random.Generator(np.random.SFC64([seed, n]))
+    data = rng.integers(0, 256, size=(10, n), dtype=np.uint8)
+    parity = gf256.gf_matmul_bytes(gf256.parity_rows(10, 4)[:1], data)
+    shards = {i: (data[i] if i < 10 else parity[0]) for i in SURVIVORS}
+    got = codec.reconstruct(shards, targets=[LOST])[LOST]
+    assert got.shape == (n,) and np.array_equal(got, data[LOST])
+
+
+def warm_ladder(tile: int) -> dict:
+    """One reconstruct at every rung; what the checks below start from."""
+    codec = RSCodec(backend="jax")
+    shapes = kernel_shapes() if device.started() else 0
+    for t in RUNGS:
+        ladder_reconstruct(codec, t * tile, seed=1)
+    return {"codec": codec, "tile": tile, "shapes_before": shapes}
+
+
+def check_ladder_widths(ladder: dict, widths) -> None:
+    """Each width goes to the kernel at a rung at or above it, with a zero
+    tail of at most a third above nine tiles and the tile multiple below; it
+    is one device program, compiles nothing and builds no new shape."""
+    codec, tile = ladder["codec"], ladder["tile"]
+    compiles, shapes = compile_requests(), kernel_shapes()
+    # the rungs' programs and no other, however many the process had before
+    assert shapes - ladder["shapes_before"] <= len(RUNGS) <= 20
+    assert all((LADDER_MATRIX, 1, 10, t * tile) in device._kernel_shapes
+               for t in RUNGS)
+    for n in widths:
+        width = rs_pallas.ladder_width(n, tile)
+        assert width >= n and width // tile in RUNGS and width % tile == 0
+        if n <= 9 * tile:
+            assert width == n + (-n) % tile
+        else:
+            assert (width - n) * 3 <= width
+        before = device_programs()
+        ladder_reconstruct(codec, n, seed=2)
+        assert device_programs() - before == 1
+    assert compile_requests() == compiles and kernel_shapes() == shapes
+
+
+def rung_neighbours(rung: int, tile: int) -> list[int]:
+    w = rung * tile
+    return [w - 1, w] + ([w + 1] if rung != RUNGS[-1] else [])
+
+
+def sweep_widths(seed: int, tile: int, count: int = 50) -> list[int]:
+    rng = np.random.Generator(np.random.SFC64([28, seed]))
+    return [int(n) for n in rng.integers(1, RUNGS[-1] * tile + 1, size=count)]
+
+
+@pytest.fixture(scope="module")
+def xla_ladder():
+    """The XLA form (the CPU's) at the kernel's own tile: rungs of 8 KiB to
+    1 MiB."""
+    return warm_ladder(rs_pallas.TILE)
+
+
+@pytest.mark.parametrize("rung", RUNGS)
+def test_ladder_rung_neighbours_share_the_rungs_programs(xla_ladder, rung):
+    check_ladder_widths(xla_ladder, rung_neighbours(rung, xla_ladder["tile"]))
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_ladder_sweep_of_widths_up_to_a_block_compiles_nothing(xla_ladder, seed):
+    check_ladder_widths(xla_ladder, sweep_widths(seed, xla_ladder["tile"]))
+
+
+def check_direct_host_array_goes_to_a_rung(tiles: int, tile: int) -> None:
+    """A host array handed to the kernel's own entry, not through the codec's
+    door, reaches it at a rung too: the zero tail on the host, a slice on the
+    device, and the oracle's bytes."""
+    n = tiles * tile
+    width = rs_pallas.ladder_width(n, tile)
+    assert width > n and width // tile in RUNGS
+    matrix = np.frombuffer(LADDER_MATRIX, dtype=np.uint8).reshape(1, 10)
+    rng = np.random.Generator(np.random.SFC64([28, tiles]))
+    data = rng.integers(0, 256, size=(10, n), dtype=np.uint8)
+    before = set(device._kernel_shapes)
+    out, programs = rs_kernel._enqueue(matrix, data)
+    assert programs == 2
+    assert set(device._kernel_shapes) - before <= {(LADDER_MATRIX, 1, 10, width)}
+    assert (LADDER_MATRIX, 1, 10, width) in device._kernel_shapes
+    got = np.asarray(out)
+    assert got.shape == (1, n)
+    assert np.array_equal(got, gf256.gf_matmul_bytes(matrix, data))
+
+
+OFF_THE_LADDER = [10, 11, 13, 100]  # tile multiples that are no rung
+
+
+@pytest.mark.parametrize("tiles", OFF_THE_LADDER)
+def test_host_array_off_the_ladder_goes_to_a_rung(tiles):
+    check_direct_host_array_goes_to_a_rung(tiles, rs_pallas.TILE)

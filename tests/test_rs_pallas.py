@@ -16,10 +16,17 @@ import pytest
 
 from seaweedfs_tpu.ops import gf256, rs_kernel, rs_pallas
 from tests.test_rs_codec import (
+    OFF_THE_LADDER,
+    RUNGS,
+    check_direct_host_array_goes_to_a_rung,
     check_door_against_oracle,
+    check_ladder_widths,
     check_one_bucket_one_program,
     device_programs,
     door_widths,
+    rung_neighbours,
+    sweep_widths,
+    warm_ladder,
 )
 
 DATA, PARITY = 10, 4
@@ -120,3 +127,38 @@ def test_device_array_path_is_what_it_was(request, entry, form, programs):
     assert device_programs() - before == programs
     assert np.array_equal(
         got, gf256.gf_matmul_bytes(gf256.parity_rows(DATA, PARITY), data))
+
+
+@pytest.mark.parametrize("tiles", OFF_THE_LADDER)
+def test_host_array_off_the_ladder_goes_to_a_rung(pallas_door, tiles):
+    check_direct_host_array_goes_to_a_rung(tiles, TILE)
+
+
+# --- the ladder over the Pallas form ---------------------------------------------
+@pytest.fixture(scope="module")
+def pallas_ladder():
+    """The Pallas form, interpreted, at this file's tile, with one reconstruct
+    made at every rung (512 bytes to 64 KiB here). Module-scoped, so the
+    rungs' programs live as long as the tests below; these come last in the
+    file, after every test that clears the kernel's cache."""
+    from jax.experimental import pallas as pl
+
+    mp = pytest.MonkeyPatch()
+    mp.setattr(pl, "pallas_call",
+               functools.partial(pl.pallas_call, interpret=True))
+    mp.setattr(rs_kernel, "transform_kernel", lambda: "pallas")
+    mp.setattr(rs_pallas, "TILE", TILE)
+    rs_pallas._compiled.cache_clear()
+    yield warm_ladder(TILE)
+    rs_pallas._compiled.cache_clear()
+    mp.undo()
+
+
+@pytest.mark.parametrize("rung", RUNGS)
+def test_ladder_rung_neighbours_share_the_rungs_programs(pallas_ladder, rung):
+    check_ladder_widths(pallas_ladder, rung_neighbours(rung, TILE))
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_ladder_sweep_of_widths_up_to_a_block_compiles_nothing(pallas_ladder, seed):
+    check_ladder_widths(pallas_ladder, sweep_widths(seed, TILE))

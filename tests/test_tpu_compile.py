@@ -85,6 +85,10 @@ PALLAS_CASES = [
     ("rebuild-4x10", _rebuild_matrix((0, 3, 11, 12)), DEVICE_BATCH),
     # degraded read of a 1 MiB needle: one interval, one missing shard
     ("reconstruct-1x10-interval", _rebuild_matrix((3,)), MiB + 40),
+    # degraded read of a 4 MiB chunk needle: one whole small block, the top
+    # rung of the door's ladder; and a rung in its middle
+    ("reconstruct-1x10-block", _rebuild_matrix((3,)), MiB),
+    ("reconstruct-1x10-rung-96k", _rebuild_matrix((3,)), 96 * 1024),
     # partial-sum repair hops: a holder's few columns of the decode matrix
     ("partial-sum-1x1", _rebuild_matrix((3,))[:, :1], 4 * MiB),
     ("partial-sum-1x3", _rebuild_matrix((3,))[:, :3], 4 * MiB),

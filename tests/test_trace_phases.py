@@ -268,6 +268,9 @@ FAMILIES = [
     ("SeaweedFS_volume_ec_device_bytes_total", "kernel", {"h2d", "d2h-wait"}),
     ("SeaweedFS_volume_ec_device_programs_total", None, None),
     ("SeaweedFS_volume_ec_decode_cpu_seconds_total", "kernel", None),
+    # every blob lies in the dropped shard's first block; the other rungs'
+    # labels are held to the layout in tests/test_ec_read_intervals.py
+    ("SeaweedFS_volume_ec_read_interval_bytes_total", "source", {"reconstruct"}),
     ("SeaweedFS_http_request_cpu_seconds_total", "role", {"volume", "master"}),
     ("SeaweedFS_http_request_cpu_seconds_total", "method", {"GET", "POST"}),
     ("SeaweedFS_process_cpu_seconds_total", None, None),
@@ -368,3 +371,15 @@ def test_status_has_memory_exactly_when_jax_is_started_and_reports_it(
         "bytes_in_use": 1, "peak_bytes_in_use": 2, "bytes_limit": 3}
     monkeypatch.setattr(device, "_jax", None)  # a process that never started jax
     assert "memory" not in device.report() and "jax" not in device.report()
+    assert "kernel_shapes" not in device.report()
+
+
+def test_status_counts_the_kernel_shapes_built(sealed):
+    """`ec.kernel_shapes`: the programs the RS transform has run since boot,
+    one per coefficient matrix and width. Here at least the pipeline's encode
+    and one reconstruct of the dropped shard; six 30 KB blobs reach the
+    kernel at a handful of rungs, never one per length."""
+    from seaweedfs_tpu.ops import device
+
+    shapes = sealed["status"]["ec"]["kernel_shapes"]
+    assert isinstance(shapes, int) and 2 <= shapes <= len(device._kernel_shapes)

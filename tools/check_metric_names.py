@@ -74,6 +74,7 @@ def collect() -> tuple[dict[str, str], list[str]]:
         trace._kernel_metrics(fam)
     trace._cpu_counter(trace.EC_DECODE_SECONDS)  # ..._decode_cpu_seconds_total
     trace.device_programs_counter()  # SeaweedFS_volume_ec_device_programs_total
+    trace.read_interval_bytes_counter()  # ..._ec_read_interval_bytes_total
     ec_encoder._pipeline_hist()  # SeaweedFS_volume_ec_pipeline_seconds
     from seaweedfs_tpu.storage.erasure_coding import online as ec_online
 
@@ -715,8 +716,9 @@ PHASE_KERNEL_RE = re.compile(r"^[a-z][a-z0-9]*(-[a-z0-9]+)*$")
 def phase_label_violations() -> list[str]:
     """The closed label sets of the PR-26 phase families: the `op` values of
     SeaweedFS_volume_ec_admin_seconds (a handler, or `<handler>.<step>` with
-    a declared handler) and the `kernel` values of
-    SeaweedFS_volume_ec_device_seconds — unique, well-formed, and each one
+    a declared handler), the `kernel` values of
+    SeaweedFS_volume_ec_device_seconds and the `source` values of
+    SeaweedFS_volume_ec_read_interval_bytes_total — unique, well-formed, each
     written by the module that owns the seam, so a renamed value cannot
     silently empty the benchmark's per-layer metrics that read it."""
     from seaweedfs_tpu.stats import trace
@@ -728,6 +730,9 @@ def phase_label_violations() -> list[str]:
          os.path.join("seaweedfs_tpu", "server", "volume.py")),
         ("ec device kernel", trace.EC_DEVICE_KERNELS, PHASE_KERNEL_RE,
          os.path.join("seaweedfs_tpu", "ops", "rs_kernel.py")),
+        ("ec read interval source", trace.EC_READ_INTERVAL_SOURCES,
+         PHASE_KERNEL_RE, os.path.join(
+             "seaweedfs_tpu", "storage", "erasure_coding", "ec_volume.py")),
     ):
         with open(os.path.join(root, seam)) as f:
             src = f.read()
