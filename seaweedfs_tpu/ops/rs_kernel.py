@@ -27,11 +27,13 @@ import numpy as np
 
 from seaweedfs_tpu.stats import trace
 
-from . import device, gf256, rs_pallas
+from seaweedfs_tpu.storage.erasure_coding.constants import (  # noqa: F401
+    DATA_SHARDS,
+    PARITY_SHARDS,
+    TOTAL_SHARDS,
+)
 
-DATA_SHARDS = 10
-PARITY_SHARDS = 4
-TOTAL_SHARDS = DATA_SHARDS + PARITY_SHARDS
+from . import device, gf256, rs_pallas
 
 # Default chunk: bound device memory per call; callers stream larger inputs.
 DEFAULT_CHUNK = 64 * 1024 * 1024

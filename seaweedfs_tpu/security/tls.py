@@ -25,6 +25,8 @@ import re
 import ssl
 from dataclasses import dataclass
 
+from seaweedfs_tpu.util import http_client
+
 
 @dataclass
 class TLSConfig:
@@ -45,7 +47,6 @@ class TLSConfig:
 
 
 _SERVER_CTX: ssl.SSLContext | None = None
-_CLIENT_CTX: ssl.SSLContext | None = None
 _ALLOWED_CNS: list[str] = []
 _CFG: TLSConfig | None = None  # file paths retained for the native engine
 
@@ -53,7 +54,7 @@ _CFG: TLSConfig | None = None  # file paths retained for the native engine
 def configure(cfg: TLSConfig) -> None:
     """Install mutual TLS process-wide (like the reference's security.toml:
     every listener and every outbound client in the process)."""
-    global _SERVER_CTX, _CLIENT_CTX, _ALLOWED_CNS, _CFG
+    global _SERVER_CTX, _ALLOWED_CNS, _CFG
     if cfg.partially_set:
         # fail CLOSED: a typo'd [tls] section must not silently run the
         # cluster as plaintext HTTP (the reference errors on cert-load
@@ -75,7 +76,7 @@ def configure(cfg: TLSConfig) -> None:
     client.check_hostname = False  # identity is the CA + CN, not the address
     client.verify_mode = ssl.CERT_REQUIRED
     _SERVER_CTX = server
-    _CLIENT_CTX = client
+    http_client.set_tls_context(client)
     _CFG = cfg
     _ALLOWED_CNS = [
         compile_cn_pattern(s.strip())
@@ -85,9 +86,9 @@ def configure(cfg: TLSConfig) -> None:
 
 
 def reset() -> None:
-    global _SERVER_CTX, _CLIENT_CTX, _ALLOWED_CNS, _CFG
+    global _SERVER_CTX, _ALLOWED_CNS, _CFG
     _SERVER_CTX = None
-    _CLIENT_CTX = None
+    http_client.set_tls_context(None)
     _ALLOWED_CNS = []
     _CFG = None
 
@@ -103,7 +104,7 @@ def server_context() -> ssl.SSLContext | None:
 
 
 def client_context() -> ssl.SSLContext | None:
-    return _CLIENT_CTX
+    return http_client.tls_context
 
 
 def compile_cn_pattern(pattern: str) -> re.Pattern:

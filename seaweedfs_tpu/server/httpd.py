@@ -13,7 +13,6 @@ import os
 import socket
 import threading
 import urllib.parse
-import urllib.request
 
 from seaweedfs_tpu.security import tls as _tls
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -900,90 +899,16 @@ def peer_url(hostport: str) -> str:
     return f"{scheme}://{hostport}"
 
 
-# --- tiny client helpers ----------------------------------------------------
-# Every outbound call in this repo routes through these helpers (or
-# PooledHTTP); the default timeout is the shared RetryPolicy one so no
-# call anywhere can hang a worker forever — callers pass their own only
-# to tighten (heartbeats) or loosen (volume copies).
-from seaweedfs_tpu.util.retry import DEFAULT_TIMEOUT as _DEFAULT_TIMEOUT
-
-
-def http_request(
-    method: str,
-    url: str,
-    body: bytes | None = None,
-    headers: dict | None = None,
-    timeout: float = _DEFAULT_TIMEOUT,
-) -> tuple[int, dict, bytes]:
-    from seaweedfs_tpu.stats import trace as _trace
-
-    headers = _trace.with_trace_headers(headers)
-    if url.startswith("http+unix://"):
-        return _unix_http_request(method, url, body, headers, timeout)
-    req = urllib.request.Request(url, data=body, method=method)
-    for k, v in (headers or {}).items():
-        req.add_header(k, v)
-    ctx = _tls.client_context() if url.startswith("https:") else None
-    try:
-        with urllib.request.urlopen(req, timeout=timeout, context=ctx) as resp:
-            return resp.status, dict(resp.headers), resp.read()
-    except urllib.error.HTTPError as e:
-        return e.code, dict(e.headers), e.read()
-
-
-def _unix_http_request(
-    method: str, url: str, body: bytes | None, headers: dict | None,
-    timeout: float,
-) -> tuple[int, dict, bytes]:
-    """HTTP over a unix domain socket. URL form
-    `http+unix://<percent-encoded-socket-path><request-path>` — the same
-    convention requests-unix-socket/docker clients use. Server side:
-    HTTPService.enable_unix_socket (`-filer.localSocket`)."""
-    import http.client
-    import socket as _socket
-
-    rest = url[len("http+unix://"):]
-    sock_quoted, _, path_qs = rest.partition("/")
-    sock_path = urllib.parse.unquote(sock_quoted)
-
-    class _Conn(http.client.HTTPConnection):
-        def __init__(self) -> None:
-            super().__init__("localhost", timeout=timeout)
-
-        def connect(self) -> None:
-            s = _socket.socket(_socket.AF_UNIX, _socket.SOCK_STREAM)
-            s.settimeout(timeout)
-            s.connect(sock_path)
-            self.sock = s
-
-    conn = _Conn()
-    try:
-        conn.request(method, "/" + path_qs, body=body,
-                     headers=dict(headers or {}))
-        resp = conn.getresponse()
-        return resp.status, dict(resp.headers), resp.read()
-    finally:
-        conn.close()
-
-
-def get_json(url: str, timeout: float = _DEFAULT_TIMEOUT) -> dict:
-    status, _, body = http_request("GET", url, timeout=timeout)
-    data = json.loads(body) if body else {}
-    if status >= 400:
-        raise IOError(f"GET {url} -> {status}: {data}")
-    return data
-
-
-def post_json(url: str, payload: dict | None = None,
-              timeout: float = _DEFAULT_TIMEOUT) -> dict:
-    body = json.dumps(payload or {}).encode()
-    status, _, out = http_request(
-        "POST", url, body, {"Content-Type": "application/json"}, timeout
-    )
-    data = json.loads(out) if out else {}
-    if status >= 400:
-        raise IOError(f"POST {url} -> {status}: {data}")
-    return data
+# --- client helpers -----------------------------------------------------------
+# The one-shot client (`http_request`, `get_json`, `post_json`) is
+# `util.http_client`, a module the admin shell can import without this one;
+# servers and every other caller keep taking the names from here.
+from seaweedfs_tpu.util.http_client import (  # noqa: E402,F401
+    DEFAULT_TIMEOUT as _DEFAULT_TIMEOUT,
+    get_json,
+    http_request,
+    post_json,
+)
 
 
 class PooledHTTP:

@@ -5,6 +5,8 @@ from __future__ import annotations
 
 import json
 
+from seaweedfs_tpu.util.http_client import get_json, http_request, post_json
+
 from .env import CommandEnv, ShellError
 from .registry import command, parse_flags
 
@@ -38,7 +40,6 @@ def cmd_cluster_ps(env: CommandEnv, args: list[str]) -> str:
 
 def _scrape(url: str) -> list:
     """GET <url>/metrics -> parsed (name, labels, value) samples."""
-    from seaweedfs_tpu.server.httpd import http_request
     from seaweedfs_tpu.stats import parse_exposition
 
     status, _, body = http_request("GET", f"{url}/metrics", timeout=10)
@@ -1837,7 +1838,6 @@ def cmd_mount_configure(env: CommandEnv, args: list[str]) -> str:
     import urllib.parse as _u
 
     from seaweedfs_tpu.mount import admin_socket_path
-    from seaweedfs_tpu.server.httpd import get_json, post_json
 
     flags = parse_flags(args)
     mp = flags.get("dir")

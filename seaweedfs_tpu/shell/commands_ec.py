@@ -19,13 +19,15 @@ import json
 import time
 import urllib.parse
 
-from seaweedfs_tpu.storage.erasure_coding import decoder as ec_decoder
+from seaweedfs_tpu.storage.erasure_coding import repair_names
+from seaweedfs_tpu.storage.erasure_coding.constants import (
+    DATA_SHARDS,
+    TOTAL_SHARDS,
+)
+from seaweedfs_tpu.util.http_client import http_request
 
 from .env import CommandEnv, ServerView, ShellError
 from .registry import command, dry_run_flag, parse_flags, render_plan
-
-TOTAL_SHARDS = 14
-DATA_SHARDS = 10
 
 # partial chunk ceiling: ranges per chain pass. Big enough to amortize
 # the hop HTTP overhead, small enough that a mid-chain death retries
@@ -242,7 +244,7 @@ def apply_rebuild(env: CommandEnv, plan: dict) -> list[int]:
     pulls are flagged `repair` so the rebuilder counts them into
     ec_repair_bytes_on_wire{mode="classic"} — the baseline the pipelined
     mode is measured against."""
-    _, mseconds, _, _ = ec_decoder.repair_metrics()
+    _, mseconds, _, _ = repair_names.repair_metrics()
     vid, collection = plan["volume"], plan["collection"]
     rb = plan["rebuilder_url"]
     t0 = time.perf_counter()
@@ -278,7 +280,7 @@ def apply_rebuild(env: CommandEnv, plan: dict) -> list[int]:
 
 class PipelinedRebuildError(ShellError):
     """A pipelined rebuild could not complete; `reason` is one of
-    decoder.REPAIR_FALLBACK_REASONS and the caller falls back to classic."""
+    repair_names.REPAIR_FALLBACK_REASONS and the caller falls back to classic."""
 
     def __init__(self, reason: str, detail: str = "") -> None:
         super().__init__(f"pipelined rebuild failed ({reason}): {detail}")
@@ -291,10 +293,13 @@ def plan_rebuild_pipelined(
     exclude: tuple[str, ...] = (),
     prefer_rebuilder: str | None = None,
 ) -> dict | None:
-    """The partial-sum chain plan: decode coefficients per holder, hops
-    ordered with the rebuilder LAST (it lands the accumulated sum in its
-    /admin/ec/partial/start state). `exclude` drops dead hops on a chain
-    restart. `prefer_rebuilder` pins the writer on restarts: the
+    """The partial-sum chain plan: which holder contributes which shards,
+    hops ordered with the rebuilder LAST (it lands the accumulated sum in
+    its /admin/ec/partial/start state). Laid out from the shard ids alone:
+    `auto` weighs a plan by its hops, and the decode coefficients are
+    computed when one is rendered or applied (`fill_rebuild_coefficients`).
+    `exclude` drops dead hops on a chain restart. `prefer_rebuilder` pins
+    the writer on restarts: the
     committed frontier lives in the old rebuilder's partial state, and
     the (shard-count, free_slots) ranking can flip between plans while
     volumes move underneath — switching writers would silently discard
@@ -318,7 +323,9 @@ def plan_rebuild_pipelined(
             f"volume {vid}: only {len(usable)} usable shards"
             f" (excluding {list(exclude)}), cannot rebuild"
         )
-    use, matrix = ec_decoder.repair_coefficients(usable, missing)
+    # the canonical subset full decode reads (decoder.repair_coefficients
+    # makes the same choice: sorted, the first 10)
+    use = usable[:DATA_SHARDS]
     rebuilder = next(
         (sv for sv in holders if sv.id == prefer_rebuilder), None
     ) or max(
@@ -342,11 +349,6 @@ def plan_rebuild_pipelined(
             continue  # nothing to contribute, not the writer: skip the hop
         chain.append({
             "server": sv.id, "url": sv.http, "shards": own,
-            "coefs": {
-                str(s): [int(matrix[t, use.index(s)])
-                         for t in range(len(missing))]
-                for s in own
-            },
             "write": sv.id == rebuilder.id,
         })
     return {
@@ -357,7 +359,27 @@ def plan_rebuild_pipelined(
     }
 
 
+def fill_rebuild_coefficients(plan: dict) -> None:
+    """Give every hop of a pipelined plan its `coefs`: per contributed
+    shard, the GF(2^8) factor towards each missing one. The one place of
+    the verb that needs the decoder (a 10 x 10 inverse, and numpy with
+    it), so it is imported here, when a pipelined plan is rendered or
+    applied, and a repair that `auto` hands to classic never loads it."""
+    if all("coefs" in hop for hop in plan["chain"]):
+        return
+    from seaweedfs_tpu.storage.erasure_coding import decoder
+
+    use, matrix = decoder.repair_coefficients(plan["use"], plan["missing"])
+    for hop in plan["chain"]:
+        hop["coefs"] = {
+            str(s): [int(matrix[t, use.index(s)])
+                     for t in range(len(plan["missing"]))]
+            for s in hop["shards"]
+        }
+
+
 def describe_rebuild_pipelined(plan: dict) -> list[str]:
+    fill_rebuild_coefficients(plan)
     steps = []
     for hop in plan["chain"]:
         if hop["write"]:
@@ -432,13 +454,14 @@ def apply_rebuild_pipelined(
     streaming session mode (hop-parallel, ~(hops + chunks) chunk-times);
     True/False forces. `chunk=None` sizes chunks via auto_chunk() off
     the real shard size. Returns (rebuilt shard ids, wire stats)."""
-    _, mseconds, _, mrestarts = ec_decoder.repair_metrics()
+    _, mseconds, _, mrestarts = repair_names.repair_metrics()
     excluded: list[str] = []
     restarts = 0
     strikes = {r: 0 for r in ("crc_mismatch", "chunk_crc", "stream_stall")}
     rb_url = plan["rebuilder_url"]
     try:
         while True:
+            fill_rebuild_coefficients(plan)
             try:
                 return _run_chain(env, plan, chunk, mseconds, restarts,
                                   stream=stream, window=window,
@@ -447,7 +470,7 @@ def apply_rebuild_pipelined(
                 raise
             except _HopFailed as e:
                 reason = e.reason \
-                    if e.reason in ec_decoder.REPAIR_RESTART_REASONS \
+                    if e.reason in repair_names.REPAIR_RESTART_REASONS \
                     else "hop_failed"
                 mrestarts.labels(reason).inc()
                 from seaweedfs_tpu.stats import events as events_mod
@@ -523,7 +546,7 @@ def _json_or_empty(out: bytes) -> dict:
 
 def _reason_of(resp: dict) -> str:
     err = resp.get("error", "")
-    return err if err in ec_decoder.REPAIR_RESTART_REASONS else "hop_failed"
+    return err if err in repair_names.REPAIR_RESTART_REASONS else "hop_failed"
 
 
 def _run_chain(env, plan, chunk, mseconds, restarts, stream=None,
@@ -556,7 +579,7 @@ def _run_chain(env, plan, chunk, mseconds, restarts, stream=None,
         # its own shards; the chunk POSTs carry empty bodies), so there
         # are no wire savings to count.
         saved = committed * len(targets) * (len(chain) - 1)
-        ec_decoder.stream_metrics()[1].inc(saved)
+        repair_names.stream_metrics()[1].inc(saved)
     use_stream = stream if stream is not None else (
         len(chain) > 1 and shard_size - committed > chunk)
     t1 = time.perf_counter()
@@ -603,8 +626,6 @@ def _serial_chunks(env, plan, chunk, shard_size, committed):
     """One nested chain pass per chunk (the pre-streaming dataflow, kept
     for single-chunk repairs, 1-hop chains and as the forced-comparison
     baseline the bench measures the streaming win against)."""
-    from seaweedfs_tpu.server.httpd import http_request
-
     vid, collection = plan["volume"], plan["collection"]
     chain = plan["chain"]
     targets = plan["missing"]
@@ -646,8 +667,6 @@ def _stream_chunks(env, plan, chunk, window, shard_size, committed,
     reports per-hop wire/read accounting + the writer's committed
     frontier (the resume point when anything failed)."""
     import uuid
-
-    from seaweedfs_tpu.server.httpd import http_request
 
     vid, collection = plan["volume"], plan["collection"]
     chain = plan["chain"]
@@ -754,7 +773,7 @@ def _run_rebuild(
     env: CommandEnv, vid: int, collection: str, mode: str,
     pressure: dict | None, dry_run: bool, stream: bool | None = None,
 ) -> dict:
-    if mode not in ("auto",) + ec_decoder.REPAIR_MODES:
+    if mode not in ("auto",) + repair_names.REPAIR_MODES:
         raise ShellError(f"mode must be auto|classic|pipelined, got {mode}")
     plan = plan_rebuild(env, vid, collection)
     if plan is None:
@@ -771,11 +790,11 @@ def _run_rebuild(
     if mode == "auto":
         mode, _why = choose_rebuild_mode(pplan, pressure)
         if mode == "classic" and pplan is not None:
-            ec_decoder.repair_metrics()[2].labels("too_few_holders").inc()
+            repair_names.repair_metrics()[2].labels("too_few_holders").inc()
             events_mod.emit("fallback_repair", volume=vid,
                             reason="too_few_holders")
     if mode == "pipelined" and pplan is None:
-        ec_decoder.repair_metrics()[2].labels("insufficient_shards").inc()
+        repair_names.repair_metrics()[2].labels("insufficient_shards").inc()
         events_mod.emit("fallback_repair", volume=vid,
                         reason="insufficient_shards")
         mode = "classic"
@@ -792,7 +811,7 @@ def _run_rebuild(
                     "rebuilt": rebuilt, "rebuilder": pplan["rebuilder"],
                     "stats": stats}
         except PipelinedRebuildError as e:
-            ec_decoder.repair_metrics()[2].labels(e.reason).inc()
+            repair_names.repair_metrics()[2].labels(e.reason).inc()
             events_mod.emit("fallback_repair", volume=vid, reason=e.reason,
                             detail=e.detail[:200])
             # classic stays the fallback: re-plan (the chain attempts may

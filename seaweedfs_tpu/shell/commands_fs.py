@@ -6,7 +6,7 @@ from __future__ import annotations
 
 import json
 
-from seaweedfs_tpu.server.httpd import http_request
+from seaweedfs_tpu.util.http_client import http_request, post_json
 
 from .env import CommandEnv, ShellError
 from .registry import command, parse_flags
@@ -238,8 +238,6 @@ def cmd_fs_dedup_gc(env: CommandEnv, args: list[str]) -> str:
          "[dir] — resend directory+file metadata to the notification queue"
          " (bootstrap a downstream replicator)")
 def cmd_fs_meta_notify(env: CommandEnv, args: list[str]) -> str:
-    from seaweedfs_tpu.server.httpd import post_json
-
     directory = args[0] if args else env.cwd
     out = post_json(f"{env.require_filer()}/__meta__/notify",
                     {"directory": directory})
@@ -250,8 +248,6 @@ def cmd_fs_meta_notify(env: CommandEnv, args: list[str]) -> str:
          "-dir <dir> -fromVolumeId <x> -toVolumeId <y> — rewrite volume ids"
          " inside chunk fids (after volume relocation)")
 def cmd_fs_meta_change_volume_id(env: CommandEnv, args: list[str]) -> str:
-    from seaweedfs_tpu.server.httpd import post_json
-
     flags = parse_flags(args)
     directory = flags.get("dir", env.cwd)
     try:
@@ -276,7 +272,6 @@ def cmd_fs_configure(env: CommandEnv, args: list[str]) -> str:
     it is written to /etc/seaweedfs/filer.conf, which every filer
     hot-reloads via its metadata subscription."""
     from seaweedfs_tpu.filer.filer_conf import FILER_CONF_PATH, FilerConf
-    from seaweedfs_tpu.server.httpd import http_request
 
     flags = parse_flags(args)
     filer = env.require_filer()
@@ -356,8 +351,6 @@ def cmd_fs_log_purge(env: CommandEnv, args: list[str]) -> str:
 def cmd_fs_merge_volumes(env: CommandEnv, args: list[str]) -> str:
     """`command_fs_merge_volumes.go`: re-home every chunk of volume X into
     volume Y (needle key/cookie preserved), dry-run unless -apply."""
-    from seaweedfs_tpu.server.httpd import post_json
-
     flags = parse_flags(args)
     try:
         payload = {

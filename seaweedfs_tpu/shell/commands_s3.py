@@ -7,6 +7,8 @@ from __future__ import annotations
 import json
 import time
 
+from seaweedfs_tpu.util.http_client import http_request
+
 from .env import CommandEnv, ShellError
 from .registry import command, parse_flags
 
@@ -40,8 +42,6 @@ def cmd_s3_bucket_create(env: CommandEnv, args: list[str]) -> str:
 
 @command("s3.bucket.delete", "-name <bucket> — delete the bucket and all objects")
 def cmd_s3_bucket_delete(env: CommandEnv, args: list[str]) -> str:
-    from seaweedfs_tpu.server.httpd import http_request
-
     flags = parse_flags(args)
     name = flags["name"]
     status, _, _ = env.filer_read(f"{BUCKETS_DIR}/{name}", "metadata=true")
@@ -54,8 +54,6 @@ def cmd_s3_bucket_delete(env: CommandEnv, args: list[str]) -> str:
 
 @command("s3.bucket.quota", "-name <bucket> [-sizeMB n] — set/show bucket quota")
 def cmd_s3_bucket_quota(env: CommandEnv, args: list[str]) -> str:
-    from seaweedfs_tpu.server.httpd import http_request
-
     flags = parse_flags(args)
     name = flags["name"]
     path = f"{BUCKETS_DIR}/{name}"
@@ -78,8 +76,6 @@ def cmd_s3_bucket_quota(env: CommandEnv, args: list[str]) -> str:
 
 @command("s3.clean.uploads", "[-timeAgo 24h] — abort stale multipart staging dirs")
 def cmd_s3_clean_uploads(env: CommandEnv, args: list[str]) -> str:
-    from seaweedfs_tpu.server.httpd import http_request
-
     flags = parse_flags(args)
     age_spec = flags.get("timeAgo", "24h")
     mult = {"s": 1, "m": 60, "h": 3600, "d": 86400}
@@ -112,8 +108,6 @@ def cmd_s3_clean_uploads(env: CommandEnv, args: list[str]) -> str:
          "-user <name> -access_key <ak> -secret_key <sk> [-actions Read,Write]"
          " [-buckets b1,b2] [-delete] — manage S3 identities")
 def cmd_s3_configure(env: CommandEnv, args: list[str]) -> str:
-    from seaweedfs_tpu.server.httpd import http_request
-
     flags = parse_flags(args)
     path = "/etc/iam/identity.json"
     status, _, body = env.filer_read(path)
@@ -151,8 +145,6 @@ def cmd_s3_configure(env: CommandEnv, args: list[str]) -> str:
          "[-global.readLimit n] [-global.writeLimit n] — show/update the S3 "
          "gateway concurrency limits config")
 def cmd_s3_circuitbreaker(env: CommandEnv, args: list[str]) -> str:
-    from seaweedfs_tpu.server.httpd import http_request
-
     flags = parse_flags(args)
     path = "/etc/s3/circuit_breaker.json"
     status, _, body = env.filer_read(path)
@@ -178,8 +170,6 @@ def cmd_s3_bucket_quota_enforce(env: CommandEnv, args: list[str]) -> str:
     """`command_s3_bucket_quota_check.go`: walk the buckets, compare used
     bytes against the quota.bytes extended attribute, and (with -apply)
     set/clear the s3-read-only attribute the gateway's write paths honor."""
-    from seaweedfs_tpu.server.httpd import http_request
-
     flags = parse_flags(args)
     apply = "apply" in flags
 

@@ -5,7 +5,7 @@
 
 from __future__ import annotations
 
-from seaweedfs_tpu.server.httpd import http_request
+from seaweedfs_tpu.util.http_client import http_request
 
 from .env import CommandEnv, ServerView, ShellError
 from .registry import command, dry_run_flag, parse_flags, render_plan

@@ -3,7 +3,7 @@
 
 from __future__ import annotations
 
-from seaweedfs_tpu.server.httpd import get_json, http_request, post_json
+from seaweedfs_tpu.util.http_client import get_json, http_request, post_json
 
 
 class ShellError(Exception):

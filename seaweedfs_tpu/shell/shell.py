@@ -3,11 +3,29 @@
 from __future__ import annotations
 
 import sys
+import time
 
+from seaweedfs_tpu import command as cli
 from seaweedfs_tpu.stats import trace
 
 from .env import CommandEnv, ShellError
 from .registry import run_command
+
+# what an operator waits for before a script's first RPC: the first root span
+# of a process says how long the process took to get there and how many
+# modules it had loaded by then
+_startup_reported = False
+
+
+def _startup_attrs() -> dict:
+    """`startup_s` (since the process entered the CLI, `command.started`)
+    and `modules`, once a process."""
+    global _startup_reported
+    if _startup_reported:
+        return {}
+    _startup_reported = True
+    return {"startup_s": round(time.perf_counter() - cli.started, 6),
+            "modules": len(sys.modules)}
 
 
 def run(args: list[str]) -> int:
@@ -53,7 +71,8 @@ def run_shell(
         try:
             # one root span a verb: every RPC of the verb carries its trace
             # id, so the servers' spans are this span's children
-            with trace.span("shell " + line.split(None, 1)[0], role="shell"):
+            with trace.span("shell " + line.split(None, 1)[0], role="shell",
+                            **_startup_attrs()):
                 result = run_command(env, line)
             if result:
                 print(result, file=out)

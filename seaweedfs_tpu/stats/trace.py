@@ -2,7 +2,7 @@
 
 A request entering any HTTPService gets (or inherits via the
 `X-Sw-Trace-Id` / `X-Sw-Span` header pair) a trace id; every internal
-client hop (`server.httpd.http_request` / `PooledHTTP`) re-injects the
+client hop (`util.http_client.http_request` / `PooledHTTP`) re-injects the
 pair, so one S3 PUT shows up as a span tree spanning the s3 gateway, the
 filer, the volume servers, and the master. Spans land in a bounded
 in-process ring buffer exposed at `GET /debug/traces` (recent finished

@@ -9,6 +9,10 @@ File family per volume (reference `weed/storage/erasure_coding/`):
 
 The shard *math* runs through ops.rs_kernel.RSCodec (TPU bit-plane matmul /
 C++ / numpy, byte-identical to klauspost as used by the reference).
+
+Importing the package loads the layout alone (`constants`, `geometry`:
+standard library only); whoever wants the codec names its module
+(`encoder`, `decoder`, `online`, `ec_volume`).
 """
 
 from .geometry import (
@@ -22,11 +26,7 @@ from .geometry import (
     to_ext,
 )
 
-from .online import OnlineEcWriter, online_info
-
 __all__ = [
-    "OnlineEcWriter",
-    "online_info",
     "DATA_SHARDS_COUNT",
     "PARITY_SHARDS_COUNT",
     "TOTAL_SHARDS_COUNT",

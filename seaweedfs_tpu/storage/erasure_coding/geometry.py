@@ -11,13 +11,13 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-from seaweedfs_tpu.ops.rs_kernel import (
+from .constants import (  # noqa: F401  (re-exported under the reference's names)
     DATA_SHARDS as DATA_SHARDS_COUNT,
+    LARGE_BLOCK_SIZE,
     PARITY_SHARDS as PARITY_SHARDS_COUNT,
+    SMALL_BLOCK_SIZE,
     TOTAL_SHARDS as TOTAL_SHARDS_COUNT,
 )
-LARGE_BLOCK_SIZE = 1024 * 1024 * 1024  # 1GB
-SMALL_BLOCK_SIZE = 1024 * 1024  # 1MB
 
 
 def to_ext(ec_index: int) -> str:

@@ -40,7 +40,10 @@ def own_alerts():
     of its own, and take the host's speed out of the latency SLOs by
     moving their thresholds to the histogram's last bound (the burn is
     still computed; only a request slower than 10 s can spend budget).
-    Availability SLOs and every other rule stay as they are."""
+    Availability SLOs and every other rule stay as they are. Ask for it
+    BEFORE `cluster`: a master ships the engine's firing edges in its own
+    telemetry frame from the moment it starts, and `cluster.check` reads
+    them from there until the master's next frame."""
     from seaweedfs_tpu.stats import alerts as alerts_mod
     from seaweedfs_tpu.stats import history as history_mod
 
@@ -87,7 +90,7 @@ class TestBasicCommands:
         ps = run_command(env, "cluster.ps")
         assert "volumeServer" in ps and "master" in ps
 
-    def test_cluster_check_healthy(self, cluster, own_alerts):
+    def test_cluster_check_healthy(self, own_alerts, cluster):
         master, volumes, env = cluster
         write_blobs(master.url, 3)
         for vs in volumes:
@@ -101,7 +104,7 @@ class TestBasicCommands:
         assert "disk" in out and "heartbeat" in out
         assert "fastlane native" in out
 
-    def test_cluster_check_fail_mode_on_readonly(self, cluster, own_alerts):
+    def test_cluster_check_fail_mode_on_readonly(self, own_alerts, cluster):
         """Acceptance: a read-only volume makes `cluster.check -fail` exit
         nonzero; without -fail the problems render but the verb returns."""
         master, volumes, env = cluster

@@ -75,6 +75,7 @@ def traced_sections():
     began = next(float(dict(p.stats)["profile_start_time"]) * 1e-9
                  for p in profile.planes if "profile_start_time" in dict(p.stats))
     events = {}
+    python_frames = 0
     for plane in profile.planes:
         if not plane.name.startswith("/host:"):
             continue
@@ -83,7 +84,9 @@ def traced_sections():
                 if ev.name in bounds:
                     events[ev.name] = (began + ev.start_ns * 1e-9,
                                        ev.duration_ns * 1e-9)
-    return {"bounds": bounds, "events": events, "archive_bytes": len(blob[0])}
+                # the Python tracer's events: "$file.py:line function"
+                python_frames += ev.name.startswith("$")
+    return {"bounds": bounds, "events": events, "python_frames": python_frames}
 
 
 @pytest.mark.parametrize("name", ["t.phase", "t.bare", "t.span", "t.kernel"])
@@ -96,8 +99,11 @@ def test_section_lies_in_the_device_trace_on_the_wall_clock(traced_sections, nam
 
 
 def test_device_trace_leaves_the_python_tracer_off(traced_sections):
-    # a second of an idle interpreter with the Python tracer on is megabytes
-    assert traced_sections["archive_bytes"] < 256 * 1024
+    # with it on every Python call of the second is an event (megabytes on a
+    # busy server). Counted by name, not by the archive's size: the archive
+    # also holds the metadata of every XLA program the process has loaded,
+    # half a megabyte when tests/test_hash_kernels.py ran in it before
+    assert traced_sections["python_frames"] == 0
 
 
 def test_phase_starts_no_jax_while_no_device_trace_runs():
