@@ -7,8 +7,6 @@ designed TPU-first:
     content hashing, CDC dedup fingerprinting) run as JAX/XLA/Pallas kernels on
     TPU, batched onto the MXU/VPU, with C++ native CPU fallbacks (never pure
     Python) loaded via ctypes;
-  - multi-chip scaling uses `jax.sharding.Mesh` + `shard_map` over volume
-    batches (embarrassingly parallel over ICI; DCN for host batches);
   - the control plane (master / volume server / filer) is asyncio + HTTP/JSON,
     mirroring the reference's own HTTP surface (/dir/assign, /dir/lookup,
     /<vid>,<fid>), with on-disk formats bit-compatible with the reference
@@ -19,7 +17,6 @@ Layout:
   storage/   volume engine: needle format, volumes, needle maps, erasure coding
   ops/       TPU kernels: GF(2^8) Reed-Solomon, CRC32C, MD5, CDC (JAX/Pallas)
   native/    C++ CPU kernels (Reed-Solomon, CRC32C, MD5) behind ctypes
-  parallel/  device mesh + shard_map multi-chip execution
   topology/  master-side cluster state: DC/rack/node tree, volume layout, growth
   server/    master / volume / filer HTTP servers
   filer/     namespace: entries, chunking, visible intervals, stores

@@ -1919,10 +1919,18 @@ bool filer_commit(Engine* E, const std::string& frame) {
     return true;
 }
 
+// `unless_tombstone`: a put that reports the STORE's state (the meta-log
+// subscriber, a Python-served read) must not replace the tombstone of a
+// natively-acked DELETE whose frame the drain has not applied yet — the
+// store is behind the ack, and the live entry would answer 200 for a
+// deleted path. The drain's own cache_del lifts the tombstone.
 void fcache_put(Engine* E, const std::string& path,
-                std::shared_ptr<FilerCacheEnt> ent) {
+                std::shared_ptr<FilerCacheEnt> ent,
+                bool unless_tombstone = false) {
     std::unique_lock<std::shared_mutex> l(E->fcache_mu);
     auto old = E->fcache.find(path);
+    if (unless_tombstone && old != E->fcache.end() && old->second->tombstone)
+        return;
     bool carried = false;
     if (old != E->fcache.end() && !old->second->inline_data.empty()) {
         if (ent->inline_data.empty() && old->second->md5_hex == ent->md5_hex) {
@@ -4132,7 +4140,7 @@ int sw_fl_filer_cache_put(int h, const char* path, const char* host,
     ent->md5_hex = md5_hex ? md5_hex : "";
     ent->size = size;
     ent->mtime = mtime;
-    fcache_put(E, path, std::move(ent));
+    fcache_put(E, path, std::move(ent), /*unless_tombstone=*/true);
     return 0;
 }
 

@@ -1,6 +1,5 @@
 // Minimal epoll HTTP load generator — measures the fastlane engine's
-// ceiling without a GIL-bound client in the way (bench.py small-file
-// configs). One thread, N keep-alive connections, one in-flight request
+// ceiling without a GIL-bound client in the way. One thread, N keep-alive connections, one in-flight request
 // per connection; counts 2xx and completes when every path ran once.
 
 #include <arpa/inet.h>

@@ -9,7 +9,7 @@ or two, below jax's default threshold for keeping an entry, so the
 threshold is dropped to zero.
 
 The module also holds what a process knows about its device side, for
-`GET /status` on the volume server and for `chip_smoke.py` to read:
+`GET /status` on the volume server (read by `benchmark/run.py` and the tests):
 the devices jax sees (only once jax has been started), the compile
 counters, how many kernel shapes the RS transform has run, and every
 failure that made a backend selection skip a candidate.
@@ -118,7 +118,7 @@ def note_selection_failure(where: str, exc: BaseException) -> None:
 
 def note_kernel_shape(matrix: bytes, rows: int, cols: int, width: int) -> None:
     """The RS transform is about to run the program of its (rows, cols)
-    coefficient matrix at `width` (`ops/rs_kernel`, `ops/rs_pallas`): a set
+    coefficient matrix at `width` (the door, `ops/rs_kernel._enqueue`): a set
     insert, on every call."""
     _kernel_shapes.add((matrix, rows, cols, width))
 

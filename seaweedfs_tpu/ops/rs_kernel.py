@@ -18,6 +18,7 @@ native path, and therefore klauspost/reedsolomon as used by the reference
 
 from __future__ import annotations
 
+import bisect
 import functools
 import os
 import threading
@@ -35,8 +36,14 @@ from seaweedfs_tpu.storage.erasure_coding.constants import (  # noqa: F401
 
 from . import device, gf256, rs_pallas
 
-# Default chunk: bound device memory per call; callers stream larger inputs.
-DEFAULT_CHUNK = 64 * 1024 * 1024
+# The Pallas body's block width, and the unit of every width below.
+TILE = 8192
+# The widths, in tiles, that host bytes of up to one small block (128 tiles =
+# SMALL_BLOCK_SIZE) reach the kernel at: every tile multiple up to nine (a
+# 64 KiB needle's record and below), then a step of a third or a half, so the
+# zero tail stays under a third of what crosses the link. A degraded read of
+# any length thus compiles at most these 17 programs per coefficient matrix.
+LADDER_TILES = (1, 2, 3, 4, 5, 6, 7, 8, 9, 12, 16, 24, 32, 48, 64, 96, 128)
 
 
 def transform_kernel() -> str:
@@ -47,14 +54,14 @@ def transform_kernel() -> str:
 
 
 @functools.lru_cache(maxsize=64)
-def _compiled_transform(rows: int, cols: int, a_bytes: bytes):
-    """jit-compiled bit-plane transform for a fixed bit-matrix."""
+def _compiled_xla(rows: int, cols: int, matrix_bytes: bytes, tile: int):
+    """The jitted XLA form of one (rows, cols) coefficient matrix:
+    fn((cols, n) uint8) -> (rows, n) uint8, for any n (`tile` is the Pallas
+    body's business: `rs_pallas.compiled` has the same signature)."""
     jax = device.jax()
     jnp = jax.numpy
-    a = jnp.asarray(
-        np.frombuffer(a_bytes, dtype=np.uint8).reshape(cols * 8, rows * 8),
-        dtype=jnp.int8,
-    )
+    m = np.frombuffer(matrix_bytes, dtype=np.uint8).reshape(rows, cols)
+    a = jnp.asarray(gf256.bit_matrix(m), dtype=jnp.int8)  # (cols*8, rows*8)
 
     @jax.jit
     def transform(shards):  # (cols, n) uint8
@@ -78,59 +85,86 @@ def _compiled_transform(rows: int, cols: int, a_bytes: bytes):
     return transform
 
 
-@functools.lru_cache(maxsize=256)
-def _cached_bit_matrix(matrix_bytes: bytes, rows: int, cols: int) -> np.ndarray:
-    m = np.frombuffer(matrix_bytes, dtype=np.uint8).reshape(rows, cols)
-    return gf256.bit_matrix(m)
+def ladder_width(n: int, tile: int) -> int:
+    """The width host bytes of width n go to the kernel at: the next rung of
+    `LADDER_TILES`, or beyond the ladder (rows of large blocks) the next
+    multiple of `tile`."""
+    tiles = -(-n // tile)
+    if tiles > LADDER_TILES[-1]:
+        return tiles * tile
+    return LADDER_TILES[bisect.bisect_left(LADDER_TILES, tiles)] * tile
 
 
-def _enqueue(matrix: np.ndarray, shards, chunk: int = DEFAULT_CHUNK):
-    """`gf_matmul_jax`, and beside its result the number of device programs
-    the call enqueued. A host array goes to the jitted program at a rung of
-    the kernel's ladder (`rs_pallas.zero_tailed`, in both forms), and the
-    program does its own transfer."""
-    if transform_kernel() == "pallas":
-        return rs_pallas.enqueue(matrix, shards, rs_pallas.TILE)
-    jnp = device.jax().numpy
+def zero_tailed(rows, tile: int) -> np.ndarray:
+    """`rows` — a (cols, n) array or a sequence of cols (n,) arrays — as one
+    C-contiguous (cols, `ladder_width(n, tile)`) uint8 host array, zero
+    beyond column n: one copy per row, as `np.stack` makes, and none where
+    `rows` is such an array already. The one place that decides at which
+    width host bytes reach the kernel, in either of its forms.
+
+    The copies go through a memoryview and so keep the interpreter lock:
+    numpy gives it up around each copy of more than 500 bytes, and under
+    sixteen reader threads getting it back ten times a read costs several
+    times the copies themselves (PERF.md, PR 27)."""
+    n = len(rows[0])
+    width = ladder_width(n, tile)
+    if isinstance(rows, np.ndarray) and width == n:
+        return np.ascontiguousarray(rows, dtype=np.uint8)
+    buf = bytearray(len(rows) * width)  # zeroed
+    flat = memoryview(buf)
+    for i, row in enumerate(rows):
+        flat[i * width : i * width + n] = row
+    return np.frombuffer(buf, dtype=np.uint8).reshape(len(rows), width)
+
+
+def _enqueue(matrix: np.ndarray, shards):
+    """The door: out[r] = XOR_c matrix[r,c] x shards[c] enqueued on the
+    device, and beside the (rows, n) device result the number of programs
+    the call enqueued — 1 for the kernel, 1 more for a pad on the device,
+    1 more for a slice on the device.
+
+    matrix: (rows, cols) uint8 host array; shards: (cols, n) uint8 on either
+    side of the transfer, any n. The zero tail is written where the bytes
+    are (zero bytes transform to zero bytes, so the result is exact). A host
+    array goes to the jitted program at a rung of the ladder (`zero_tailed`;
+    none is written where the width is a rung's already, as `_apply_jax`
+    hands it in) and the program does its own transfer: no put, and at a
+    rung's width no pad and no slice either. A device array is padded to a
+    tile multiple for the Pallas body, which takes nothing else; the XLA
+    body takes any width."""
+    pallas = transform_kernel() == "pallas"
+    tile = TILE
     rows, cols = matrix.shape
     matrix_bytes = matrix.tobytes()
-    a = _cached_bit_matrix(matrix_bytes, rows, cols)
-    fn = _compiled_transform(rows, cols, a.tobytes())
+    fn = (rs_pallas.compiled if pallas else _compiled_xla)(
+        rows, cols, matrix_bytes, tile)
     n = shards.shape[1]
     on_host = isinstance(shards, np.ndarray)
     if on_host:
-        shards = rs_pallas.zero_tailed(shards, rs_pallas.TILE)
+        shards = zero_tailed(shards, tile)
     else:
+        jnp = device.jax().numpy
         shards = jnp.asarray(shards, dtype=jnp.uint8)
-    width = shards.shape[1]
-
-    def run(piece):
-        device.note_kernel_shape(matrix_bytes, rows, cols, piece.shape[1])
-        return fn(piece)
-
-    if width <= chunk:
-        out, programs = run(shards), 1
-    else:
-        outs = [run(shards[:, i : i + chunk]) for i in range(0, width, chunk)]
-        # a device array is cut by a slice program per chunk, a host array by
-        # numpy
-        out = jnp.concatenate(outs, axis=1)
-        programs = len(outs) * (1 if on_host else 2) + 1
-    # a host array's zero tail, taken off on the device
-    return (out, programs) if width == n else (out[:, :n], programs + 1)
+        # no named scope around the pad and the slice: two scopes cost a
+        # read a percent (PERF.md, PR 26); the trace knows the two programs
+        # as `jit__pad` and `jit_dynamic_slice`
+        if pallas and n % tile:
+            shards = jnp.pad(shards, ((0, 0), (0, (-n) % tile)))
+    device.note_kernel_shape(matrix_bytes, rows, cols, shards.shape[1])
+    out = fn(shards)
+    if shards.shape[1] == n:
+        return out, 1
+    return out[:, :n], 2 if on_host else 3
 
 
-def gf_matmul_jax(matrix: np.ndarray, shards, chunk: int = DEFAULT_CHUNK):
-    """out[r] = XOR_c matrix[r,c] x shards[c] on the accelerator.
-
-    matrix: (rows, cols) uint8 numpy (host). shards: (cols, n) uint8 —
-    numpy or jax array, any n. Returns a jax array (rows, n) uint8 (device).
-    """
-    return _enqueue(matrix, shards, chunk)[0]
+def gf_matmul_jax(matrix: np.ndarray, shards):
+    """`_enqueue`'s device result alone, for callers outside `ops/` (tests:
+    the codec and the pipelines go through `_dispatch`)."""
+    return _enqueue(np.ascontiguousarray(matrix, dtype=np.uint8), shards)[0]
 
 
 def _dispatch(matrix: np.ndarray, shards):
-    """`gf_matmul_jax` as the codec calls it, with the host's seconds in the
+    """`_enqueue` as the codec calls it, with the host's seconds in the
     call counted under `dispatch` and the device programs it enqueued under
     `SeaweedFS_volume_ec_device_programs_total`: the kernel alone where
     `shards` is a host array at a rung of the ladder (`_apply_jax`; the
@@ -145,10 +179,10 @@ def _dispatch(matrix: np.ndarray, shards):
 def _apply_jax(matrix: np.ndarray, rows) -> np.ndarray:
     """The transform of host bytes — a (cols, n) array or a sequence of cols
     (n,) arrays — as one device program and one copy back: the width is
-    brought to a rung of the kernel's ladder (`rs_pallas.LADDER_TILES`) on
-    the host and taken back on the host, so nothing compiles per length."""
+    brought to a rung of the kernel's ladder (`LADDER_TILES`) on the host
+    and taken back on the host, so nothing compiles per length."""
     n = len(rows[0])
-    rows = rs_pallas.zero_tailed(rows, rs_pallas.TILE)
+    rows = zero_tailed(rows, TILE)
     return _JaxHandle(_dispatch(matrix, rows), n).result()
 
 
@@ -335,7 +369,7 @@ class _JaxHandle:
 
 # Host arrays above this size are put on the device in pieces of this size
 # and concatenated there.
-H2D_CHUNK = int(os.environ.get("SEAWEEDFS_TPU_H2D_CHUNK", 4 * 1024 * 1024))
+H2D_CHUNK = 4 * 1024 * 1024
 
 
 def _device_put_1d(buf: np.ndarray):

@@ -20,6 +20,7 @@ import base64
 import hashlib
 import json
 import os
+import threading
 import time
 import urllib.parse
 
@@ -257,7 +258,8 @@ class FilerServer:
         if journal:
             self.fastlane._lib.sw_fl_filer_journal_reset(self.fastlane.handle)
         self._fl_filer_on = True
-        self._fl_drain_mu = __import__("threading").Lock()
+        self._fl_drain_mu = threading.Lock()
+        self._fl_applying = None  # (thread, path) inside `_fl_apply`
         self._fl_buf = __import__("ctypes").create_string_buffer(1 << 20)
         self.filer.subscribe(self._fl_on_meta)
         self._fl_push_rules()  # fs.configure prefixes defer to Python
@@ -287,8 +289,6 @@ class FilerServer:
             lines, names=self.FL_FRONT_FAMILIES)
 
     def start(self) -> None:
-        import threading
-
         self._start_fastlane()
         if self.local_socket:
             self.service.enable_unix_socket(self.local_socket)
@@ -347,7 +347,12 @@ class FilerServer:
             try:
                 chunks = self.filer.delete_entry(path)
             except FilerError:
-                return
+                chunks = []
+            # the engine keeps the path's tombstone against every put that
+            # reports the store's state until this frame is applied; where
+            # the store had nothing left to delete no meta event lifts it
+            self.fastlane._lib.sw_fl_filer_cache_del(
+                self.fastlane.handle, path.encode())
             self._reclaim_chunks(chunks)
             return
         entry = Entry(full_path=path)
@@ -379,6 +384,11 @@ class FilerServer:
             except FilerError:
                 break
         old = self.filer.find_entry(path)
+        # the engine cached this path when it acked the write, and what its
+        # cache holds now is that entry or something it acked later: the
+        # meta event of this apply must not refresh it from the store,
+        # which is behind (`_fl_on_meta`)
+        self._fl_applying = (threading.get_ident(), path)
         try:
             freed = self.filer.create_entry(entry)
         except FilerError:
@@ -390,6 +400,8 @@ class FilerServer:
             glog.warning("native write to %s rejected by store; dropped",
                          path)
             return
+        finally:
+            self._fl_applying = None
         # journal replay is idempotent: never reclaim the very chunk this
         # frame records (a replayed frame sees itself as the old entry)
         new_fids = {c.file_id for c in entry.chunks}
@@ -590,7 +602,11 @@ class FilerServer:
                                 or old.full_path != new.full_path):
             self.fastlane._lib.sw_fl_filer_cache_del(
                 self.fastlane.handle, old.full_path.encode())
-        if new is not None:
+        # not for the drain's own apply of a native write: the engine's
+        # entry is the newer one, and a chunk entry whose volume this filer
+        # has not looked up yet would be dropped by the push
+        if new is not None and self._fl_applying != (
+                threading.get_ident(), new.full_path):
             self._fl_cache_push(new, blocking_lookup=False)
 
     def _fl_cache_push(self, entry, blocking_lookup: bool) -> None:

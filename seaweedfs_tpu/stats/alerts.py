@@ -297,8 +297,7 @@ def _check_trace_drops(hist, now, p):
     )
     if rate is not None and rate > p["trace_drop_rate"]:
         return rate, (
-            f"trace ring dropping {rate:.0f} spans/s"
-            " (capacity churn — raise SEAWEEDFS_TPU_TRACE_CAPACITY?)"
+            f"trace ring dropping {rate:.0f} spans/s (capacity churn)"
         )
     return None
 
@@ -824,15 +823,6 @@ class AlertEngine:
             "slo_windows": {"fast": self.params["slo_fast_window"],
                             "slow": self.params["slo_slow_window"]},
         }
-
-    def snapshot(self) -> dict:
-        """Public view of the firing state + edge counter (bench.py's
-        request_rates summary reads this; no private-state reach-ins)."""
-        with self._lock:
-            return {
-                "fired_events": self.fired_events,
-                "firing": sorted(self.firing),
-            }
 
     def _lines(self) -> list[str]:
         with self._lock:

@@ -25,8 +25,8 @@ Design constraints mirror util/faults.py:
      silently journal nothing, and tools/check_metric_names.py lints
      that every declared type is emitted by a real seam and exercised
      by the tests.
-  3. **Bounded.** A fixed ring (SEAWEEDFS_TPU_EVENTS_CAPACITY, default
-     4096) with eviction counted into
+  3. **Bounded.** A fixed ring (`DEFAULT_CAPACITY`, 4096 events) with
+     eviction counted into
      `SeaweedFS_events_dropped_total` — the journal can lose history,
      never memory.
 """
@@ -34,7 +34,6 @@ Design constraints mirror util/faults.py:
 from __future__ import annotations
 
 import collections
-import os
 import threading
 import time
 
@@ -86,8 +85,7 @@ EVENT_FAMILIES = (
     "SeaweedFS_events_dropped_total",
 )
 
-DEFAULT_CAPACITY = int(os.environ.get("SEAWEEDFS_TPU_EVENTS_CAPACITY",
-                                      "4096"))
+DEFAULT_CAPACITY = 4096
 
 
 class Event:

@@ -34,7 +34,6 @@ a request path (the arXiv:1207.6744 foreground-protection principle).
 
 from __future__ import annotations
 
-import os
 import threading
 import time
 
@@ -51,8 +50,8 @@ ROLLUP_FAMILIES = (
 # EWMA smoothing weight for new observations, and the hysteresis pair
 # (ops/s) whose crossings journal heat_promoted / heat_demoted edges
 DEFAULT_ALPHA = 0.3
-DEFAULT_PROMOTE = float(os.environ.get("SEAWEEDFS_TPU_HEAT_PROMOTE", "10"))
-DEFAULT_DEMOTE = float(os.environ.get("SEAWEEDFS_TPU_HEAT_DEMOTE", "2"))
+DEFAULT_PROMOTE = 10.0
+DEFAULT_DEMOTE = 2.0
 # rate window for heat (seconds) and the fit window for the capacity
 # forecast — the forecast window bounds how long stale fill history can
 # keep a days-to-full gauge alive after a mass deletion

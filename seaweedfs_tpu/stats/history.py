@@ -35,17 +35,14 @@ layer that makes raw metrics actionable.
 from __future__ import annotations
 
 import collections
-import os
 import threading
 import time
 
 from seaweedfs_tpu.stats.metrics import default_registry, parse_exposition
 
-DEFAULT_INTERVAL = float(os.environ.get("SEAWEEDFS_TPU_HISTORY_INTERVAL", "5"))
-DEFAULT_SLOTS = int(os.environ.get("SEAWEEDFS_TPU_HISTORY_SLOTS", "120"))
-DEFAULT_MAX_SERIES = int(
-    os.environ.get("SEAWEEDFS_TPU_HISTORY_MAX_SERIES", "4096")
-)
+DEFAULT_INTERVAL = 5.0
+DEFAULT_SLOTS = 120
+DEFAULT_MAX_SERIES = 4096
 
 # Exposition names with these suffixes carry counter semantics (histogram
 # _sum/_count/_bucket components are cumulative too): windowed rates make

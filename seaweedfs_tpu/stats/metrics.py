@@ -465,7 +465,8 @@ def parse_exposition(text: str):
     """Parse Prometheus text format -> list of (name, labels, value).
 
     The inverse of Registry.render, shared by `cluster.check` (scraping
-    /metrics across the cluster), bench.py's fastlane summary, and tests.
+    /metrics across the cluster), the history ring's scrape
+    (`stats/history.py`), the telemetry frames (`stats/aggregate.py`) and tests.
     Unparseable lines are skipped, like Prometheus itself treats them."""
     import re
 

@@ -34,8 +34,7 @@ in-memory slope. This module is the persistence leg:
     Ring eviction during a long deferral is counted
     (`SeaweedFS_telemetry_events_lost_total`), never silent. Writes ride
     a token bucket (the arXiv:1207.6744 background-never-starves-
-    foreground rule the repair throttle follows); bench.py bounds the
-    native-write-path overhead at <3%.
+    foreground rule the repair throttle follows).
 
   * **Replay.** On restart the store replays its tail: raw samples
     preload the history ring (so `/debug/metrics/history` serves
@@ -68,9 +67,7 @@ _REC_MAGIC = 0x53575453  # "SWTS": SeaWeed Telemetry Segment
 # make the reader allocate gigabytes before the CRC gets a say
 _MAX_RECORD = 8 << 20
 
-DEFAULT_RETENTION_MB = float(
-    os.environ.get("SEAWEEDFS_TPU_TELEMETRY_RETENTION_MB", "64")
-)
+DEFAULT_RETENTION_MB = 64.0
 # flusher token bucket: sustained spool write rate + burst. Small on
 # purpose — telemetry is background work and must never starve the
 # foreground disk (the repair-throttle rule, arXiv:1207.6744).

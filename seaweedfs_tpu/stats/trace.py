@@ -177,9 +177,7 @@ class TraceCollector:
     cluster naturally merges its hops into one trace; multi-process
     clusters are merged by `cluster.trace` fetching each node's ring."""
 
-    def __init__(self, max_spans: int | None = None) -> None:
-        if max_spans is None:
-            max_spans = int(os.environ.get("SEAWEEDFS_TPU_TRACE_CAPACITY", "2048"))
+    def __init__(self, max_spans: int = 2048) -> None:
         self.max_spans = max_spans
         self._ring: collections.deque[Span] = collections.deque(maxlen=max_spans)
         self._inflight: dict[str, Span] = {}

@@ -26,7 +26,6 @@ explain why a tenant's counts are approximate.
 
 from __future__ import annotations
 
-import os
 import threading
 
 USAGE_FAMILIES = (
@@ -195,9 +194,7 @@ class UsageAccountant:
     the same K. Handler paths call record(); the metrics collector calls
     lines() at scrape time and folds in native-engine deltas first."""
 
-    def __init__(self, k: int | None = None):
-        if k is None:
-            k = int(os.environ.get("SEAWEEDFS_TPU_USAGE_K", DEFAULT_K))
+    def __init__(self, k: int = DEFAULT_K):
         self.k = k
         self._lock = threading.Lock()
         self._sketches = {dim: SpaceSaving(k) for dim in _DIMS}

@@ -56,8 +56,6 @@ from .geometry import (
 # wants large batches to amortize transfer/dispatch overhead instead.
 DEFAULT_BATCH_HOST = 1024 * 1024
 DEFAULT_BATCH_DEVICE = 32 * 1024 * 1024
-# Back-compat alias (tests/benches may import it)
-DEFAULT_BATCH = DEFAULT_BATCH_HOST
 
 
 def _default_batch(backend: str) -> int:

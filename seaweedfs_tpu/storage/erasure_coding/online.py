@@ -101,9 +101,7 @@ _FP_PARITY = faults.register("volume.ec.parity.write")
 _JOURNAL_MAGIC = 0x53574550  # "SWEP"
 _JOURNAL_REC = struct.Struct("<IQQI")
 
-_DEFAULT_BLOCK = int(
-    os.environ.get("SEAWEEDFS_TPU_EC_ONLINE_BLOCK", SMALL_BLOCK_SIZE)
-)
+_DEFAULT_BLOCK = SMALL_BLOCK_SIZE
 
 _metrics_cache = None
 

@@ -1,16 +1,9 @@
-"""Test harness config: tests run on JAX's CPU backend with eight virtual
-devices (`--xla_force_host_platform_device_count=8`), which is also what
-validates the multi-chip sharding in tests/test_parallel.py. They never need
-a chip: `chip_smoke.py` is what runs the program on one.
+"""Test harness config: tests run on JAX's CPU backend. They never need a
+chip: `benchmark/run.py` is what runs the program on one.
 """
 
 import os
 
-xla_flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in xla_flags:
-    os.environ["XLA_FLAGS"] = (
-        xla_flags + " --xla_force_host_platform_device_count=8"
-    ).strip()
 os.environ["JAX_PLATFORMS"] = "cpu"
 
 import pathlib

@@ -19,7 +19,7 @@ import shutil
 import numpy as np
 import pytest
 
-from seaweedfs_tpu.ops import gf256, rs_pallas
+from seaweedfs_tpu.ops import gf256, rs_kernel
 from seaweedfs_tpu.ops.rs_kernel import RSCodec
 from seaweedfs_tpu.stats import trace
 from seaweedfs_tpu.stats.metrics import default_registry, parse_exposition
@@ -30,7 +30,7 @@ from seaweedfs_tpu.storage.needle import Needle, get_actual_size
 from seaweedfs_tpu.storage.volume import Volume
 
 TILE = 8
-SMALL = rs_pallas.LADDER_TILES[-1] * TILE  # 1,024: one block is the top rung
+SMALL = rs_kernel.LADDER_TILES[-1] * TILE  # 1,024: one block is the top rung
 LARGE = 1024 * SMALL                        # never reached: small rows only
 NEEDLES, NEEDLE_BYTES = 12, 4 * SMALL
 DATA, TOTAL = 10, 14
@@ -116,7 +116,7 @@ CASES = [((s,), None) for s in range(TOTAL)] + [
 def test_five_block_needles_read_back_and_count_their_intervals(
         sealed, tmp_path, monkeypatch, lost, fetch):
     src, written = sealed
-    monkeypatch.setattr(rs_pallas, "TILE", TILE)
+    monkeypatch.setattr(rs_kernel, "TILE", TILE)
     d = tmp_path / "v"
     _degraded_copy(src, d, lost)
     ev = EcVolume(str(d), "", 1, codec=RSCodec(backend="jax"),

@@ -37,6 +37,22 @@ def _filer(cluster, **kw):
     return f
 
 
+def _wait_cached(f, path, seconds=10.0):
+    """Until a GET of `path` is answered from the engine's cache: a read
+    that Python serves puts the entry there."""
+    import time
+
+    deadline = time.time() + seconds
+    while True:
+        before = f.fastlane.front_metrics()["read"]["native"]
+        st, _, _ = http_request("GET", f.url + path)
+        assert st == 200
+        if f.fastlane.front_metrics()["read"]["native"] == before + 1:
+            return
+        assert time.time() < deadline, f"{path} never reached the cache"
+        time.sleep(0.02)
+
+
 class TestNativeFilerPath:
     def test_inline_and_chunk_served_natively(self, cluster):
         f = _filer(cluster)
@@ -256,6 +272,11 @@ class TestNativeDeleteAndFrontDoor:
             st, _, _ = http_request("POST", f.url + "/d/c.bin",
                                     os.urandom(20000))
             assert st == 201
+            # "a cached entry" is the premise: a write that fell back to
+            # Python (no lease yet on a loaded machine) leaves its entry to
+            # the first read, and the DELETE would then be Python's too
+            for path in ("/d/i.txt", "/d/c.bin"):
+                _wait_cached(f, path)
             before = f.fastlane.front_metrics()["delete"]["native"]
             for path in ("/d/i.txt", "/d/c.bin"):
                 st, _, _ = http_request("DELETE", f.url + path)
@@ -275,6 +296,73 @@ class TestNativeDeleteAndFrontDoor:
             st, _, _ = http_request("POST", f.url + "/d/i.txt", b"again")
             assert st == 201
             st, _, body = http_request("GET", f.url + "/d/i.txt")
+            assert st == 200 and body == b"again"
+        finally:
+            f.stop()
+
+    def test_native_write_stays_cached_through_its_own_drain(self, cluster):
+        """The drain's apply of a native write leaves the engine's entry
+        alone: it is the newer one, and a refresh from the store would
+        drop a chunk entry whose volume the filer never looked up (the
+        engine wrote it through a lease) — the next read, and a DELETE,
+        would be Python's."""
+        f = _filer(cluster)
+        if not f._fl_filer_on:
+            f.stop()
+            pytest.skip("engine unavailable")
+        try:
+            payload = os.urandom(20000)
+            with f._fl_drain_mu:  # the loop's drain waits for ours
+                st, _, _ = http_request("POST", f.url + "/k/c.bin", payload)
+                assert st == 201
+                if f.fastlane.front_metrics()["write"]["native"] != 1:
+                    pytest.skip("no lease yet: the write was Python's")
+            assert f._fl_filer_drain() == 1
+            assert f.filer.find_entry("/k/c.bin") is not None
+            st, _, body = http_request("GET", f.url + "/k/c.bin")
+            assert st == 200 and body == payload
+            fm = f.fastlane.front_metrics()["read"]
+            assert fm["native"] == 1 and not fm["fallback"]["cache_miss"]
+        finally:
+            f.stop()
+
+    def test_put_of_the_stores_state_keeps_a_tombstone(self, cluster):
+        """A cache refresh that reports the store's state — a read that
+        Python serves looks the entry up, then pushes it — can come after
+        the engine acked a DELETE of the path and before the drain applied
+        it: the store is behind the ack, and the refresh must not put the
+        live entry back over the tombstone (a GET would answer 200 for a
+        deleted path). Held still here: the drain is locked out and the
+        refresh is made by hand."""
+        from seaweedfs_tpu.filer import Entry
+
+        f = _filer(cluster)
+        if not f._fl_filer_on:
+            f.stop()
+            pytest.skip("engine unavailable")
+        try:
+            with f._fl_drain_mu:
+                st, _, _ = http_request("POST", f.url + "/t/i.txt", b"inline")
+                assert st == 201
+                st, _, _ = http_request("DELETE", f.url + "/t/i.txt")
+                assert st == 204
+                fm = f.fastlane.front_metrics()
+                assert fm["write"]["native"] == fm["delete"]["native"] == 1
+                stale = Entry(full_path="/t/i.txt", content=b"inline")
+                stale.attributes.md5 = "0" * 32
+                stale.attributes.file_size = 6
+                f._fl_cache_push(stale, blocking_lookup=False)
+                st, _, _ = http_request("GET", f.url + "/t/i.txt")
+                assert st == 404, "a stale put resurrected a deleted path"
+                assert f.fastlane.front_metrics()["read"]["native"] == 1
+            # the drain lifts the tombstone; the path is free again
+            f._fl_filer_drain()
+            assert f.filer.find_entry("/t/i.txt") is None
+            st, _, _ = http_request("GET", f.url + "/t/i.txt")
+            assert st == 404
+            st, _, _ = http_request("POST", f.url + "/t/i.txt", b"again")
+            assert st == 201
+            st, _, body = http_request("GET", f.url + "/t/i.txt")
             assert st == 200 and body == b"again"
         finally:
             f.stop()

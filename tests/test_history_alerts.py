@@ -7,7 +7,7 @@ correctness against hand-computed values (incl. the counter-reset clamp),
 each alert rule on synthetic series, the live acceptance path — an
 injected 5xx burst firing an alert visible in /debug/alerts, /metrics,
 cluster.top, and cluster.check -fail's exit — plus a 3-role
-cluster.top -once render and bench.py's request_rates summary.
+cluster.top -once render.
 """
 
 import os
@@ -566,34 +566,3 @@ class TestClusterAcceptance:
             hist.clear()
             eng.evaluate()
         assert "http_error_ratio" not in eng.firing
-
-
-class TestBenchRequestRates:
-    def test_summary_from_synthetic_history(self):
-        import bench
-
-        reg = Registry()
-        c = reg.counter("SeaweedFS_http_request_total", "",
-                        ("role", "method", "code"))
-        fl_req = reg.counter("SeaweedFS_volume_fastlane_requests_total", "",
-                             ("server", "op"))
-        fl_bytes = reg.counter("SeaweedFS_volume_fastlane_bytes_total", "",
-                               ("server", "op"))
-        h = MetricsHistory(reg, interval=1.0, slots=16)
-        eng = alerts_mod.AlertEngine(history=h, registry=reg)
-        c.labels("master", "GET", "200").inc(10)
-        fl_req.labels("n1", "read").inc(100)
-        fl_bytes.labels("n1", "read").inc(1000)
-        h.scrape_once(now=1000.0)
-        c.labels("master", "GET", "200").inc(100)
-        fl_req.labels("n1", "read").inc(400)
-        fl_bytes.labels("n1", "read").inc(4_000_000)
-        h.scrape_once(now=1010.0)
-        out = bench.request_rates_summary_from_history(
-            h, 60.0, now=1010.0, eng=eng
-        )
-        assert out["http_req_s"]["master:GET"] == pytest.approx(10.0)
-        assert out["fastlane_ops"]["read"]["req_s"] == pytest.approx(40.0)
-        assert out["fastlane_ops"]["read"]["bytes_s"] \
-            == pytest.approx(400_000.0, rel=1e-3)
-        assert out["alerts_fired"] == 0 and out["alerts_firing"] == []

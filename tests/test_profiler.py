@@ -5,8 +5,8 @@ Covers: Hz/seconds clamping, collapsed-stack capture and merging, the
 self-measured overhead guard (<10% wall on a busy loop at 50 Hz), the
 profiler/trace-ring self-metric collectors, every HTTPService role
 exposing /debug/pprof/threads (tier-1), 400s on malformed query params,
-per-stage busy/wait histograms from the EC pipeline, bench.py's
-ec_pipeline summary, and a 3-role cluster.profile merge.
+per-stage busy/wait histograms from the EC pipeline, and a 3-role
+cluster.profile merge.
 """
 
 import json
@@ -220,23 +220,6 @@ class TestPipelineStageMetrics:
                 )
                 assert needle in text, needle
 
-    def test_bench_ec_pipeline_summary(self):
-        import bench
-
-        text = "\n".join([
-            'SeaweedFS_volume_ec_pipeline_seconds_sum{stage="read",state="busy"} 2.0',
-            'SeaweedFS_volume_ec_pipeline_seconds_count{stage="read",state="busy"} 10',
-            'SeaweedFS_volume_ec_pipeline_seconds_sum{stage="read",state="wait"} 6.0',
-            'SeaweedFS_volume_ec_pipeline_seconds_count{stage="read",state="wait"} 10',
-            'SeaweedFS_volume_ec_pipeline_seconds_sum{stage="fused",state="busy"} 1.5',
-            'SeaweedFS_volume_ec_pipeline_seconds_count{stage="fused",state="busy"} 3',
-        ])
-        out = bench.ec_pipeline_summary_from_metrics(text)
-        assert out["read"]["busy_seconds"] == 2.0
-        assert out["read"]["wait_seconds"] == 6.0
-        assert out["read"]["utilization"] == 0.25
-        assert out["fused"]["busy_seconds"] == 1.5
-        assert out["fused"]["utilization"] == 1.0
 
 
 @pytest.fixture(scope="class")

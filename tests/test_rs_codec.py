@@ -3,8 +3,8 @@
 import numpy as np
 import pytest
 
-from seaweedfs_tpu.ops import device, gf256, rs_kernel, rs_pallas
-from seaweedfs_tpu.ops.rs_kernel import RSCodec, gf_matmul_jax
+from seaweedfs_tpu.ops import device, gf256, rs_kernel
+from seaweedfs_tpu.ops.rs_kernel import RSCodec
 from seaweedfs_tpu.stats import default_registry, trace
 
 
@@ -126,16 +126,6 @@ class TestRSCodec:
             assert np.array_equal(p_np, p_jax)
 
 
-class TestJaxChunking:
-    def test_chunked_equals_whole(self):
-        rng = np.random.RandomState(4)
-        m = gf256.parity_rows(10, 4)
-        data = rng.randint(0, 256, size=(10, 1000)).astype(np.uint8)
-        whole = np.asarray(gf_matmul_jax(m, data))
-        chunked = np.asarray(gf_matmul_jax(m, data, chunk=96))
-        assert np.array_equal(whole, chunked)
-
-
 # --- the jax door: host bytes in, one device program, host bytes out ----------
 # tests/test_rs_pallas.py runs the same checks through the Pallas form.
 
@@ -192,7 +182,7 @@ def check_one_bucket_one_program(n_first: int, n_second: int) -> None:
 
 
 @pytest.mark.parametrize("lost", [3, 11], ids=["lost-data", "lost-parity"])
-@pytest.mark.parametrize("n", door_widths(rs_pallas.TILE))
+@pytest.mark.parametrize("n", door_widths(rs_kernel.TILE))
 def test_door_host_bytes_match_the_oracle(n, lost):
     assert door_widths(8192) == (1, 8191, 8192, 8193, 27720, 65576, 73728)
     check_door_against_oracle(n, lost)
@@ -203,10 +193,10 @@ def test_door_lengths_of_one_bucket_share_one_program():
 
 
 # --- the ladder: a closed set of kernel widths up to one small block ----------
-# Host bytes reach the kernel at a rung of `rs_pallas.LADDER_TILES`, so a
+# Host bytes reach the kernel at a rung of `rs_kernel.LADDER_TILES`, so a
 # degraded read of any length up to a block compiles nothing beyond the rungs.
 
-RUNGS = rs_pallas.LADDER_TILES
+RUNGS = rs_kernel.LADDER_TILES
 LOST = 6
 # data shards without the lost one, and the first parity shard: ten survivors
 SURVIVORS = tuple(i for i in range(11) if i != LOST)
@@ -217,7 +207,7 @@ def test_ladder_is_small_closed_and_ends_at_one_small_block():
     from seaweedfs_tpu.storage.erasure_coding.geometry import SMALL_BLOCK_SIZE
 
     assert list(RUNGS) == sorted(set(RUNGS)) and len(RUNGS) <= 20
-    assert RUNGS[-1] * rs_pallas.TILE == SMALL_BLOCK_SIZE
+    assert RUNGS[-1] * rs_kernel.TILE == SMALL_BLOCK_SIZE
     assert RUNGS[:9] == tuple(range(1, 10))  # every width a 64 KiB needle makes
     for lo, hi in zip(RUNGS[8:], RUNGS[9:]):  # a step up wastes under a third
         assert (hi - lo) * 3 <= hi
@@ -226,7 +216,7 @@ def test_ladder_is_small_closed_and_ends_at_one_small_block():
 @pytest.mark.parametrize("n", [1, 40, 8191, 8192, 8193, 27720, 65536, 65576,
                                73727, 73728])
 def test_ladder_maps_a_64k_needles_widths_as_before(n):
-    assert rs_pallas.ladder_width(n, 8192) == n + (-n) % 8192
+    assert rs_kernel.ladder_width(n, 8192) == n + (-n) % 8192
 
 
 @pytest.mark.parametrize("n,want", [
@@ -236,7 +226,7 @@ def test_ladder_maps_a_64k_needles_widths_as_before(n):
     (1048577, 1048576 + 8192), (4 * 1048576 + 5, 4 * 1048576 + 8192),
 ])
 def test_ladder_above_a_64k_needle_and_beyond_a_block(n, want):
-    assert rs_pallas.ladder_width(n, 8192) == want
+    assert rs_kernel.ladder_width(n, 8192) == want
 
 
 def kernel_shapes() -> int:
@@ -275,7 +265,7 @@ def check_ladder_widths(ladder: dict, widths) -> None:
     assert all((LADDER_MATRIX, 1, 10, t * tile) in device._kernel_shapes
                for t in RUNGS)
     for n in widths:
-        width = rs_pallas.ladder_width(n, tile)
+        width = rs_kernel.ladder_width(n, tile)
         assert width >= n and width // tile in RUNGS and width % tile == 0
         if n <= 9 * tile:
             assert width == n + (-n) % tile
@@ -301,7 +291,7 @@ def sweep_widths(seed: int, tile: int, count: int = 50) -> list[int]:
 def xla_ladder():
     """The XLA form (the CPU's) at the kernel's own tile: rungs of 8 KiB to
     1 MiB."""
-    return warm_ladder(rs_pallas.TILE)
+    return warm_ladder(rs_kernel.TILE)
 
 
 @pytest.mark.parametrize("rung", RUNGS)
@@ -315,11 +305,10 @@ def test_ladder_sweep_of_widths_up_to_a_block_compiles_nothing(xla_ladder, seed)
 
 
 def check_direct_host_array_goes_to_a_rung(tiles: int, tile: int) -> None:
-    """A host array handed to the kernel's own entry, not through the codec's
-    door, reaches it at a rung too: the zero tail on the host, a slice on the
+    """A host array handed to the door itself, not through the codec, reaches it at a rung too: the zero tail on the host, a slice on the
     device, and the oracle's bytes."""
     n = tiles * tile
-    width = rs_pallas.ladder_width(n, tile)
+    width = rs_kernel.ladder_width(n, tile)
     assert width > n and width // tile in RUNGS
     matrix = np.frombuffer(LADDER_MATRIX, dtype=np.uint8).reshape(1, 10)
     rng = np.random.Generator(np.random.SFC64([28, tiles]))
@@ -339,4 +328,4 @@ OFF_THE_LADDER = [10, 11, 13, 100]  # tile multiples that are no rung
 
 @pytest.mark.parametrize("tiles", OFF_THE_LADDER)
 def test_host_array_off_the_ladder_goes_to_a_rung(tiles):
-    check_direct_host_array_goes_to_a_rung(tiles, rs_pallas.TILE)
+    check_direct_host_array_goes_to_a_rung(tiles, rs_kernel.TILE)

@@ -1,7 +1,7 @@
 """The Pallas kernel body of ops/rs_pallas.py, executed.
 
-On the CPU `gf_matmul_jax` takes the XLA form, so nothing else in the suite
-runs the kernel. Here the test wraps `pl.pallas_call` with `interpret=True`
+On the CPU the door (`rs_kernel._enqueue`) takes the XLA form, so nothing
+else in the suite runs the kernel. Here the test wraps `pl.pallas_call` with `interpret=True`
 (the program has no switch for it) and holds the kernel, bit for bit, to the
 numpy oracle `ops.gf256.gf_matmul_bytes` for every matrix shape the served
 path hands it.
@@ -40,9 +40,17 @@ def interpreted(monkeypatch):
     monkeypatch.setattr(
         pl, "pallas_call", functools.partial(pl.pallas_call, interpret=True)
     )
-    rs_pallas._compiled.cache_clear()  # traces made without the wrapper
+    rs_pallas.compiled.cache_clear()  # traces made without the wrapper
     yield
-    rs_pallas._compiled.cache_clear()  # and the ones made with it
+    rs_pallas.compiled.cache_clear()  # and the ones made with it
+
+
+@pytest.fixture()
+def pallas_door(interpreted, monkeypatch):
+    """The door as it runs on a TPU: over the Pallas form (here
+    interpreted), at this file's tile."""
+    monkeypatch.setattr(rs_kernel, "transform_kernel", lambda: "pallas")
+    monkeypatch.setattr(rs_kernel, "TILE", TILE)
 
 
 def _rebuild_matrix(missing: tuple[int, ...]) -> np.ndarray:
@@ -75,24 +83,16 @@ CASES = [
 @pytest.mark.parametrize(
     "matrix,n", [c[1:] for c in CASES], ids=[c[0] for c in CASES]
 )
-def test_kernel_matches_numpy_oracle(interpreted, matrix, n):
+def test_kernel_matches_numpy_oracle(pallas_door, matrix, n):
     rng = np.random.RandomState(n + matrix.shape[0] * 31 + matrix.shape[1])
     shards = rng.randint(0, 256, size=(matrix.shape[1], n), dtype=np.uint8)
-    got = np.asarray(rs_pallas.gf_matmul_pallas(matrix, shards, tile=TILE))
+    got = np.asarray(rs_kernel.gf_matmul_jax(matrix, shards))
     want = gf256.gf_matmul_bytes(matrix, shards)
     assert got.shape == want.shape and got.dtype == np.uint8
     assert np.array_equal(got, want)
 
 
 # --- the codec's door over the Pallas form -------------------------------------
-@pytest.fixture()
-def pallas_door(interpreted, monkeypatch):
-    """RSCodec(backend="jax") as it runs on a TPU: the Pallas form (here
-    interpreted), at this file's tile."""
-    monkeypatch.setattr(rs_kernel, "transform_kernel", lambda: "pallas")
-    monkeypatch.setattr(rs_pallas, "TILE", TILE)
-
-
 @pytest.mark.parametrize("lost", [3, 11], ids=["lost-data", "lost-parity"])
 @pytest.mark.parametrize("n", door_widths(TILE))
 def test_door_host_bytes_match_the_oracle(pallas_door, n, lost):
@@ -118,7 +118,7 @@ def test_device_array_path_is_what_it_was(request, entry, form, programs):
     buf = rng.randint(0, 256, size=rows * DATA * block, dtype=np.uint8)
     data = np.ascontiguousarray(
         buf.reshape(rows, DATA, block).transpose(1, 0, 2)).reshape(DATA, -1)
-    assert data.shape[1] % TILE and data.shape[1] % rs_pallas.TILE
+    assert data.shape[1] % TILE and data.shape[1] % rs_kernel.TILE
     before = device_programs()
     if entry == "apply2d_async":
         got = codec.apply2d_async(gf256.parity_rows(DATA, PARITY), data).result()
@@ -147,10 +147,10 @@ def pallas_ladder():
     mp.setattr(pl, "pallas_call",
                functools.partial(pl.pallas_call, interpret=True))
     mp.setattr(rs_kernel, "transform_kernel", lambda: "pallas")
-    mp.setattr(rs_pallas, "TILE", TILE)
-    rs_pallas._compiled.cache_clear()
+    mp.setattr(rs_kernel, "TILE", TILE)
+    rs_pallas.compiled.cache_clear()
     yield warm_ladder(TILE)
-    rs_pallas._compiled.cache_clear()
+    rs_pallas.compiled.cache_clear()
     mp.undo()
 
 

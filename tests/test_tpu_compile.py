@@ -68,6 +68,16 @@ def _u8(shape, sharding):
     return jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=sharding)
 
 
+@pytest.fixture()
+def pallas_door(monkeypatch):
+    """`ops.rs_kernel` with its door over the Pallas body, as on a TPU: here
+    `jax.default_backend()` is the CPU, so the test steers the choice."""
+    from seaweedfs_tpu.ops import rs_kernel
+
+    monkeypatch.setattr(rs_kernel, "transform_kernel", lambda: "pallas")
+    return rs_kernel
+
+
 def _rebuild_matrix(missing: tuple[int, ...]) -> np.ndarray:
     present = tuple(i for i in range(DATA + PARITY) if i not in missing)
     return gf256.decode_matrix(DATA, PARITY, present, missing)
@@ -99,12 +109,9 @@ PALLAS_CASES = [
 @pytest.mark.parametrize(
     "matrix,n", [c[1:] for c in PALLAS_CASES], ids=[c[0] for c in PALLAS_CASES]
 )
-def test_pallas_transform_compiles(one_chip, matrix, n):
-    from seaweedfs_tpu.ops import rs_pallas
-
-    matrix = np.ascontiguousarray(matrix)
+def test_pallas_transform_compiles(one_chip, pallas_door, matrix, n):
     compiled = _compile(
-        functools.partial(rs_pallas.gf_matmul_pallas, matrix),
+        functools.partial(pallas_door.gf_matmul_jax, matrix),
         _u8((matrix.shape[1], n), one_chip),
     )
     text = compiled.as_text()
@@ -112,17 +119,15 @@ def test_pallas_transform_compiles(one_chip, matrix, n):
     assert "%rs_gf_matmul" in text  # the name the device trace shows
 
 
-def test_pipeline_encode_rows_compiles(one_chip):
+def test_pipeline_encode_rows_compiles(one_chip, pallas_door):
     """The device half of RSCodec.encode_rows_async at the pipeline's batch:
     32 rows of 10 x 1 MiB in .dat order -> reshape/transpose -> kernel."""
-    from seaweedfs_tpu.ops import rs_pallas
-
     rows, block = DEVICE_BATCH // MiB, MiB
     m = gf256.parity_rows(DATA, PARITY)
 
     def device_half(flat):
         x = flat.reshape(rows, DATA, block).transpose(1, 0, 2)
-        return rs_pallas.gf_matmul_pallas(m, x.reshape(DATA, -1))
+        return pallas_door.gf_matmul_jax(m, x.reshape(DATA, -1))
 
     compiled = _compile(device_half, _u8((rows * DATA * block,), one_chip))
     assert "tpu_custom_call" in compiled.as_text()
@@ -136,8 +141,7 @@ def test_xla_transform_compiles(one_chip):
     from seaweedfs_tpu.ops import rs_kernel
 
     m = gf256.parity_rows(DATA, PARITY)
-    a = gf256.bit_matrix(m)
-    fn = rs_kernel._compiled_transform(PARITY, DATA, a.tobytes())
+    fn = rs_kernel._compiled_xla(PARITY, DATA, m.tobytes(), rs_kernel.TILE)
     fn.lower(_u8((DATA, 4 * MiB), one_chip)).compile()
 
 
@@ -148,21 +152,3 @@ def test_hash_kernel_compiles(one_chip, kernel):
 
     mod = md5_kernel if kernel == "md5" else crc32c_kernel
     mod._compiled_batch(4096).lower(_u8((8192, 4096), one_chip)).compile()
-
-
-def test_sharded_encode_compiles_on_four_chips(topo):
-    """parallel/ has no caller a user can reach yet; until it has one, this
-    compile on a four-device mesh is what guards it."""
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-    from seaweedfs_tpu.parallel import ec_shard_map
-
-    mesh = Mesh(np.array(topo.devices), ("dp",))
-    assert mesh.size == 4
-    n_volumes, n = 8, 4 * MiB
-    fn = ec_shard_map._encode_fn(mesh, n_volumes, n)
-    compiled = fn.lower(
-        _u8((n_volumes, DATA, n), NamedSharding(mesh, P("dp", None, None)))
-    ).compile()
-    out = compiled.output_shardings
-    assert out.spec == P("dp", None, None)

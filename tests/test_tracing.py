@@ -289,20 +289,6 @@ class TestKernelSpans:
         assert "SeaweedFS_filer_hash_seconds_sum" in text
         assert "SeaweedFS_filer_hash_bytes_total" in text
 
-    def test_kernel_gbps_scrape(self):
-        """bench.kernel_gbps_from_metrics computes per-kernel GB/s from
-        exposition text alone."""
-        import bench
-
-        text = "\n".join([
-            'SeaweedFS_volume_ec_encode_seconds_sum{kernel="fused"} 0.5',
-            'SeaweedFS_volume_ec_encode_seconds_count{kernel="fused"} 2',
-            'SeaweedFS_volume_ec_encode_bytes_total{kernel="fused"} 1e+09',
-        ])
-        out = bench.kernel_gbps_from_metrics(text)
-        assert out == {
-            "volume_ec_encode:fused": {"gbps": 2.0, "seconds": 0.5, "gb": 1.0}
-        }
 
 
 class TestPushErrorCounter:
