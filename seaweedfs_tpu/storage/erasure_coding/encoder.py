@@ -9,7 +9,11 @@ stages overlap:
     reader thread --(bounded queue)--> GF transform --(bounded queue)--> writer thread
 
 * the reader pre-fetches row batches from the .dat into a small ring of
-  reusable host buffers (positional pread, zero-padded past EOF);
+  reusable host buffers (positional preadv straight into the buffer,
+  zero-padded past EOF); the rebuild's reader fills a batch the same way,
+  in place, each surviving shard file's slice into its own row, one file
+  after another (a survivor that comes up short is an IOError, never
+  padded; ten reads side by side gained nothing, PERF.md §6 PR 31);
 * the transform stage submits each batch to the RSCodec pipeline backend —
   on the TPU that is chunked host->HBM puts feeding the Pallas bit-plane
   matmul with async dispatch, on the CPU one GIL-released GFNI/AVX-512
@@ -107,6 +111,18 @@ def _pread_padded(fd: int, offset: int, size: int, out: np.ndarray) -> None:
     got = os.preadv(fd, [memoryview(out)[:size]], offset)
     if got < size:
         out[got:size] = 0
+
+
+def _pread_exact(
+    fd: int, offset: int, size: int, out: np.ndarray, shard_id: int
+) -> None:
+    """The same read in place for the rebuild, where a surviving shard that
+    ends before offset + size is an error and must never be zero-filled."""
+    got = os.preadv(fd, [memoryview(out)[:size]], offset)
+    if got != size:
+        raise IOError(
+            f"ec shard {shard_id} short read at {offset}: {got} != {size}"
+        )
 
 
 def _schedule(total: int, large: int, small: int, batch: int):
@@ -674,13 +690,7 @@ def _rebuild_ec_files(
                 buf = _ensure_buf(buf, need, chunk * DATA_SHARDS_COUNT)
                 view = buf[:need].reshape(DATA_SHARDS_COUNT, width)
                 for i, sid in enumerate(use):
-                    data = os.pread(present_fds[sid], width, off)
-                    if len(data) != width:
-                        raise IOError(
-                            f"ec shard {sid} short read at {off}:"
-                            f" {len(data)} != {width}"
-                        )
-                    view[i] = np.frombuffer(data, dtype=np.uint8)
+                    _pread_exact(present_fds[sid], off, width, view[i], sid)
                 return buf
 
             def encode_job(job, buf):

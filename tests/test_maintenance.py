@@ -631,9 +631,14 @@ class TestSelfHealing:
             return False
 
         wait_until(compacted, msg=f"volume {vid} vacuum")
-        st = get_json(f"{master.url}/debug/maintenance")
-        assert any(h["task"]["type"] == "vacuum"
-                   and h["state"] == "completed" for h in st["history"])
+        wait_until(  # history append trails the compaction by a moment
+            lambda: any(
+                h["task"]["type"] == "vacuum" and h["state"] == "completed"
+                for h in get_json(
+                    f"{master.url}/debug/maintenance")["history"]
+            ),
+            msg="vacuum in history",
+        )
         # the surviving blob is intact post-compaction. Read through a
         # location lookup like a real client: the daemon owns EVERY
         # repair class while enabled, and its balance task may have
