@@ -49,17 +49,40 @@ class Run:
         self.outdir = os.path.join(self.workdir, "logs")
         self.notes: dict = {}
         self.server: cluster.Server | None = None
-        self.payload: volume.Payload | None = None
-        self.vid = self.key0 = self.dat_bytes = 0
-        self.cookie = ""
-        self.kept_base = ""
+        # the configuration's volumes, all of one collection ("": the default)
+        self.collection = str(self.config.get("collection", ""))
+        self.vols: list[volume.Vol] = []
         self.memory_peak = None
 
     def log(self, msg: str) -> None:
         print(f"[bench] {msg}", file=sys.stderr, flush=True)
 
-    def fid_of(self, i: int) -> str:
-        return f"{self.vid},{self.key0 + i:x}{self.cookie}"
+    @property
+    def dat_bytes(self) -> int:
+        """All the volumes' `.dat` bytes: what one verb over them counts for."""
+        return sum(vol.dat_bytes for vol in self.vols)
+
+    def needle(self, g: int) -> tuple[str, memoryview]:
+        """(fid, payload) of needle g, numbered through all the volumes:
+        needle i of volume v is g = v * needles + i."""
+        v, i = divmod(g, self.size["needles"])
+        return self.vols[v].fid_of(i), self.vols[v].payload.of(i)
+
+    def shell(self, template: str, log_name: str) -> tuple[int, str, float]:
+        """A traffic file's script through one `shell` child, `{vid}` the id
+        of volume 0 and `{collection}` the configuration's collection."""
+        return self.server.shell(
+            template.format(vid=self.vols[0].vid, collection=self.collection),
+            os.path.join(self.outdir, log_name))
+
+    def seal_verb(self, template: str, log_name: str) -> tuple[bool, str, float]:
+        """One script that has to seal every volume: (ok, output, seconds).
+        Ok only if the exit code is 0 and the verb says of each volume, and
+        of no other, that its shards are spread."""
+        rc, text, seconds = self.shell(template, log_name)
+        ok = (rc == 0 and text.count(": shards spread") == len(self.vols) and all(
+            f"ec.encode volume {vol.vid}: shards spread" in text for vol in self.vols))
+        return ok, text, seconds
 
     # --- set-up -------------------------------------------------------------
     def setup(self, own_jax_platforms: bool) -> None:
@@ -68,18 +91,19 @@ class Run:
         env = cluster.child_env(os.environ, own_jax_platforms)
         self.server = srv = cluster.Server(
             self.workdir, env, os.path.join(self.outdir, "server.log"))
-        n, nbytes = self.size["needles"], self.size["needle_bytes"]
-        self.payload = volume.Payload(self.seed, n, nbytes)
+        self.vols = [
+            volume.Vol(v, volume.Payload(self.seed, self.size["needles"],
+                                         self.size["needle_bytes"], v))
+            for v in range(int(self.config.get("volumes", 1)))]
         t0 = time.perf_counter()
-        assign = srv.assign(n)
+        self.assign_volumes()
         self.notes["boot_s"] = time.perf_counter() - t0
-        fid0 = assign["fid"]
-        self.vid, self.key0, self.cookie = volume.parse_fid(fid0)
         t0 = time.perf_counter()
-        errors = volume.fill(srv.volume, fid0, self.payload,
-                             int(self.config["assumed"]["fill_writers"]))
-        if errors:
-            raise cluster.RunError(f"{len(errors)} writes failed: {errors[:3]}")
+        for vol in self.vols:
+            errors = volume.fill(srv.volume, vol.fid0, vol.payload,
+                                 int(self.config["assumed"]["fill_writers"]))
+            if errors:
+                raise cluster.RunError(f"{len(errors)} writes failed: {errors[:3]}")
         self.notes["fill_s"] = time.perf_counter() - t0
         if self.traffic.get("sync_after_fill"):
             # a volume at rest: the fill's pages are on disk (and still in the
@@ -88,18 +112,20 @@ class Run:
             t0 = time.perf_counter()
             os.sync()
             self.notes["sync_s"] = time.perf_counter() - t0
-        # the volume as it was acknowledged, under a second name: what the
+        # each volume as it was acknowledged, under a second name: what the
         # reference is computed from, and what a restore links back
-        self.kept_base = os.path.join(self.workdir, "kept")
-        for ext in (".dat", ".idx"):
-            os.link(os.path.join(srv.dir, f"{self.vid}{ext}"), self.kept_base + ext)
-        self.dat_bytes = os.path.getsize(self.kept_base + ".dat")
+        for vol in self.vols:
+            vol.kept_base = os.path.join(
+                self.workdir, f"kept_{vol.number}" if vol.number else "kept")
+            base = volume.file_base(srv.dir, self.collection, vol.vid)
+            for ext in (".dat", ".idx"):
+                os.link(base + ext, vol.kept_base + ext)
+            vol.dat_bytes = os.path.getsize(vol.kept_base + ".dat")
         # the first encode: first use of the device, pays jax's start and
         # every compile of the pipeline
-        rc, text, seconds = srv.shell(
-            f"lock\nec.encode -volumeId {self.vid}\nunlock\n",
-            os.path.join(self.outdir, "setup_encode.log"))
-        if rc != 0 or f"ec.encode volume {self.vid}: shards spread" not in text:
+        ok, text, seconds = self.seal_verb(
+            loops.first_encode(self.traffic), "setup_encode.log")
+        if not ok:
             raise cluster.RunError(f"the first ec.encode failed: {text[-600:]!r}")
         self.notes["first_encode_s"] = seconds
         seen = srv.status().get("ec", {}).get("jax", {})
@@ -112,6 +138,40 @@ class Run:
         t0 = time.perf_counter()
         self.loop.prepare()
         self.notes["prepare_s"] = time.perf_counter() - t0
+
+    def assign_volumes(self) -> None:
+        """Has the master grow the collection and hand out one run of keys in
+        each of as many volumes as the configuration holds: `/dir/assign`
+        picks a volume at random, so it is asked until that many have come
+        up, volume 0 taking the first. A configuration that names its
+        collection then has it hold those volumes and no other (`ec.encode
+        -collection` takes every volume of it): the empty ones that the
+        master grew beside them are deleted through the volume server."""
+        srv, n = self.server, self.size["needles"]
+        taken: dict[int, str] = {}
+        for _ in range(200):
+            fid = srv.assign(n, self.collection)["fid"]
+            taken.setdefault(volume.parse_fid(fid)[0], fid)
+            if len(taken) == len(self.vols):
+                break
+        else:
+            raise cluster.RunError(
+                f"the master handed out keys in {len(taken)} volumes of"
+                f" {len(self.vols)}: one growth is all it makes")
+        for vol, fid in zip(self.vols, taken.values()):
+            vol.assigned(fid)
+        deleted = []
+        if "collection" in self.config:
+            for v in srv.status().get("volumes", []):
+                if v.get("collection", "") == self.collection and v["id"] not in taken:
+                    cluster.post_json(srv.volume, "/admin/delete_volume", {"volume": v["id"]})
+                    deleted.append(v["id"])
+        self.notes["volumes"] = {
+            "made": "grown by the master at /dir/assign"
+                    + (f"?collection={self.collection}" if self.collection else "")
+                    + ", one run of keys assigned in each, filled over HTTP",
+            "ids": [vol.vid for vol in self.vols], "collection": self.collection,
+            "empty_ones_deleted": deleted}
 
     # --- the whole run --------------------------------------------------------
     def execute(self, own_jax_platforms: bool = False) -> dict:
@@ -155,7 +215,8 @@ class Run:
             path = self.loop.produced_shard_path()
             at, length = os.path.getsize(path) // 2, 1
         elif fault == "corrupt-surviving-shard":
-            path = os.path.join(self.server.dir, f"{self.loop.vid}.ec05")
+            path = volume.file_base(
+                self.server.dir, self.collection, self.vols[0].vid) + ".ec05"
             at, length = 0, reference.SMALL_BLOCK
         else:
             raise cluster.RunError(f"unknown fault {fault!r}")
@@ -173,10 +234,9 @@ class Run:
             return 0, 0
         import numpy as np
 
-        n = self.payload.needles
+        n = len(self.vols) * self.size["needles"]
         rng = np.random.Generator(np.random.SFC64([self.seed, 4]))
         picks = [int(i) for i in rng.choice(n, size=min(n, 16), replace=False)]
-        self.vid = self.loop.vid  # a restored volume has a new id
         return loops.NeedleReader(self).read_all(picks, threads=1), len(picks)
 
     # --- correct ----------------------------------------------------------------
@@ -188,7 +248,9 @@ class Run:
         want = None
         t0 = time.perf_counter()
         if loop.compares_shards:
-            want = reference.expected_shards(self.kept_base + ".dat")
+            # every volume's shards from that volume's own kept file
+            want = [reference.expected_shards(vol.kept_base + ".dat")
+                    for vol in self.vols]
         self.notes["reference_compute_s"] = time.perf_counter() - t0
         checks = dict(loop.compare(want))
         self.notes["reference_s"] = time.perf_counter() - t0
@@ -265,6 +327,7 @@ class Run:
             trace = ctx.get("trace")
             if trace:
                 device["busy_s"] = trace["busy_s"]
+                device["busy_s_per_chip"] = trace["busy_s_per_chip"]
                 device["window_s"] = trace["window_s"]
                 stage = ctx.get("busiest_stage", "")
                 out["breakdown"] = {

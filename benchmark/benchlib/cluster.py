@@ -73,8 +73,8 @@ def child_env(base: dict, own_jax_platforms: bool) -> dict:
 
 class Server:
     """`python -m seaweedfs_tpu.command.main server` (master + volume) as a
-    child, started as `chip_smoke.py` starts it, with the EC pipeline set to
-    the device in the child's environment only."""
+    child, with the EC pipeline set to the device in the child's environment
+    only."""
 
     def __init__(self, workdir: str, env: dict, log_path: str) -> None:
         self.dir = os.path.join(workdir, "srv")
@@ -104,15 +104,18 @@ class Server:
         )
         self.volume = ""  # host:port, known after the first assign
 
-    def assign(self, count: int, deadline_s: float = 180.0) -> dict:
-        """First `/dir/assign`: also the wait for the server to be up."""
+    def assign(self, count: int, collection: str = "",
+               deadline_s: float = 180.0) -> dict:
+        """`/dir/assign` of `count` keys in a volume of the collection; the
+        first one is also the wait for the server to be up."""
+        query = f"count={count}" + (f"&collection={collection}" if collection else "")
         deadline = time.monotonic() + deadline_s
         while True:
             if self.proc.poll() is not None:
                 raise RunError(f"server exited {self.proc.returncode} at boot;"
                                f" see {self.log_path}")
             try:
-                out = get_json(self.master, f"/dir/assign?count={count}")
+                out = get_json(self.master, f"/dir/assign?{query}")
                 if "fid" in out:
                     self.volume = out["url"]
                     return out

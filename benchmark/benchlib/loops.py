@@ -3,10 +3,14 @@ benchmark/traffic/ that names a loop kind and its parameters; a cell's window
 is that loop repeated in place until the window closes.
 
 Loop kinds:
-  seal    one closed-loop sealer: `ec.encode` of the volume, then (between
-          verbs, inside the window) the volume restored under a new id
+  seal    one closed-loop sealer: one script that seals every volume of the
+          configuration (`ec.encode -volumeId {vid}` of its one volume, or
+          `ec.encode -collection {collection}` of its N), then (between
+          verbs, inside the window) each volume restored under a new id
   repair  one closed-loop repairer: remove one shard, `ec.rebuild`
   read    N closed-loop readers of needles drawn from the seed
+
+`repair` and `read` drive a configuration of one volume.
 
 Verb loops close on a verb's end: verbs run back to back from the window's
 start, the window closes when the first verb finishes at or after the asked
@@ -25,6 +29,13 @@ import numpy as np
 from . import cluster, promtext, reference, stats, volume
 
 ALL_SHARDS = list(range(reference.TOTAL))
+# how set-up seals the one volume of a mix that repairs or reads it
+SEAL_ONE = "lock\nec.encode -volumeId {vid}\nunlock\n"
+
+
+def first_encode(traffic: dict) -> str:
+    """The script of set-up's first encode: a seal mix's own verb."""
+    return traffic["verb"] if traffic["loop"] == "seal" else SEAL_ONE
 
 
 class Tracer:
@@ -99,7 +110,6 @@ class VerbLoop:
     def __init__(self, run) -> None:
         self.run = run
         self.unit_bytes = run.dat_bytes  # what one completed verb counts for
-        self.vid = run.vid
         self.server: cluster.Server = run.server
         self.traffic: dict = run.traffic
         self.verbs: list[dict] = []
@@ -145,14 +155,11 @@ class VerbLoop:
     def between(self, k: int) -> None:
         """Inside the window, after verb k-1 and before verb k."""
 
-    def shell_verb(self, k: int, vid: int, expect: str) -> dict:
-        script = self.traffic["verb"].format(vid=vid)
-        rc, text, seconds = self.server.shell(
-            script, os.path.join(self.run.outdir, f"verb_{k}.log"))
-        ok = rc == 0 and expect in text
+    def verb(self, k: int, ok: bool, text: str, seconds: float) -> dict:
         if not ok:
-            self.run.log(f"verb {k} failed (exit {rc}): {text[-400:]!r}")
-        return {"ok": ok, "seconds": seconds, "volume": vid}
+            self.run.log(f"verb {k} failed: {text[-400:]!r}")
+        return {"ok": ok, "seconds": seconds,
+                "volumes": [vol.vid for vol in self.run.vols]}
 
     def end_to_end(self) -> dict:
         done = [v for v in self.verbs if v["ok"]]
@@ -186,30 +193,39 @@ class SealLoop(VerbLoop):
         self.restore()
 
     def restore(self) -> None:
-        """Drop the sealed copy, link the kept pair under a new id, mount."""
-        srv = self.server
-        cluster.post_json(srv.volume, "/admin/ec/delete_shards", {
-            "volume": self.vid, "collection": "", "shards": ALL_SHARDS,
-            "delete_index": True})
+        """Drop every sealed copy, link each kept pair under a new id, mount."""
+        srv, run = self.server, self.run
+        for vol in run.vols:
+            cluster.post_json(srv.volume, "/admin/ec/delete_shards", {
+                "volume": vol.vid, "collection": run.collection,
+                "shards": ALL_SHARDS, "delete_index": True})
         # ids the master never hands out at this scale: assign grows a few
-        # volumes beside the filled one
-        self.vid = max(self.vid, 1000) + 1
-        for ext in (".dat", ".idx"):
-            os.link(self.run.kept_base + ext,
-                    os.path.join(srv.dir, f"{self.vid}{ext}"))
-        cluster.post_json(srv.volume, "/admin/volume/mount",
-                          {"volume": self.vid, "collection": ""})
+        # volumes beside the filled ones
+        first = max(max(vol.vid for vol in run.vols), 1000) + 1
+        for vol in run.vols:
+            vol.vid = first + vol.number
+            base = volume.file_base(srv.dir, run.collection, vol.vid)
+            for ext in (".dat", ".idx"):
+                os.link(vol.kept_base + ext, base + ext)
+            cluster.post_json(srv.volume, "/admin/volume/mount",
+                              {"volume": vol.vid, "collection": run.collection})
 
     def keep_links(self, k: int) -> None:
         d = os.path.join(self.run.workdir, f"seal_{k}")
         os.makedirs(d)
-        for s in ALL_SHARDS:
-            os.link(os.path.join(self.server.dir, f"{self.vid}.ec{s:02d}"),
-                    os.path.join(d, f"ec{s:02d}"))
+        for vol in self.run.vols:
+            base = volume.file_base(self.server.dir, self.run.collection, vol.vid)
+            for s in ALL_SHARDS:
+                os.link(f"{base}.ec{s:02d}", self.kept_shard(d, vol, s))
         self.kept.append((k, d))
 
+    @staticmethod
+    def kept_shard(d: str, vol: volume.Vol, shard: int) -> str:
+        return os.path.join(
+            d, (f"v{vol.number}." if vol.number else "") + f"ec{shard:02d}")
+
     def one(self, k: int) -> dict:
-        return self.shell_verb(k, self.vid, f"ec.encode volume {self.vid}: shards spread")
+        return self.verb(k, *self.run.seal_verb(self.traffic["verb"], f"verb_{k}.log"))
 
     def between(self, k: int) -> None:
         if k - 1 in self.keep_idx:
@@ -220,23 +236,31 @@ class SealLoop(VerbLoop):
         if self.verbs and self.verbs[-1]["ok"]:
             self.keep_links(len(self.verbs) - 1)
 
-    def compare(self, want: np.ndarray) -> dict:
+    def compare(self, want: list[np.ndarray]) -> dict:
         differing = reference.files_differing(
-            [(os.path.join(d, f"ec{s:02d}"), want[s])
-             for _, d in self.kept for s in ALL_SHARDS])
+            [(self.kept_shard(d, vol, s), want[vol.number][s])
+             for _, d in self.kept for vol in self.run.vols for s in ALL_SHARDS])
         return {"shard_files_differing": (differing, 0),
                 "seals_compared": (len(self.kept), None)}
 
     def device_bytes_expected(self) -> float:
-        return sum(1 for v in self.verbs if v["ok"]) * float(self.run.dat_bytes)
+        return sum(1 for v in self.verbs if v["ok"]) * float(self.unit_bytes)
 
     def produced_shard_path(self) -> str:
-        return os.path.join(self.kept[-1][1], "ec11")
+        return self.kept_shard(self.kept[-1][1], self.run.vols[-1], 11)
+
+
+def one_volume(run, kind: str) -> volume.Vol:
+    if len(run.vols) != 1:
+        raise cluster.RunError(f"the `{kind}` loop drives a configuration of one"
+                               f" volume; this one holds {len(run.vols)}")
+    return run.vols[0]
 
 
 class RepairLoop(VerbLoop):
     def __init__(self, run) -> None:
         super().__init__(run)
+        self.vol = one_volume(run, "repair")
         self.shard_bytes = reference.shard_file_size(run.dat_bytes)
         # the mix's shard ids in an order drawn from the seed. The program
         # compiles a device program for every coefficient matrix, that is for
@@ -253,8 +277,10 @@ class RepairLoop(VerbLoop):
 
     def cycle(self, k: int, shard: int) -> dict:
         removed = cluster.post_json(self.server.volume, "/admin/ec/delete_shards", {
-            "volume": self.vid, "collection": "", "shards": [shard]})
-        verb = self.shell_verb(k, self.vid, f"rebuilt shards [{shard}]")
+            "volume": self.vol.vid, "collection": self.run.collection,
+            "shards": [shard]})
+        rc, text, seconds = self.run.shell(self.traffic["verb"], f"verb_{k}.log")
+        verb = self.verb(k, rc == 0 and f"rebuilt shards [{shard}]" in text, text, seconds)
         verb["shard"] = shard
         verb["ok"] = verb["ok"] and removed.get("removed") == [shard]
         return verb
@@ -265,10 +291,13 @@ class RepairLoop(VerbLoop):
     def after_window(self) -> None:
         pass
 
-    def compare(self, want: np.ndarray) -> dict:
-        base = os.path.join(self.server.dir, str(self.vid))
+    def shard_path(self, shard: int) -> str:
+        return volume.file_base(
+            self.server.dir, self.run.collection, self.vol.vid) + f".ec{shard:02d}"
+
+    def compare(self, want: list[np.ndarray]) -> dict:
         differing = reference.files_differing(
-            [(f"{base}.ec{s:02d}", want[s]) for s in ALL_SHARDS])
+            [(self.shard_path(s), want[0][s]) for s in ALL_SHARDS])
         rebuilt = {v["shard"] for v in self.verbs if v["ok"]}
         return {"shard_files_differing": (differing, 0),
                 "shards_rebuilt_in_window": (len(rebuilt), None)}
@@ -278,8 +307,7 @@ class RepairLoop(VerbLoop):
             self.shard_bytes * reference.DATA)
 
     def produced_shard_path(self) -> str:
-        shard = self.verbs[-1]["shard"]
-        return os.path.join(self.server.dir, f"{self.vid}.ec{shard:02d}")
+        return self.shard_path(self.verbs[-1]["shard"])
 
 
 class NeedleReader:
@@ -288,12 +316,15 @@ class NeedleReader:
     def __init__(self, run) -> None:
         self.run = run
         self.first_errors: list[str] = []
+        self.compare_seconds: list[float] = []  # what each comparison cost
 
     def get(self, conn: http.client.HTTPConnection, i: int) -> tuple[float, int]:
-        """(seconds from send to last byte, 0 right | 1 wrong | 2 failed)."""
+        """(seconds from send to last byte, 0 right | 1 wrong | 2 failed) of
+        needle i, numbered through all the run's volumes."""
+        fid, want = self.run.needle(i)
         t0 = time.perf_counter()
         try:
-            conn.request("GET", "/" + self.run.fid_of(i))
+            conn.request("GET", "/" + fid)
             resp = conn.getresponse()
             body = resp.read()
             dt = time.perf_counter() - t0
@@ -304,7 +335,10 @@ class NeedleReader:
         if resp.status != 200:
             self._note(f"needle {i}: {resp.status} {body[:120]!r}")
             return dt, 2
-        return dt, 0 if body == self.run.payload.of(i) else 1
+        t0 = time.perf_counter()
+        same = volume.same_bytes(body, want)
+        self.compare_seconds.append(time.perf_counter() - t0)
+        return dt, 0 if same else 1
 
     def _note(self, what: str) -> None:
         if len(self.first_errors) < 5:
@@ -343,7 +377,7 @@ class ReadLoop:
         self.run = run
         self.server: cluster.Server = run.server
         self.traffic: dict = run.traffic
-        self.vid = run.vid
+        self.vol = one_volume(run, "read")
         self.clients = int(self.traffic["clients"])
         self.lost = [int(s) for s in run.config.get("lost_shards", [])]
         self.latencies: list[float] = []
@@ -357,13 +391,15 @@ class ReadLoop:
         """Lose the configuration's shards, find the needles the mix reads,
         and read once every interval length they will make the server
         reconstruct: each new length is a new shape to the device path."""
-        run, srv = self.run, self.server
+        run, srv, key0 = self.run, self.server, self.vol.key0
         if self.lost:
             removed = cluster.post_json(srv.volume, "/admin/ec/delete_shards", {
-                "volume": self.vid, "collection": "", "shards": self.lost})
+                "volume": self.vol.vid, "collection": run.collection,
+                "shards": self.lost})
             if removed.get("removed") != self.lost:
                 raise cluster.RunError(f"shards {self.lost} not removed: {removed}")
-        index = volume.read_index(os.path.join(srv.dir, f"{self.vid}.ecx"))
+        index = volume.read_index(
+            volume.file_base(srv.dir, run.collection, self.vol.vid) + ".ecx")
         touching = volume.records_on_shards(index, run.dat_bytes, self.lost)
         among = self.traffic.get("among", "all")
         if among == "touching-lost-shards":
@@ -372,7 +408,7 @@ class ReadLoop:
             keys = sorted(k for k, _, _ in index)
         else:
             raise cluster.RunError(f"traffic: unknown among {among!r}")
-        self.pool = [k - run.key0 for k in keys]
+        self.pool = [k - key0 for k in keys]
         if not self.pool:
             raise cluster.RunError("the mix selects no needle")
         seen: set[int] = set()
@@ -381,7 +417,7 @@ class ReadLoop:
             new = set(touching.get(key, ())) - seen
             if new:
                 seen |= new
-                warm.append(key - run.key0)
+                warm.append(key - key0)
         run.notes["degraded_needles"] = len(touching)
         run.notes["reconstruct_lengths_warmed"] = len(seen)
         bad = self.reader.read_all(warm, threads=min(4, self.clients))
@@ -396,6 +432,8 @@ class ReadLoop:
     def window(self, seconds: float, trace: bool) -> None:
         pool = np.asarray(self.pool)
         results: list[list[tuple[float, int]]] = [[] for _ in range(self.clients)]
+        self.reader.compare_seconds.clear()
+        cpu0 = time.process_time()
         t0 = time.perf_counter()
         deadline = t0 + seconds
 
@@ -429,6 +467,13 @@ class ReadLoop:
         # the window is all the time until the last read that was started in
         # it has come back
         self.elapsed = time.perf_counter() - t0
+        # the load generator's own share, so that a window in which it, and
+        # not the server, sets the pace says so
+        compared = self.reader.compare_seconds
+        self.run.notes["generator"] = {
+            "compare_ms_per_read": sum(compared) / max(1, len(compared)) * 1e3,
+            "runner_cpu_s_per_window_s": (time.process_time() - cpu0) / self.elapsed,
+        }
         for out in results:
             for dt, verdict in out:
                 self.latencies.append(dt)
@@ -456,7 +501,7 @@ class ReadLoop:
     def failed(self) -> int:
         return self.wrong + self.errors
 
-    def compare(self, want: np.ndarray | None) -> dict:
+    def compare(self, want: list[np.ndarray] | None) -> dict:
         return {"reads_wrong": (self.wrong, 0), "reads_failed": (self.errors, 0)}
 
     def device_bytes_expected(self) -> float:

@@ -3,7 +3,9 @@ volume's files itself (the sorted index, upstream's shard layout)."""
 
 from __future__ import annotations
 
+import ctypes
 import http.client
+import os
 import struct
 import threading
 
@@ -13,18 +15,68 @@ from . import reference
 
 
 class Payload:
-    """Every needle's bytes, made from the seed in one call: needle i is the
-    i-th slice of one buffer, so a read is compared without making it again."""
+    """Every needle's bytes of one volume, made from the seed in one call:
+    needle i is the i-th slice of one buffer, so a read is compared without
+    making it again. `needle_bytes` is one size for every needle, or a list
+    of sizes cycled over the needles (`[4194304, 4194304, 2097152]`: a
+    10 MiB object as the filer cuts it), the offsets cumulative. Volume 0 of
+    a run draws from the stream `[seed, 1]`, volume v >= 1 from
+    `[seed, 1, v]`."""
 
-    def __init__(self, seed: int, needles: int, needle_bytes: int) -> None:
+    def __init__(self, seed: int, needles: int, needle_bytes: int | list[int],
+                 volume: int = 0) -> None:
+        sizes = [needle_bytes] if isinstance(needle_bytes, int) else list(needle_bytes)
         self.needles, self.needle_bytes = needles, needle_bytes
-        words = -(-needles * needle_bytes // 8)
-        rng = np.random.Generator(np.random.SFC64([seed, 1]))
+        self._at = [0]  # needle i is bytes [_at[i], _at[i + 1])
+        for i in range(needles):
+            self._at.append(self._at[-1] + int(sizes[i % len(sizes)]))
+        words = -(-self._at[-1] // 8)
+        stream = [seed, 1] if volume == 0 else [seed, 1, volume]
+        rng = np.random.Generator(np.random.SFC64(stream))
         self._buf = memoryview(
             rng.integers(0, 2**64, size=words, dtype=np.uint64)).cast("B")
 
     def of(self, i: int) -> memoryview:
-        return self._buf[i * self.needle_bytes:(i + 1) * self.needle_bytes]
+        return self._buf[self._at[i]:self._at[i + 1]]
+
+
+_memcmp = ctypes.CDLL(None).memcmp
+_memcmp.argtypes = (ctypes.c_char_p, ctypes.c_void_p, ctypes.c_size_t)
+_memcmp.restype = ctypes.c_int
+
+
+def same_bytes(body: bytes, want: memoryview) -> bool:
+    """Whether a body that came back is exactly the payload's slice: every
+    byte, at the cost of libc's `memcmp` on the slice where it lies (no copy
+    of it is made, and the interpreter lock is not held meanwhile). The one
+    comparison of read bodies: the read loop's and the control's."""
+    n = len(want)
+    return len(body) == n and (n == 0 or _memcmp(
+        body, ctypes.addressof(ctypes.c_char.from_buffer(want)), n) == 0)
+
+
+class Vol:
+    """One filled volume of a run: which of the configuration's volumes it is,
+    its payload, the fid of its first needle as the master assigned it, the
+    id it lives under now (a restore moves it to a new one), and the pair of
+    files kept of it as it was acknowledged."""
+
+    def __init__(self, number: int, payload: Payload) -> None:
+        self.number, self.payload = number, payload
+        self.fid0 = self.cookie = self.kept_base = ""
+        self.vid = self.key0 = self.dat_bytes = 0
+
+    def assigned(self, fid0: str) -> None:
+        self.fid0 = fid0
+        self.vid, self.key0, self.cookie = parse_fid(fid0)
+
+    def fid_of(self, i: int) -> str:
+        return f"{self.vid},{self.key0 + i:x}{self.cookie}"
+
+
+def file_base(directory: str, collection: str, vid: int) -> str:
+    """Path without extension of a volume's files in a server's directory."""
+    return os.path.join(directory, f"{collection}_{vid}" if collection else str(vid))
 
 
 def parse_fid(fid: str) -> tuple[int, int, str]:

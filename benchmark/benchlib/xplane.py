@@ -50,6 +50,10 @@ def short_name(name: str) -> str:
     return f"{lhs} {op.group(1) if op else '?'} {shape}"[:100]
 
 
+# "/device:TPU:0": a chip's own plane ("/device:CUSTOM:Megascale Trace" is not)
+_CHIP_PLANE = re.compile(r"/device:[A-Za-z]+:\d+$")
+
+
 def is_device_plane(name: str) -> bool:
     return name.startswith("/device:") and "CPU" not in name.split(":")[1]
 
@@ -67,13 +71,16 @@ def profile_times(profile) -> tuple[float, float]:
 
 def reduce(profile, lo: float, hi: float, top: int = 10) -> dict:
     """What ran on the device between `lo` and `hi`, seconds from the
-    profile's start: {"chips", "busy_s" (mean over chips), "window_s" (hi -
+    profile's start: {"chips", "busy_s" (mean over chips), "busy_s_per_chip"
+    (of every chip's plane in the trace's order, 0.0 for a chip on which
+    nothing ran, which the mean leaves out), "window_s" (hi -
     lo), "device_ops": [[name, seconds]...], "idle_gaps": [[what lies around
     it, seconds]...] of the first chip, "planes": what the trace held}.
     `busy_s` is the union of the operation intervals, so operations that
     overlap count once; operations are cut at the window's ends. Only device
     planes are walked: the host's lines can hold millions of events."""
     per_chip: list[list[tuple[float, float]]] = []
+    every_chip: list[float] = []
     first_chip: list[tuple[float, float, str]] = []
     by_name: dict[str, float] = {}
     seen: list[str] = []
@@ -94,6 +101,8 @@ def reduce(profile, lo: float, hi: float, top: int = 10) -> dict:
                     by_name[name] = by_name.get(name, 0.0) + end - start
                     if not per_chip:
                         first_chip.append((start, end, name))
+        if _CHIP_PLANE.match(plane.name):
+            every_chip.append(stats.union_length(intervals))
         if intervals:
             per_chip.append(intervals)
     busy = [stats.union_length(iv) for iv in per_chip]
@@ -101,6 +110,7 @@ def reduce(profile, lo: float, hi: float, top: int = 10) -> dict:
     return {
         "chips": len(per_chip),
         "busy_s": sum(busy) / len(busy) if busy else 0.0,
+        "busy_s_per_chip": every_chip,
         "window_s": hi - lo,
         "device_ops": [[name, seconds] for name, seconds in ops],
         "idle_gaps": named_gaps(first_chip, lo, hi, top),

@@ -37,10 +37,9 @@ DATA_AT = 20          # where a record's payload starts
 def make_volume_file(path: str, payload: volume.Payload) -> int:
     """A volume file of the cell's size and layout: an 8-byte superblock,
     then one record per needle with the payload inside."""
-    n, nb = payload.needles, payload.needle_bytes
     with open(path, "wb") as f:
         f.write(bytes(8))
-        for i in range(n):
+        for i in range(payload.needles):
             f.write(bytes(DATA_AT))
             f.write(payload.of(i))
             f.write(bytes(RECORD_OVERHEAD - DATA_AT))
@@ -51,16 +50,21 @@ def control(workload: str, seed: int, size: str, workdir: str) -> dict:
     spec = cellrun.load_spec()
     run = cellrun.Run(spec, workload, seed, 0.0, False, size, 0.0)
     kind = run.traffic["loop"]
-    payload = volume.Payload(seed, run.size["needles"], run.size["needle_bytes"])
     shutil.rmtree(workdir, ignore_errors=True)
     os.makedirs(workdir)
     try:
         dat = os.path.join(workdir, "control.dat")
-        dat_bytes = make_volume_file(dat, payload)
         sound = reference.coding_matrix()
         other = reference.cauchy_matrix()
         path_of = lambda s: os.path.join(workdir, f"control.ec{s:02d}")  # noqa: E731
-        if kind in ("seal", "repair"):
+        differing = sound_differing = 0
+        # every volume of the configuration in turn; a mix that reads has one
+        for v in range(int(run.config.get("volumes", 1))):
+            payload = volume.Payload(seed, run.size["needles"],
+                                     run.size["needle_bytes"], v)
+            dat_bytes = make_volume_file(dat, payload)
+            if kind not in ("seal", "repair"):
+                break
             want = reference.expected_shards(dat)
             if kind == "seal":
                 made = reference.expected_shards(dat, matrix=other)
@@ -74,16 +78,19 @@ def control(workload: str, seed: int, size: str, workdir: str) -> dict:
             for s in range(reference.TOTAL):
                 made[s].tofile(path_of(s))
             files = [(path_of(s), want[s]) for s in range(reference.TOTAL)]
-            checks = {"shard_files_differing": (reference.files_differing(files), 0)}
+            differing += reference.files_differing(files)
             for s in range(reference.TOTAL):
                 want[s].tofile(path_of(s))
-            sound_checks = {"shard_files_differing":
-                            (reference.files_differing(files), 0)}
+            sound_differing += reference.files_differing(files)
+        if kind in ("seal", "repair"):
+            checks = {"shard_files_differing": (differing, 0)}
+            sound_checks = {"shard_files_differing": (sound_differing, 0)}
         elif kind == "read":
             lost = [int(s) for s in run.config["lost_shards"]]
-            n, nb = payload.needles, payload.needle_bytes
-            rec = nb + RECORD_OVERHEAD
-            index = [(i, 8 + i * rec, nb) for i in range(n)]
+            index, at = [], 8
+            for i in range(payload.needles):
+                index.append((i, at, len(payload.of(i))))
+                at += index[-1][2] + RECORD_OVERHEAD
             touching = volume.records_on_shards(index, dat_bytes, lost)
             rng = np.random.Generator(np.random.SFC64([seed, 3, 0]))
             picks = [sorted(touching)[int(j)] for j in
@@ -92,7 +99,7 @@ def control(workload: str, seed: int, size: str, workdir: str) -> dict:
             wrong = sound_wrong = 0
             rows = reference.padded_rows(dat)
             for i in picks:
-                off = 8 + i * rec + DATA_AT
+                off, nb = index[i][1] + DATA_AT, index[i][2]
                 body = np.frombuffer(payload.of(i), dtype=np.uint8).copy()
                 rebuilt = body.copy()
                 for b in range(off // block, (off + nb - 1) // block + 1):
@@ -110,8 +117,9 @@ def control(workload: str, seed: int, size: str, workdir: str) -> dict:
                     dec = reference.decode_rows(sound, present, [s])
                     rebuilt[lo - off:hi - off] = reference.apply_matrix(
                         dec, shards[sorted(present)[:10]])[0]
-                wrong += bytes(body) != payload.of(i)
-                sound_wrong += bytes(rebuilt) != payload.of(i)
+                # by the read loop's own comparison (NeedleReader.get)
+                wrong += not volume.same_bytes(bytes(body), payload.of(i))
+                sound_wrong += not volume.same_bytes(bytes(rebuilt), payload.of(i))
             checks = {"reads_wrong": (wrong, 0)}
             sound_checks = {"reads_wrong": (sound_wrong, 0)}
         else:
