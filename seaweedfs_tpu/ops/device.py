@@ -13,13 +13,22 @@ The module also holds what a process knows about its device side, for
 the devices jax sees (only once jax has been started), the compile
 counters, how many kernel shapes the RS transform has run, and every
 failure that made a backend selection skip a candidate.
+
+A process may have several local devices. An EC pipeline that builds its own
+codec borrows one for as long as it runs (`lease()`): one pipeline a device
+at a time, so a chip's memory stays what one pipeline takes, and as many
+pipelines at once as there are devices. Everything else (a degraded read's
+reconstruct) names no device and runs on jax's default one, lease or no.
 """
 
 from __future__ import annotations
 
+import bisect
+import contextlib
 import os
 import threading
 
+from seaweedfs_tpu.stats import trace
 from seaweedfs_tpu.util import glog
 
 CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
@@ -33,10 +42,12 @@ _jax = None
 _cache: dict = {}
 _compiles = {"requests": 0, "seconds": 0.0, "cache_hits": 0}
 _selection_failures: dict[str, str] = {}
-# (coefficient matrix, rows, cols, width) of every call the RS transform has
-# made of its jitted programs. The bit matrix is baked into the program, so
-# each is one program built (or taken from the compile cache)
-_kernel_shapes: set[tuple[bytes, int, int, int]] = set()
+# (coefficient matrix, rows, cols, width, device) of every call the RS
+# transform has made of its jitted programs. The bit matrix is baked into the
+# program and a program is built per device, so each is one program built (or
+# taken from the compile cache)
+_kernel_shapes: set[tuple[bytes, int, int, int, int]] = set()
+_leases: "Leases | None" = None
 
 
 def cache_dir() -> tuple[str, str]:
@@ -116,11 +127,64 @@ def note_selection_failure(where: str, exc: BaseException) -> None:
         glog.warning("backend selection: %s failed: %s", where, cause)
 
 
-def note_kernel_shape(matrix: bytes, rows: int, cols: int, width: int) -> None:
+def note_kernel_shape(matrix: bytes, rows: int, cols: int, width: int,
+                      dev: int) -> None:
     """The RS transform is about to run the program of its (rows, cols)
-    coefficient matrix at `width` (the door, `ops/rs_kernel._enqueue`): a set
-    insert, on every call."""
-    _kernel_shapes.add((matrix, rows, cols, width))
+    coefficient matrix at `width` on the device of index `dev` (the door,
+    `ops/rs_kernel._enqueue`): a set insert, on every call."""
+    _kernel_shapes.add((matrix, rows, cols, width, dev))
+
+
+class Leases:
+    """`devices`, lent one at a time each: `lease()` hands out the free one
+    of lowest index and blocks while none is free. What a device is is the
+    borrower's business (the process's own pool holds `jax.local_devices()`)."""
+
+    def __init__(self, devices) -> None:
+        self._devices = tuple(devices)
+        self._free = list(range(len(self._devices)))  # ascending
+        self._cond = threading.Condition()
+        self._granted = [0] * len(self._devices)
+
+    @contextlib.contextmanager
+    def lease(self):
+        """(index, device) for the length of the `with`, given back however
+        it ends. Seconds from the ask to the grant go under
+        `SeaweedFS_volume_ec_device_lease_seconds{device,state="wait"}`, from
+        the grant to the return under `state="held"` (a `with` left by an
+        exception counts none, as every phase)."""
+        with trace.phase("ec.device_lease.wait", trace.EC_LEASE_SECONDS) as ask:
+            with self._cond:
+                while not self._free:
+                    self._cond.wait()
+                index = self._free.pop(0)
+                self._granted[index] += 1
+            ask.kernel = (str(index), "wait")
+        try:
+            with trace.phase("ec.device_lease.held", trace.EC_LEASE_SECONDS,
+                             (str(index), "held")):
+                yield index, self._devices[index]
+        finally:
+            with self._cond:
+                bisect.insort(self._free, index)
+                self._cond.notify()
+
+    def granted(self) -> dict[str, int]:
+        """{device index: leases granted so far}, every device listed."""
+        with self._cond:
+            return {str(i): n for i, n in enumerate(self._granted)}
+
+
+def lease():
+    """Borrow one of this process's local devices (`Leases.lease` over
+    `jax.local_devices()`). Starts jax."""
+    global _leases
+    if _leases is None:
+        devices = jax().local_devices()
+        with _lock:
+            if _leases is None:
+                _leases = Leases(devices)
+    return _leases.lease()
 
 
 def _fullest_memory(devices) -> dict | None:
@@ -142,7 +206,8 @@ def _fullest_memory(devices) -> dict | None:
 def report() -> dict:
     """What this process knows about its device side. `jax` and `memory`
     are absent, not guessed, when nothing in the process has started jax;
-    `memory` also where the backend reports none."""
+    `memory` (of the fullest local device) also where the backend reports
+    none."""
     with _lock:
         out: dict = {"selection_failures": dict(_selection_failures)}
         compiles = dict(_compiles)
@@ -153,6 +218,8 @@ def report() -> dict:
         "platform": devices[0].platform,
         "device_kind": devices[0].device_kind,
         "count": len(devices),
+        # which local devices have worked: leases granted so far, by index
+        "leases": _leases.granted() if _leases is not None else {},
     }
     memory = _fullest_memory(_jax.local_devices())
     if memory is not None:

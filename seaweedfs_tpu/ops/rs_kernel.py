@@ -131,7 +131,9 @@ def _enqueue(matrix: np.ndarray, shards):
     hands it in) and the program does its own transfer: no put, and at a
     rung's width no pad and no slice either. A device array is padded to a
     tile multiple for the Pallas body, which takes nothing else; the XLA
-    body takes any width."""
+    body takes any width. Nothing here names a device: a device array's
+    program runs where the array lies (the puts below commit it there), a
+    host array's on jax's default device."""
     pallas = transform_kernel() == "pallas"
     tile = TILE
     rows, cols = matrix.shape
@@ -140,17 +142,22 @@ def _enqueue(matrix: np.ndarray, shards):
         rows, cols, matrix_bytes, tile)
     n = shards.shape[1]
     on_host = isinstance(shards, np.ndarray)
+    dev = 0  # the default device's index: where a host array's program runs
     if on_host:
         shards = zero_tailed(shards, tile)
     else:
-        jnp = device.jax().numpy
+        jax = device.jax()
+        jnp = jax.numpy
         shards = jnp.asarray(shards, dtype=jnp.uint8)
+        # an array inside a caller's own jit lies nowhere yet
+        if not isinstance(shards, jax.core.Tracer):
+            dev = next(iter(shards.devices())).id
         # no named scope around the pad and the slice: two scopes cost a
         # read a percent (PERF.md, PR 26); the trace knows the two programs
         # as `jit__pad` and `jit_dynamic_slice`
         if pallas and n % tile:
             shards = jnp.pad(shards, ((0, 0), (0, (-n) % tile)))
-    device.note_kernel_shape(matrix_bytes, rows, cols, shards.shape[1])
+    device.note_kernel_shape(matrix_bytes, rows, cols, shards.shape[1], dev)
     out = fn(shards)
     if shards.shape[1] == n:
         return out, 1
@@ -192,6 +199,11 @@ class RSCodec:
     backend: "jax" (TPU/accelerator bit-plane matmul), "native" (C++ via
     ctypes), "numpy" (table oracle). Mirrors the reference's pluggable
     `Encoder` boundary from BASELINE.json (klauspost CPU vs TPU sidecar).
+
+    device: the jax device the async pipeline API puts its batches on (a
+    pipeline's, from `ops.device.lease`); None is jax's default device. The
+    calls that take host arrays (`encode`, `reconstruct`, `apply_matrix`:
+    the read path) name none and run on the default device either way.
     """
 
     def __init__(
@@ -199,10 +211,12 @@ class RSCodec:
         data_shards: int = DATA_SHARDS,
         parity_shards: int = PARITY_SHARDS,
         backend: str = "auto",
+        device=None,
     ) -> None:
         self.data_shards = data_shards
         self.parity_shards = parity_shards
         self.total_shards = data_shards + parity_shards
+        self.device = device
         # "auto" resolves lazily on first use so constructing a codec (e.g.
         # opening an EcVolume that may never reconstruct) doesn't init JAX.
         self._backend = backend
@@ -299,7 +313,8 @@ class RSCodec:
         """data: C-contiguous (cols, n) uint8. Handle yields (rows, n)."""
         if self.backend == "jax":
             return _JaxHandle(
-                _dispatch(matrix, _device_put_2d(data)), data.shape[1]
+                _dispatch(matrix, _device_put_2d(data, self.device)),
+                data.shape[1],
             )
         if self.backend == "native":
             from seaweedfs_tpu.native import lib
@@ -320,7 +335,7 @@ class RSCodec:
         if self.backend == "jax":
             jax = device.jax()
             jnp = jax.numpy
-            x = _device_put_1d(buf)
+            x = _device_put_1d(buf, self.device)
             with jax.named_scope("rs.rows_transpose"):
                 x = x.reshape(row_count, self.data_shards, block)
                 x = jnp.transpose(x, (1, 0, 2)).reshape(self.data_shards, -1)
@@ -372,27 +387,29 @@ class _JaxHandle:
 H2D_CHUNK = 4 * 1024 * 1024
 
 
-def _device_put_1d(buf: np.ndarray):
+def _device_put_1d(buf: np.ndarray, dev=None):
+    """`buf` on `dev` (None: jax's default device), flat. The concat, and
+    whatever is computed from its result, runs where the pieces lie."""
     jax = device.jax()
     jnp = jax.numpy
     flat = buf.reshape(-1)
     # `h2d`: the host's seconds in the puts and in dispatching the concat
     with trace.phase("rs.h2d", trace.EC_DEVICE_SECONDS, "h2d", flat.nbytes):
         if flat.nbytes <= H2D_CHUNK:
-            return jax.device_put(flat)
+            return jax.device_put(flat, dev)
         pieces = [
-            jax.device_put(flat[i : i + H2D_CHUNK])
+            jax.device_put(flat[i : i + H2D_CHUNK], dev)
             for i in range(0, flat.nbytes, H2D_CHUNK)
         ]
         with jax.named_scope("rs.h2d_concat"):
             return jnp.concatenate(pieces)
 
 
-def _device_put_2d(data: np.ndarray):
+def _device_put_2d(data: np.ndarray, dev=None):
     if data.nbytes <= H2D_CHUNK:
         with trace.phase("rs.h2d", trace.EC_DEVICE_SECONDS, "h2d", data.nbytes):
-            return device.jax().device_put(data)
-    return _device_put_1d(data).reshape(data.shape)
+            return device.jax().device_put(data, dev)
+    return _device_put_1d(data, dev).reshape(data.shape)
 
 
 def _native_lib():

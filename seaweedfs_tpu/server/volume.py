@@ -156,6 +156,11 @@ class VolumeServer:
         # N-chunk rebuild costs ~(H + N) chunk-times instead of H x N.
         self._partial_streams: dict[str, dict] = {}
         self._stream_lock = threading.Lock()
+        # one beat at a time, collected and posted under the lock: admin
+        # handlers beat after every state change on their own threads (four
+        # `ec.encode`s of a collection end side by side), and a beat
+        # collected earlier must not reach the master later
+        self._beat_lock = threading.Lock()
         # background integrity scrubber (maintenance/scrub.py): walks
         # volumes/EC shards in token-bucket-throttled passes. -scrub.
         # interval 0 disables the loop; /admin/scrub/run still works.
@@ -513,13 +518,14 @@ class VolumeServer:
         and evict real request traces."""
         from seaweedfs_tpu.stats import trace
 
-        n = getattr(self, "_hb_count", 0)
-        self._hb_count = n + 1
-        if n % 12:
-            self._heartbeat_once()
-            return
-        with trace.span("volume.heartbeat", role="volume"):
-            self._heartbeat_once()
+        with self._beat_lock:
+            n = getattr(self, "_hb_count", 0)
+            self._hb_count = n + 1
+            if n % 12:
+                self._heartbeat_once()
+                return
+            with trace.span("volume.heartbeat", role="volume"):
+                self._heartbeat_once()
 
     def _heartbeat_once(self) -> None:
         import json as _json
@@ -970,7 +976,7 @@ class VolumeServer:
             online = {
                 str(v.id): v.online_ec.stats()
                 for loc in self.store.locations
-                for v in loc.volumes.values()
+                for v in list(loc.volumes.values())
                 if v.online_ec is not None
             }
             if online:

@@ -15,10 +15,13 @@ maintenance scheduler's live pressure."""
 
 from __future__ import annotations
 
+import collections
 import json
+import threading
 import time
 import urllib.parse
 
+from seaweedfs_tpu.stats import trace
 from seaweedfs_tpu.storage.erasure_coding import repair_names
 from seaweedfs_tpu.storage.erasure_coding.constants import (
     DATA_SHARDS,
@@ -97,14 +100,68 @@ def _collect_ec_volume_ids(env: CommandEnv, flags: dict) -> list[tuple[int, str]
     return out
 
 
-@command("ec.encode", "-volumeId <n> | -collection <name> — erasure-code volumes "
-         "(RS(10,4) on the TPU path)", needs_lock=True)
+# upstream's `ec.encode -maxParallelization` and its default
+# (`weed/shell/command_ec_encode.go`)
+MAX_PARALLELIZATION = 10
+
+
+@command("ec.encode", "-volumeId <n> | -collection <name> [-maxParallelization"
+         " 10] — erasure-code volumes (RS(10,4) on the TPU path); a"
+         " collection's volumes side by side", needs_lock=True)
 def cmd_ec_encode(env: CommandEnv, args: list[str]) -> str:
     flags = parse_flags(args)
-    lines = []
-    for vid, collection in _collect_ec_volume_ids(env, flags):
-        lines.append(_ec_encode_one(env, vid, collection))
-    return "\n".join(lines) if lines else "no volumes to encode"
+    volumes = _collect_ec_volume_ids(env, flags)
+    if not volumes:
+        return "no volumes to encode"
+    if "volumeId" in flags:  # a collection of one, on the caller's thread
+        return _ec_encode_one(env, *volumes[0])
+    limit = int(flags.get("maxParallelization", MAX_PARALLELIZATION))
+    if limit < 1:
+        raise ShellError("-maxParallelization must be at least 1")
+    results = _ec_encode_side_by_side(env, volumes, limit)
+    lines = [r for r in results if isinstance(r, str)]
+    failed = [f"volume {vid}: {r}"
+              for (vid, _), r in zip(volumes, results) if isinstance(r, Exception)]
+    if failed:
+        raise ShellError("\n".join(
+            [f"ec.encode: {len(failed)} of {len(volumes)} volumes failed: "
+             + "; ".join(failed)] + lines))
+    return "\n".join(lines)
+
+
+def _ec_encode_side_by_side(
+    env: CommandEnv, volumes: list[tuple[int, str]], limit: int
+) -> list:
+    """`_ec_encode_one` of every volume, up to `limit` at once, each on a
+    thread under a span `ec.encode.volume` of the verb's. -> in the order of
+    `volumes`, the line each one returned or the exception it raised: one
+    that fails does not stop the others. How many the volume server really
+    encodes at once is its own business (one pipeline a device,
+    `ops/device.lease`)."""
+    parent = trace.current()  # threads carry no context of their own
+    results: list = [None] * len(volumes)
+    pending = collections.deque(enumerate(volumes))
+
+    def worker() -> None:
+        while True:
+            try:
+                at, (vid, collection) = pending.popleft()
+            except IndexError:
+                return
+            try:
+                with trace.span("ec.encode.volume", role="shell", parent=parent,
+                                adopt=True, volume=vid, collection=collection):
+                    results[at] = _ec_encode_one(env, vid, collection)
+            except Exception as e:  # noqa: BLE001 - named in the verb's error
+                results[at] = e
+
+    threads = [threading.Thread(target=worker, name=f"ec-encode-{k}")
+               for k in range(min(limit, len(volumes)))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    return results
 
 
 def _ec_encode_one(env: CommandEnv, vid: int, collection: str) -> str:

@@ -68,8 +68,16 @@ EC_DEVICE_PROGRAMS = "SeaweedFS_volume_ec_device_programs_total"
 # bytes of EC read intervals by how each was served (EcVolume._read_interval)
 EC_READ_INTERVAL_BYTES = "SeaweedFS_volume_ec_read_interval_bytes_total"
 EC_READ_INTERVAL_SOURCES = ("local", "remote", "reconstruct")
-# families of phases whose label is not `kernel` and that count no bytes
-_FAMILY_LABEL = {EC_ADMIN_SECONDS: "op"}
+# one local device lent to one EC pipeline at a time (ops/device.lease):
+# `state` is `wait` (from the ask to the grant) or `held` (from the grant to
+# the return), `device` the index of the device that was granted
+EC_LEASE_SECONDS = "SeaweedFS_volume_ec_device_lease_seconds"
+# families of phases whose labels are not `kernel` and that count no bytes; a
+# phase of a family with several labels gives `kernel` as a tuple of values
+_FAMILY_LABELS = {
+    EC_ADMIN_SECONDS: ("op",),
+    EC_LEASE_SECONDS: ("device", "state"),
+}
 
 # jax.profiler.TraceAnnotation between a device trace's start and stop
 # (set by stats.profiler.device_trace), else None
@@ -359,17 +367,20 @@ def annotate(**attrs) -> None:
 # --- span helpers -------------------------------------------------------------
 @contextmanager
 def span(name: str, role: str | None = None,
-         parent: tuple[str, str] | None = None, **attrs):
+         parent: tuple[str, str] | None = None, adopt: bool = False,
+         **attrs):
     """Generic traced section; nested client calls become children. With
     `parent`, a `current()` taken on another thread, the span joins that
     trace as its child without becoming this thread's context: for worker
-    threads, which carry no context of their own."""
+    threads, which carry no context of their own. With `adopt` as well it
+    does become the worker's context, so the worker's own client calls
+    carry the trace on."""
     if parent is None:
         sp = _collector.start_span(name, role=role, attrs=attrs)
     else:
         sp = _collector.start_span(
             name, role=role, trace_id=parent[0], parent_id=parent[1],
-            attrs=attrs, activate=False,
+            attrs=attrs, activate=adopt,
         )
     try:
         yield sp
@@ -430,16 +441,16 @@ def _kernel_metrics(family: str) -> tuple:
         pair = _kernel_metrics_cache.get(family)
         if pair is None:
             reg = default_registry()
-            label = _FAMILY_LABEL.get(family, "kernel")
+            labels = _FAMILY_LABELS.get(family, ("kernel",))
             hist = reg.histogram(
-                family, "kernel execution seconds", (label,),
+                family, "kernel execution seconds", labels,
                 buckets=KERNEL_BUCKETS,
             )
             ctr = None
-            if family not in _FAMILY_LABEL:
+            if family not in _FAMILY_LABELS:
                 ctr = reg.counter(
                     _sibling(family, "_bytes_total"),
-                    "bytes processed by the kernel", (label,),
+                    "bytes processed by the kernel", labels,
                 )
             pair = (hist, ctr)
             _kernel_metrics_cache[family] = pair
@@ -461,7 +472,7 @@ def _cpu_counter(family: str):
         ctr = default_registry().counter(  # get-or-create under its lock
             _sibling(family, "_cpu_seconds_total"),
             "thread CPU seconds spent in the kernel's host code",
-            (_FAMILY_LABEL.get(family, "kernel"),),
+            _FAMILY_LABELS.get(family, ("kernel",)),
         )
         _counters[family] = ctr
     return ctr
@@ -491,13 +502,17 @@ def read_interval_bytes_counter():
         ("source",))
 
 
-def observe_kernel(family: str, kernel: str, seconds: float, nbytes: int = 0) -> None:
+def observe_kernel(family: str, kernel: str | tuple, seconds: float,
+                   nbytes: int = 0) -> None:
     """Metrics-only record for hot per-blob paths where a trace span per
-    call would flood the ring buffer."""
+    call would flood the ring buffer. `kernel` is the family's one label
+    value, or a tuple of them where the family has several
+    (`_FAMILY_LABELS`)."""
     hist, ctr = _kernel_metrics(family)
-    hist.labels(kernel).observe(seconds)
+    values = kernel if isinstance(kernel, tuple) else (kernel,)
+    hist.labels(*values).observe(seconds)
     if nbytes and ctr is not None:
-        ctr.labels(kernel).inc(nbytes)
+        ctr.labels(*values).inc(nbytes)
 
 
 class phase:

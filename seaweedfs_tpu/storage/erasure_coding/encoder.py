@@ -30,6 +30,7 @@ carried the bytes: "fused" (native single pass), or "pipeline-" /
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import queue
@@ -38,6 +39,7 @@ import time
 
 import numpy as np
 
+from seaweedfs_tpu.ops import device
 from seaweedfs_tpu.ops.rs_kernel import RSCodec, pick_pipeline_backend
 from seaweedfs_tpu.stats import trace
 from seaweedfs_tpu.storage import idx as idx_mod
@@ -252,15 +254,17 @@ class _ShardWriters:
                 pass
 
 
-def _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes=None) -> None:
+def _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes=None,
+                  dev: int | None = None) -> None:
     """reader thread -> encode (caller thread) -> writer thread, with
     bounded queues, a shared buffer freelist for backpressure, and a stop
     flag so a failure in any stage unwinds the other two instead of
     deadlocking on a full/empty queue. Every batch feeds the per-stage
     busy/wait histograms (EC_PIPELINE_SECONDS above) and, where the caller
     runs under a span, leaves one ring span per stage as that span's child
-    (`ec.pipeline.read`, `.encode`, `.write`; attrs `batch`, `thread` and,
-    with `job_bytes(job)`, `bytes`), whichever thread did the work."""
+    (`ec.pipeline.read`, `.encode`, `.write`; attrs `batch`, `thread`, with
+    `job_bytes(job)` `bytes`, and with `dev`, the index of the device the
+    pipeline borrowed, `device`), whichever thread did the work."""
     parent = trace.current()  # worker threads carry no context of their own
     batches = {"read": 0, "encode": 0, "write": 0}  # each its own thread's
 
@@ -269,6 +273,8 @@ def _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes=None) -> None
             return fn(job, *args)
         attrs = {"batch": batches[stage],
                  "thread": threading.current_thread().name}
+        if dev is not None:
+            attrs["device"] = dev
         batches[stage] += 1
         if job_bytes is not None:
             attrs["bytes"] = job_bytes(job)
@@ -437,6 +443,26 @@ def _write_ec_files_fused(
     return True
 
 
+@contextlib.contextmanager
+def _pipeline_codec(codec: RSCodec | None, backend: str):
+    """(codec, index of the device it puts its batches on or None) for the
+    length of one pipeline. A codec the caller handed in is used as given.
+    One built here for the jax backend borrows a local device for as long as
+    the pipeline runs (`ops.device.lease`): one pipeline a device, as many
+    at once as the process has devices, the next one waiting."""
+    if codec is not None:
+        yield codec, None
+    elif backend != "jax":
+        yield RSCodec(backend=backend), None
+    else:
+        with device.lease() as (index, dev):
+            yield RSCodec(backend=backend, device=dev), index
+
+
+def _device_attr(dev: int | None) -> dict:
+    return {} if dev is None else {"device": dev}
+
+
 def write_ec_files(
     base_file_name: str,
     codec: RSCodec | None = None,
@@ -451,42 +477,41 @@ def write_ec_files(
     bytes counter), so /metrics alone yields encode GB/s."""
     dat_path = base_file_name + ".dat"
     total = os.path.getsize(dat_path)
-    if codec is None or codec.backend == "native":
-        backend = codec.backend if codec else pick_pipeline_backend()
-        if backend == "native":
-            with trace.kernel_span(
-                "ec.encode", trace.EC_ENCODE_SECONDS, "fused", nbytes=total
-            ) as sp:
-                t0 = time.perf_counter()
-                fused_ok = _write_ec_files_fused(
-                    base_file_name, large_block_size, small_block_size
-                )
-                if fused_ok:
-                    # single-pass engine: no read/encode/write stages exist,
-                    # but the family must still account for the bytes' time
-                    _pipeline_hist().labels("fused", "busy").observe(
-                        time.perf_counter() - t0
-                    )
-                if not fused_ok:
-                    # host can't run it: the pipeline span below carries
-                    # the bytes, and the probe must not count as a fused
-                    # execution in the histogram
-                    sp.attrs["bytes"] = 0
-                    sp.attrs["kernel"] = "fused-unavailable"
+    backend = codec.backend if codec else pick_pipeline_backend()
+    if backend == "native":
+        with trace.kernel_span(
+            "ec.encode", trace.EC_ENCODE_SECONDS, "fused", nbytes=total
+        ) as sp:
+            t0 = time.perf_counter()
+            fused_ok = _write_ec_files_fused(
+                base_file_name, large_block_size, small_block_size
+            )
             if fused_ok:
-                return
-        if codec is None:
-            codec = RSCodec(backend=backend)
-    if batch is None:
-        batch = _default_batch(codec.backend)
-    with trace.kernel_span(
-        "ec.encode", trace.EC_ENCODE_SECONDS,
-        "pipeline-" + codec.kernel_label, nbytes=total,
-    ):
-        _write_ec_files_pipeline(
-            base_file_name, codec, large_block_size, small_block_size,
-            batch, total,
-        )
+                # single-pass engine: no read/encode/write stages exist,
+                # but the family must still account for the bytes' time
+                _pipeline_hist().labels("fused", "busy").observe(
+                    time.perf_counter() - t0
+                )
+            if not fused_ok:
+                # host can't run it: the pipeline span below carries
+                # the bytes, and the probe must not count as a fused
+                # execution in the histogram
+                sp.attrs["bytes"] = 0
+                sp.attrs["kernel"] = "fused-unavailable"
+        if fused_ok:
+            return
+    with _pipeline_codec(codec, backend) as (codec, dev):
+        if batch is None:
+            batch = _default_batch(codec.backend)
+        with trace.kernel_span(
+            "ec.encode", trace.EC_ENCODE_SECONDS,
+            "pipeline-" + codec.kernel_label, nbytes=total,
+            **_device_attr(dev),
+        ):
+            _write_ec_files_pipeline(
+                base_file_name, codec, large_block_size, small_block_size,
+                batch, total, dev,
+            )
 
 
 def _write_ec_files_pipeline(
@@ -496,6 +521,7 @@ def _write_ec_files_pipeline(
     small_block_size: int,
     batch: int,
     total: int,
+    dev: int | None = None,
 ) -> None:
     dat_path = base_file_name + ".dat"
     shard_size = shard_file_size(total, large_block_size, small_block_size)
@@ -577,7 +603,7 @@ def _write_ec_files_pipeline(
             per_shard = job[3] * job[4] if job[0] == "rows" else job[5]
             return per_shard * DATA_SHARDS_COUNT
 
-        _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes)
+        _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes, dev)
     except BaseException:
         writers.abort()
         raise
@@ -596,11 +622,13 @@ def rebuild_ec_files(
     (`ec_encoder.go:61,237-291`), through the same three-stage pipeline —
     the GF transform is the inverted-submatrix product on the pipeline
     backend (BASELINE config 2). Returns the rebuilt shard ids."""
-    codec = codec or RSCodec(backend=pick_pipeline_backend())
-    with trace.kernel_span(
-        "ec.rebuild", trace.EC_DECODE_SECONDS, "rebuild-" + codec.kernel_label
-    ) as sp:
-        return _rebuild_ec_files(base_file_name, codec, chunk, sp)
+    backend = codec.backend if codec else pick_pipeline_backend()
+    with _pipeline_codec(codec, backend) as (codec, dev):
+        with trace.kernel_span(
+            "ec.rebuild", trace.EC_DECODE_SECONDS,
+            "rebuild-" + codec.kernel_label, **_device_attr(dev),
+        ) as sp:
+            return _rebuild_ec_files(base_file_name, codec, chunk, sp, dev)
 
 
 def _rebuild_ec_files(
@@ -608,6 +636,7 @@ def _rebuild_ec_files(
     codec: RSCodec,
     chunk: int | None,
     sp,
+    dev: int | None = None,
 ) -> list[int]:
     from seaweedfs_tpu.ops import gf256
 
@@ -710,7 +739,7 @@ def _rebuild_ec_files(
 
             _run_pipeline(
                 jobs, read_job, encode_job, write_job,
-                lambda job: job[1] * DATA_SHARDS_COUNT,
+                lambda job: job[1] * DATA_SHARDS_COUNT, dev,
             )
         except BaseException:
             writers.abort()

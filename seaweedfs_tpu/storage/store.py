@@ -318,8 +318,10 @@ class Store:
         """Message shape mirrors master_pb.Heartbeat (`store.go:249`)."""
         volumes = []
         max_file_key = 0
+        # a snapshot of each map: admin handlers add and drop volumes on
+        # their own threads while a beat or `/status` walks them
         for loc in self.locations:
-            for v in loc.volumes.values():
+            for v in list(loc.volumes.values()):
                 max_file_key = max(max_file_key, v.max_needle_id())
                 volumes.append(
                     {
@@ -358,7 +360,7 @@ class Store:
                 )
         ec_shards = []
         for loc in self.locations:
-            for ev in loc.ec_volumes.values():
+            for ev in list(loc.ec_volumes.values()):
                 ec_shards.append(
                     {
                         "id": ev.volume_id,

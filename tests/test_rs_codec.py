@@ -262,7 +262,7 @@ def check_ladder_widths(ladder: dict, widths) -> None:
     compiles, shapes = compile_requests(), kernel_shapes()
     # the rungs' programs and no other, however many the process had before
     assert shapes - ladder["shapes_before"] <= len(RUNGS) <= 20
-    assert all((LADDER_MATRIX, 1, 10, t * tile) in device._kernel_shapes
+    assert all((LADDER_MATRIX, 1, 10, t * tile, 0) in device._kernel_shapes
                for t in RUNGS)
     for n in widths:
         width = rs_kernel.ladder_width(n, tile)
@@ -316,8 +316,8 @@ def check_direct_host_array_goes_to_a_rung(tiles: int, tile: int) -> None:
     before = set(device._kernel_shapes)
     out, programs = rs_kernel._enqueue(matrix, data)
     assert programs == 2
-    assert set(device._kernel_shapes) - before <= {(LADDER_MATRIX, 1, 10, width)}
-    assert (LADDER_MATRIX, 1, 10, width) in device._kernel_shapes
+    assert set(device._kernel_shapes) - before <= {(LADDER_MATRIX, 1, 10, width, 0)}
+    assert (LADDER_MATRIX, 1, 10, width, 0) in device._kernel_shapes
     got = np.asarray(out)
     assert got.shape == (1, n)
     assert np.array_equal(got, gf256.gf_matmul_bytes(matrix, data))
