@@ -187,6 +187,12 @@ def lease():
     return _leases.lease()
 
 
+def pipelines_at_once() -> int:
+    """How many pipelines that borrow a device can run at once: one a local
+    device (what `lease()` lends); one where nothing has started jax here."""
+    return len(_jax.local_devices()) if started() else 1
+
+
 def _fullest_memory(devices) -> dict | None:
     """`memory_stats()` of the local device whose peak is highest, cut to
     the three numbers a reader sizes a deployment by; None where the backend

@@ -787,6 +787,8 @@ class VolumeServer:
                     self._teardown_stream(st)
             except Exception:
                 pass
+            # batch buffers no EC pipeline has asked for in a minute
+            ec_encoder.batch_buffers.expire()
             if getattr(self, "_leaving", False):
                 continue  # volume.server.leave: stay up, stop heartbeating
             self.heartbeat_once()
@@ -984,10 +986,12 @@ class VolumeServer:
             from seaweedfs_tpu.ops import device
             from seaweedfs_tpu.ops.rs_kernel import pipeline_backend_report
 
-            # the EC pipeline backend in force and how it was chosen, and
-            # the devices this process's jax sees (absent if never started)
+            # the EC pipeline backend in force and how it was chosen, the
+            # devices this process's jax sees (absent if never started), and
+            # the batch buffers the pipelines have left for the next one
             out["ec"] = {
                 "pipeline": pipeline_backend_report(), **device.report(),
+                "pipeline_buffers": ec_encoder.batch_buffers.report(),
             }
             return Response(out)
 

@@ -72,6 +72,10 @@ EC_READ_INTERVAL_SOURCES = ("local", "remote", "reconstruct")
 # `state` is `wait` (from the ask to the grant) or `held` (from the grant to
 # the return), `device` the index of the device that was granted
 EC_LEASE_SECONDS = "SeaweedFS_volume_ec_device_lease_seconds"
+# batch buffers handed to an EC pipeline's reader, by whether their pages
+# were there already (encoder.BatchBuffers)
+EC_PIPELINE_BUFFERS = "SeaweedFS_volume_ec_pipeline_buffers_total"
+EC_PIPELINE_BUFFER_SOURCES = ("kept", "fresh")
 # families of phases whose labels are not `kernel` and that count no bytes; a
 # phase of a family with several labels gives `kernel` as a tuple of values
 _FAMILY_LABELS = {
@@ -499,6 +503,19 @@ def read_interval_bytes_counter():
     return _plain_counter(
         EC_READ_INTERVAL_BYTES,
         "bytes of EC read intervals, by how the interval was served",
+        ("source",))
+
+
+def pipeline_buffers_counter():
+    """`SeaweedFS_volume_ec_pipeline_buffers_total{source}`: one update a
+    batch buffer handed to an EC pipeline's reader (`encoder._ensure_buf`),
+    `kept` where its pages were there already (the pipeline filled it before,
+    or the store kept it from an earlier pipeline), `fresh` where it was
+    allocated or regrown and the reader pays the first touch."""
+    return _plain_counter(
+        EC_PIPELINE_BUFFERS,
+        "batch buffers handed to an EC pipeline's reader, by where their"
+        " pages came from",
         ("source",))
 
 

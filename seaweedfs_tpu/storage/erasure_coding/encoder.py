@@ -10,7 +10,10 @@ stages overlap:
 
 * the reader pre-fetches row batches from the .dat into a small ring of
   reusable host buffers (positional preadv straight into the buffer,
-  zero-padded past EOF); the rebuild's reader fills a batch the same way,
+  zero-padded past EOF), which a pipeline takes from the process's store
+  of idle batch buffers and gives back at its end (`BatchBuffers`), so
+  that from a server's second verb on a batch is read into pages that are
+  already there; the rebuild's reader fills a batch the same way,
   in place, each surviving shard file's slice into its own row, one file
   after another (a survivor that comes up short is an IOError, never
   padded; ten reads side by side gained nothing, PERF.md §6 PR 31);
@@ -72,7 +75,7 @@ _QUEUE_DEPTH = 2
 # Per-stage pipeline attribution (RapidRAID's lesson — arXiv:1207.6744 —
 # is that a pipelined coder lives or dies by per-stage balance): each
 # batch contributes a busy observation (doing its stage's work) and a
-# wait observation (blocked on the bounded queues / buffer freelist), so
+# wait observation (blocked on the bounded queues / a free buffer slot), so
 # /metrics alone answers which stage is the bottleneck and at what
 # utilization (busy_sum / (busy_sum + wait_sum)). The write stage's busy
 # time includes blocking on the encode handle's parity (device drain):
@@ -98,12 +101,80 @@ def _pipeline_hist():
     return hist
 
 
+class BatchBuffers:
+    """The batch buffers that no pipeline is using, kept from one verb to the
+    next: a buffer above glibc's mmap threshold is a fresh anonymous mapping
+    whose pages are first touched inside the reader's `preadv` and unmapped
+    when it dies, and several pipelines doing that in one address space wait
+    for each other in the kernel (PERF.md §6 PR 33, PR 34).
+
+    One store for every caller of `_run_pipeline`, whatever its batch size:
+    `take` hands out the largest idle buffer and `_ensure_buf` decides, as it
+    always has, whether it holds the batch. What the store holds idle is
+    bounded by what the pipelines that can run at once hold while they run
+    (`_QUEUE_DEPTH + 2` slots of the device path's batch each, one pipeline
+    a local device: `ops.device.pipelines_at_once`); what comes back beyond
+    that is dropped, the smallest first. After `IDLE_SECONDS` in which no
+    pipeline took or gave a buffer, `expire` (the volume server's pulse)
+    lets them all go."""
+
+    IDLE_SECONDS = 60.0
+
+    def __init__(self, clock=time.monotonic) -> None:
+        self._lock = threading.Lock()
+        self._idle: list[np.ndarray] = []  # ascending by size
+        self._clock = clock
+        self._touched = 0.0
+
+    @staticmethod
+    def bound() -> tuple[int, int]:
+        """(slots, bytes) the store may hold idle."""
+        slots = (_QUEUE_DEPTH + 2) * device.pipelines_at_once()
+        return slots, slots * DEFAULT_BATCH_DEVICE * DATA_SHARDS_COUNT
+
+    def take(self) -> np.ndarray | None:
+        with self._lock:
+            self._touched = self._clock()
+            return self._idle.pop() if self._idle else None
+
+    def give(self, bufs: list[np.ndarray]) -> None:
+        """Buffers that nothing can still read or write."""
+        slots, nbytes = self.bound()
+        with self._lock:
+            self._touched = self._clock()
+            self._idle = sorted(self._idle + bufs, key=lambda b: b.nbytes)
+            while (len(self._idle) > slots
+                   or sum(b.nbytes for b in self._idle) > nbytes):
+                del self._idle[0]
+
+    def expire(self) -> None:
+        with self._lock:
+            if self._clock() - self._touched >= self.IDLE_SECONDS:
+                self._idle = []
+
+    def report(self) -> dict:
+        """`/status` `ec.pipeline_buffers`."""
+        with self._lock:
+            idle = [b.nbytes for b in self._idle]
+        return {"idle": len(idle), "idle_bytes": sum(idle),
+                "bound_bytes": self.bound()[1]}
+
+
+batch_buffers = BatchBuffers()
+
+
 def _ensure_buf(buf, need: int, cap: int) -> np.ndarray:
-    """Reuse the freelist slot when it is big enough, else (re)allocate to
-    max(need, cap) so the slot converges on one steady-state size."""
-    if not isinstance(buf, np.ndarray) or buf.nbytes < need:
-        buf = np.empty(max(need, cap), dtype=np.uint8)
-    return buf
+    """The slot's buffer where it holds `need` bytes: one this pipeline has
+    filled before or one the store kept from an earlier pipeline, its pages
+    there already. Else a new one of max(need, cap) bytes, so that slots
+    converge on one steady-state size. Either way the caller fills
+    `buf[:need]` whole and reads no further: what an earlier batch left
+    beyond it never reaches a shard."""
+    if isinstance(buf, np.ndarray) and buf.nbytes >= need:
+        trace.pipeline_buffers_counter().labels("kept").inc()
+        return buf
+    trace.pipeline_buffers_counter().labels("fresh").inc()
+    return np.empty(max(need, cap), dtype=np.uint8)
 
 
 def _pread_padded(fd: int, offset: int, size: int, out: np.ndarray) -> None:
@@ -257,9 +328,16 @@ class _ShardWriters:
 def _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes=None,
                   dev: int | None = None) -> None:
     """reader thread -> encode (caller thread) -> writer thread, with
-    bounded queues, a shared buffer freelist for backpressure, and a stop
-    flag so a failure in any stage unwinds the other two instead of
-    deadlocking on a full/empty queue. Every batch feeds the per-stage
+    bounded queues, `_QUEUE_DEPTH + 2` buffer slots that go round between
+    writer and reader for backpressure, and a stop flag so a failure in any
+    stage unwinds the other two instead of deadlocking on a full/empty
+    queue. A slot's buffer comes from `batch_buffers` on the slot's first
+    use, and at the pipeline's end every buffer that nothing can still read
+    or write goes back there: one the writer returned after
+    `handle.result()` (the puts and the kernel that read it are done), or one
+    that was read and never enqueued. A buffer whose batch was enqueued and
+    not drained when a stage failed is dropped, because an asynchronous
+    `device_put` may still be reading it. Every batch feeds the per-stage
     busy/wait histograms (EC_PIPELINE_SECONDS above) and, where the caller
     runs under a span, leaves one ring span per stage as that span's child
     (`ec.pipeline.read`, `.encode`, `.write`; attrs `batch`, `thread`, with
@@ -286,7 +364,7 @@ def _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes=None,
     write_q: queue.Queue = queue.Queue(maxsize=_QUEUE_DEPTH)
     free: queue.Queue = queue.Queue()
     for _ in range(_QUEUE_DEPTH + 2):
-        free.put(None)  # buffer slots; reader sizes/reuses lazily
+        free.put(None)  # buffer slots: None until the reader first uses one
     stop = threading.Event()
     errors: list[BaseException] = []
     hist = _pipeline_hist()
@@ -310,6 +388,11 @@ def _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes=None,
                     return
                 t0 = perf()
                 slot = free.get()
+                if stop.is_set():
+                    free.put(slot)
+                    return
+                if slot is None:
+                    slot = batch_buffers.take()
                 t1 = perf()
                 buf = staged("read", read_job, job, slot)
                 t2 = perf()
@@ -317,6 +400,7 @@ def _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes=None,
                 o_wait.observe((t1 - t0) + (perf() - t2))
                 o_busy.observe(t2 - t1)
                 if not ok:
+                    free.put(buf)  # read, never enqueued
                     return
         except BaseException as e:  # noqa: BLE001 - propagated below
             errors.append(e)
@@ -342,11 +426,14 @@ def _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes=None,
         except BaseException as e:  # noqa: BLE001
             errors.append(e)
             stop.set()
-            while True:  # drain + recycle buffers so reader/encode never block
+            # drain so reader/encode never block; these batches were
+            # enqueued and are not drained: their buffers are dropped, and
+            # an empty slot each wakes a reader waiting for one
+            while True:
                 item = write_q.get()
                 if item is None:
                     return
-                free.put(item[1])
+                free.put(None)
 
     rt = threading.Thread(target=reader, name="ec-reader", daemon=True)
     wt = threading.Thread(target=writer, name="ec-writer", daemon=True)
@@ -374,11 +461,17 @@ def _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes=None,
             item = read_q.get()
             if item is None:
                 break
-            free.put(item[1])
+            free.put(item[1])  # read, never enqueued
     finally:
         write_q.put(None)
         rt.join()
         wt.join()
+        idle = []
+        while not free.empty():
+            buf = free.get()
+            if buf is not None:
+                idle.append(buf)
+        batch_buffers.give(idle)
     if errors:
         raise errors[0]
 

@@ -193,8 +193,8 @@ class OnlineEcWriter:
         self.fallbacks: dict[str, int] = {}
         # reused stripe read buffer: a fresh bytes per pread would pay
         # this microVM's free-page first-touch cost (~0.15 GB/s) on every
-        # batch — the same reason the offline pipeline runs a buffer
-        # freelist (encoder._ensure_buf)
+        # batch — the same reason the offline pipeline keeps its batch
+        # buffers from one pipeline to the next (encoder.BatchBuffers)
         self._buf: np.ndarray | None = None
         self._parity_rows_sized = 0  # rows the parity fds are truncated to
         # zero-copy fast path (the fused-engine idea applied per stripe):
@@ -542,8 +542,8 @@ class OnlineEcWriter:
     def _encode_backlog_pipelined(self, offset: int, nrows: int) -> None:
         """Catch-up path for multi-stripe backlogs (drain-tick batches at
         high ingest, journal replay, seal): row batches stream through
-        encoder._run_pipeline — reader thread (preadv into the shared
-        freelist) -> GF transform -> writer thread (parity pwrite +
+        encoder._run_pipeline — reader thread (preadv into the process's
+        kept batch buffers) -> GF transform -> writer thread (parity pwrite +
         journal advance) — so read, encode, and write overlap across
         cores instead of serializing per stripe. Stage attribution lands
         in the shared SeaweedFS_volume_ec_pipeline_seconds family."""

@@ -367,6 +367,7 @@ class TestMetricNameLint:
         # PR-3: pipeline attribution + the self-observability collectors
         assert "SeaweedFS_volume_ec_pipeline_seconds" in kinds
         assert kinds["SeaweedFS_volume_ec_pipeline_seconds"] == "histogram"
+        assert kinds["SeaweedFS_volume_ec_pipeline_buffers_total"] == "counter"
         assert "SeaweedFS_stats_trace_spans_total" in collector_names
         assert "SeaweedFS_stats_trace_dropped_total" in collector_names
         assert "SeaweedFS_stats_profile_samples_total" in collector_names
@@ -489,6 +490,8 @@ class TestMetricNameLint:
         ("EC_DEVICE_KERNELS", ("h2d", "copy-back"), "never writes it"),
         ("EC_READ_INTERVAL_SOURCES", ("local", "local"), "duplicate"),
         ("EC_READ_INTERVAL_SOURCES", ("local", "page-cache"), "never writes it"),
+        ("EC_PIPELINE_BUFFER_SOURCES", ("kept", "kept"), "duplicate"),
+        ("EC_PIPELINE_BUFFER_SOURCES", ("kept", "warm"), "never writes it"),
     ])
     def test_phase_label_lint_catches_violations(
             self, monkeypatch, attr, value, complaint):

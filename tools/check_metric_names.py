@@ -75,6 +75,7 @@ def collect() -> tuple[dict[str, str], list[str]]:
     trace._cpu_counter(trace.EC_DECODE_SECONDS)  # ..._decode_cpu_seconds_total
     trace.device_programs_counter()  # SeaweedFS_volume_ec_device_programs_total
     trace.read_interval_bytes_counter()  # ..._ec_read_interval_bytes_total
+    trace.pipeline_buffers_counter()  # ..._ec_pipeline_buffers_total
     ec_encoder._pipeline_hist()  # SeaweedFS_volume_ec_pipeline_seconds
     from seaweedfs_tpu.storage.erasure_coding import online as ec_online
 
@@ -718,7 +719,8 @@ def phase_label_violations() -> list[str]:
     SeaweedFS_volume_ec_admin_seconds (a handler, or `<handler>.<step>` with
     a declared handler), the `kernel` values of
     SeaweedFS_volume_ec_device_seconds and the `source` values of
-    SeaweedFS_volume_ec_read_interval_bytes_total — unique, well-formed, each
+    SeaweedFS_volume_ec_read_interval_bytes_total and of
+    SeaweedFS_volume_ec_pipeline_buffers_total — unique, well-formed, each
     written by the module that owns the seam, so a renamed value cannot
     silently empty the benchmark's per-layer metrics that read it."""
     from seaweedfs_tpu.stats import trace
@@ -733,6 +735,9 @@ def phase_label_violations() -> list[str]:
         ("ec read interval source", trace.EC_READ_INTERVAL_SOURCES,
          PHASE_KERNEL_RE, os.path.join(
              "seaweedfs_tpu", "storage", "erasure_coding", "ec_volume.py")),
+        ("ec pipeline buffer source", trace.EC_PIPELINE_BUFFER_SOURCES,
+         PHASE_KERNEL_RE, os.path.join(
+             "seaweedfs_tpu", "storage", "erasure_coding", "encoder.py")),
     ):
         with open(os.path.join(root, seam)) as f:
             src = f.read()
