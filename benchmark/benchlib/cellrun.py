@@ -14,6 +14,10 @@ from . import cluster, loops, promtext, reference, volume, xplane
 ROOT = cluster.ROOT
 HERE = cluster.HERE
 DEVICE_SUFFIX = "-pallas"  # kernel labels under which the device carried bytes
+# tests' faults that flip one byte of a kept shard file after the window, and
+# the loop's method that names the file
+FLIPS = {"flip-shard-byte": "produced_shard_path",
+         "flip-early-parity-byte": "early_parity_path"}
 
 
 class NoChip(cluster.RunError):
@@ -138,6 +142,21 @@ class Run:
         t0 = time.perf_counter()
         self.loop.prepare()
         self.notes["prepare_s"] = time.perf_counter() - t0
+        if self.traffic.get("sync_after_fill"):
+            # set-up ends settled: `os.sync()` after the fill comes back at
+            # once in most runs on the chip's machine, and the kept volumes'
+            # pages, which no restore can drop, were then written back inside
+            # the window (PERF.md 6 PR 37). Each kept file by name, then all
+            t0 = time.perf_counter()
+            for vol in self.vols:
+                for ext in (".dat", ".idx"):
+                    fd = os.open(vol.kept_base + ext, os.O_RDONLY)
+                    try:
+                        os.fsync(fd)
+                    finally:
+                        os.close(fd)
+            os.sync()
+            self.notes["sync_before_window_s"] = time.perf_counter() - t0
 
     def assign_volumes(self) -> None:
         """Has the master grow the collection and hand out one run of keys in
@@ -185,7 +204,7 @@ class Run:
             self.loop.window(self.seconds, self.trace)
             after = {"metrics": promtext.parse(srv.metrics()), "status": srv.status()}
             self.loop.after_window()
-            if self.fault == "flip-shard-byte":
+            if self.fault in FLIPS:
                 self.plant(self.fault)
             sample = self.sample_reads()
             self.memory_peak = srv.memory_peak_bytes()
@@ -207,12 +226,13 @@ class Run:
 
     def plant(self, fault: str) -> None:
         """Tests only: break what the timed path produces, underneath the
-        comparison. `flip-shard-byte` alters one byte of one shard file that a
-        verb of the window left; `corrupt-surviving-shard` alters, before the
+        comparison. `flip-shard-byte` alters one byte of one shard file that the
+        window's last verb left, `flip-early-parity-byte` one of a parity file
+        kept of the early seal; `corrupt-surviving-shard` alters, before the
         window, the first block of a shard that degraded reads rebuild from,
         so the server hands out answers altered where they are made."""
-        if fault == "flip-shard-byte":
-            path = self.loop.produced_shard_path()
+        if fault in FLIPS:
+            path = getattr(self.loop, FLIPS[fault])()
             at, length = os.path.getsize(path) // 2, 1
         elif fault == "corrupt-surviving-shard":
             path = volume.file_base(

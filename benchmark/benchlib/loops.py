@@ -29,8 +29,10 @@ import numpy as np
 from . import cluster, promtext, reference, stats, volume
 
 ALL_SHARDS = list(range(reference.TOTAL))
+PARITY_SHARDS = ALL_SHARDS[reference.DATA:]
 # how set-up seals the one volume of a mix that repairs or reads it
 SEAL_ONE = "lock\nec.encode -volumeId {vid}\nunlock\n"
+CGROUP_MEMORY_STAT = "/sys/fs/cgroup/memory.stat"
 
 
 def first_encode(traffic: dict) -> str:
@@ -102,6 +104,18 @@ class Tracer:
             (t - lo[0]) / span if span > 0 else 1.0)
 
 
+def page_cache_now() -> dict | None:
+    """`file_dirty` and `file_writeback` (bytes) of the runner's cgroup, which
+    the server child shares: what a stalled shard write waits behind. None
+    where the machine does not show them."""
+    try:
+        with open(CGROUP_MEMORY_STAT) as f:
+            stat = dict(line.split() for line in f)
+        return {k: int(stat[k]) for k in ("file_dirty", "file_writeback")}
+    except (OSError, KeyError, ValueError):
+        return None
+
+
 class VerbLoop:
     """What seal and repair share: the window rule, the traced verb."""
 
@@ -120,6 +134,9 @@ class VerbLoop:
         raise NotImplementedError
 
     def window(self, seconds: float, trace: bool) -> None:
+        pages = page_cache_now()
+        if pages is not None:
+            self.run.notes["page_cache"] = {"before_window": pages, "after_verb": []}
         t0 = time.perf_counter()
         k = 0
         while True:
@@ -147,6 +164,8 @@ class VerbLoop:
         verb["cycle_seconds"] = time.perf_counter() - t0
         verb["traced"] = traced
         self.verbs.append(verb)
+        if "page_cache" in self.run.notes:
+            self.run.notes["page_cache"]["after_verb"].append(page_cache_now())
         if traced:
             # the traced span the metrics are read over is this verb itself
             self.tracer.wall_span = (wall0, wall0 + verb["cycle_seconds"])
@@ -181,12 +200,20 @@ class VerbLoop:
 class SealLoop(VerbLoop):
     def __init__(self, run) -> None:
         super().__init__(run)
-        self.kept: list[tuple[int, str]] = []  # (verb index, directory of links)
-        # which earlier seal is kept (as hard links: no byte is written) to be
-        # compared after the window, besides the last: one of the first three,
-        # drawn from the seed. Each kept seal holds 1.5 GB of pages that the
-        # host would otherwise drop unwritten when the restore deletes them
-        self.keep_idx = {run.seed % 3}
+        # (verb index, directory of links, the (volume, shard) files linked)
+        self.kept: list[tuple[int, str, list[tuple[volume.Vol, int]]]] = []
+        # Two seals are compared after the window: the last, whole, linked
+        # once the clock has stopped, and one of the first three, drawn from
+        # the seed and linked inside the window. A linked file's written
+        # pages cannot be dropped when the restore deletes the seal, so the
+        # host has to write them back while the next verb dirties its own:
+        # a whole early seal (1.5 GB a volume) stalled the verb after it
+        # (PERF.md 5 item 0). What is kept inside a window is therefore
+        # bounded in bytes: the four parity shards (what the device computed)
+        # of one volume drawn from the seed, 0.43 GB
+        self.early = run.seed % 3
+        rng = np.random.Generator(np.random.SFC64([run.seed, 5]))
+        self.early_volume = int(rng.integers(len(run.vols)))
 
     def prepare(self) -> None:
         """Set-up after the first encode: bring the volume back."""
@@ -210,14 +237,14 @@ class SealLoop(VerbLoop):
             cluster.post_json(srv.volume, "/admin/volume/mount",
                               {"volume": vol.vid, "collection": run.collection})
 
-    def keep_links(self, k: int) -> None:
+    def keep_links(self, k: int, files: list[tuple[volume.Vol, int]]) -> None:
+        """Hard links (no byte is written) to those shard files of seal k."""
         d = os.path.join(self.run.workdir, f"seal_{k}")
         os.makedirs(d)
-        for vol in self.run.vols:
+        for vol, s in files:
             base = volume.file_base(self.server.dir, self.run.collection, vol.vid)
-            for s in ALL_SHARDS:
-                os.link(f"{base}.ec{s:02d}", self.kept_shard(d, vol, s))
-        self.kept.append((k, d))
+            os.link(f"{base}.ec{s:02d}", self.kept_shard(d, vol, s))
+        self.kept.append((k, d, files))
 
     @staticmethod
     def kept_shard(d: str, vol: volume.Vol, shard: int) -> str:
@@ -228,18 +255,29 @@ class SealLoop(VerbLoop):
         return self.verb(k, *self.run.seal_verb(self.traffic["verb"], f"verb_{k}.log"))
 
     def between(self, k: int) -> None:
-        if k - 1 in self.keep_idx:
-            self.keep_links(k - 1)
+        if k - 1 == self.early:
+            self.keep_links(k - 1, [(self.run.vols[self.early_volume], s)
+                                    for s in PARITY_SHARDS])
         self.restore()
 
     def after_window(self) -> None:
         if self.verbs and self.verbs[-1]["ok"]:
-            self.keep_links(len(self.verbs) - 1)
+            self.keep_links(len(self.verbs) - 1, [
+                (vol, s) for vol in self.run.vols for s in ALL_SHARDS])
+        seals = self.kept_files()
+        self.run.notes["kept_seal_files"] = [len(seal) for seal in seals]
+        self.run.notes["kept_seal_bytes"] = [
+            sum(os.path.getsize(path) for path, _, _ in seal) for seal in seals]
+
+    def kept_files(self) -> list[list[tuple[str, volume.Vol, int]]]:
+        """Of each kept seal, its (link, volume, shard)s."""
+        return [[(self.kept_shard(d, vol, s), vol, s) for vol, s in files]
+                for _, d, files in self.kept]
 
     def compare(self, want: list[np.ndarray]) -> dict:
         differing = reference.files_differing(
-            [(self.kept_shard(d, vol, s), want[vol.number][s])
-             for _, d in self.kept for vol in self.run.vols for s in ALL_SHARDS])
+            [(path, want[vol.number][s])
+             for seal in self.kept_files() for path, vol, s in seal])
         return {"shard_files_differing": (differing, 0),
                 "seals_compared": (len(self.kept), None)}
 
@@ -248,6 +286,11 @@ class SealLoop(VerbLoop):
 
     def produced_shard_path(self) -> str:
         return self.kept_shard(self.kept[-1][1], self.run.vols[-1], 11)
+
+    def early_parity_path(self) -> str:
+        if len(self.kept) < 2:
+            raise cluster.RunError("the window kept no early seal")
+        return self.kept_shard(self.kept[0][1], self.run.vols[self.early_volume], 11)
 
 
 def one_volume(run, kind: str) -> volume.Vol:

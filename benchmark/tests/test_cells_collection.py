@@ -1,8 +1,10 @@
 """PR 32's harness repair, on the CPU at the tiny size: a configuration of N
 volumes in one collection sealed by one `ec.encode -collection` (a test-only
 spec: no cell of `BENCHMARK.json` has more than one volume yet), the read
-loop's comparison, payloads of mixed needle sizes, and the dry check that the
-next deployment (`ec4x1g-1m`, cell `ec4x1g.seal`) is data alone.
+loop's comparison, payloads of mixed needle sizes, and the check that the
+deployment after it (`ec4x1g-1m`, cell `ec4x1g.seal`, PR 33) was data alone:
+`BENCHMARK.json` lists what the dry check foresaw. Since PR 37 the seal loop
+keeps of the early seal four parity files of one volume and of the last all.
 """
 
 import copy
@@ -23,8 +25,8 @@ TWO = {"name": "two-volumes", "source": "test only",
        "file": "benchmark/tests/data/two-volumes.json", "reduced": [], "why": "test only"}
 TWO_SEAL = {"name": "two.seal", "config": "two-volumes", "traffic": "seal-collection",
             "chips": 1, "why": "test only"}
-# what the next `model_config` PR adds to BENCHMARK.json, and nothing else but
-# the configuration's file (tests/data/ec4x1g-1m.json, to be configs/ec4x1g-1m.json)
+# what PR 33 (`model_config`) was to add to BENCHMARK.json, and nothing else but
+# the configuration's file (tests/data/ec4x1g-1m.json, now configs/ec4x1g-1m.json)
 EC4 = {"name": "ec4x1g-1m",
        "source": "BASELINE.json config 5 (multi-volume ec.encode: 256 x 30GB volumes, pmap"
                  " across v5p-8 pod), upstream weed/shell/command_ec_encode.go -collection form",
@@ -40,11 +42,13 @@ EC4_SEAL = {"name": "ec4x1g.seal", "config": "ec4x1g-1m", "traffic": "seal-colle
 
 
 def spec_with(config: dict, cell: dict) -> dict:
+    """`BENCHMARK.json` with a test's configuration and cell in place of any
+    of the same name, the cell reporting what `ec1g.seal` reports."""
     spec = copy.deepcopy(cellrun.load_spec())
-    spec["configs"].append(config)
-    spec["workloads"].append(cell)
+    spec["configs"] = [c for c in spec["configs"] if c["name"] != config["name"]] + [config]
+    spec["workloads"] = [w for w in spec["workloads"] if w["name"] != cell["name"]] + [cell]
     for m in spec["end_to_end"] + spec["per_layer"]:
-        if "ec1g.seal" in m.get("workloads", []):
+        if "ec1g.seal" in m.get("workloads", []) and cell["name"] not in m["workloads"]:
             m["workloads"].append(cell["name"])
     return spec
 
@@ -69,8 +73,8 @@ def two_volumes():
 
     reference.files_differing = keep
     try:
-        # a traced run goes on until it has its second verb; seed 78: seal 0
-        # is kept besides the last, so both verbs are compared
+        # a traced run goes on until it has its second verb; seed 78: of seal
+        # 0 four parity files are kept besides the last, so both are compared
         run, result = rehearse(spec_with(TWO, TWO_SEAL), "two.seal", seed=78,
                                seconds=0.5, trace=True)
     finally:
@@ -84,6 +88,8 @@ def test_two_volumes_are_sealed_by_one_verb_and_the_run_is_correct(two_volumes):
     assert result["attempted"] == 2 and result["failed"] == 0
     assert result["checks"]["seals_compared"]["value"] == 2
     assert result["checks"]["sample_reads_wrong"] == {"value": 0, "limit": 0}
+    # a seal mix's set-up ends settled: the fill, then the kept files, flushed
+    assert result["notes"]["sync_s"] >= 0 and result["notes"]["sync_before_window_s"] >= 0
     # the seal cell's per-layer metrics read the same counters over N volumes
     assert {"verb_client_s.seal", "pipeline_stage_busy_share.seal",
             "compiles_in_window.seal"} <= set(result["metrics"])
@@ -100,13 +106,36 @@ def test_the_collection_holds_exactly_the_filled_volumes(two_volumes):
     assert not set(made["empty_ones_deleted"]) & set(made["ids"])
 
 
-def test_both_volumes_28_shard_files_are_compared_for_every_kept_seal(two_volumes):
-    _, _, compared = two_volumes
+def test_the_early_seal_keeps_four_parity_files_of_one_volume_and_the_last_all(two_volumes):
+    run, result, compared = two_volumes
     (files,) = compared
-    assert len(files) == 2 * 2 * 14 and len(set(files)) == len(files)
-    names = {os.path.basename(p) for p in files}
-    assert names == ({f"ec{s:02d}" for s in range(14)}
-                     | {f"v1.ec{s:02d}" for s in range(14)})
+    assert len(files) == 4 + 2 * 14 and len(set(files)) == len(files)
+    early = [p for p in files if os.path.basename(os.path.dirname(p)) == "seal_0"]
+    last = [p for p in files if os.path.basename(os.path.dirname(p)) == "seal_1"]
+    # one volume, drawn from the seed: every name has that volume's prefix
+    prefix = "v1." if run.loop.early_volume else ""
+    assert {os.path.basename(p) for p in early} == {
+        f"{prefix}ec{s:02d}" for s in (10, 11, 12, 13)}
+    assert {os.path.basename(p) for p in last} == (
+        {f"ec{s:02d}" for s in range(14)} | {f"v1.ec{s:02d}" for s in range(14)})
+    assert result["notes"]["kept_seal_files"] == [4, 28]
+    shard = reference.shard_file_size(run.vols[run.loop.early_volume].dat_bytes)
+    assert result["notes"]["kept_seal_bytes"][0] == 4 * shard
+    assert result["notes"]["kept_seal_bytes"][1] == sum(
+        14 * reference.shard_file_size(vol.dat_bytes) for vol in run.vols)
+
+
+def test_the_early_volume_is_drawn_from_the_seed_and_covers_every_volume():
+    class Seeded:
+        def __init__(self, seed):
+            self.seed, self.vols = seed, [volume.Vol(v, None) for v in range(4)]
+            self.dat_bytes, self.server, self.traffic = 0, None, {}
+
+    drawn = [loops.SealLoop(Seeded(seed)) for seed in range(2**31, 2**31 + 64)]
+    assert {loop.early_volume for loop in drawn} == {0, 1, 2, 3}
+    assert {loop.early for loop in drawn} == {0, 1, 2}
+    again = loops.SealLoop(Seeded(2**31 + 5))
+    assert (again.early, again.early_volume) == (drawn[5].early, drawn[5].early_volume)
 
 
 def test_a_verb_counts_both_volumes_bytes(two_volumes):
@@ -138,6 +167,30 @@ def test_a_byte_flipped_in_the_second_volumes_shard_is_not_correct():
     assert os.path.basename(run.loop.produced_shard_path()) == "v1.ec11"
     assert result["correct"] is False
     assert result["checks"]["shard_files_differing"] == {"value": 1, "limit": 0}
+
+
+def test_a_byte_flipped_in_a_parity_file_of_the_early_seal_is_not_correct():
+    # seed 78: seal 0 is the early one; a traced window has two verbs or more
+    run, result = rehearse(spec_with(TWO, TWO_SEAL), "two.seal", seed=78,
+                           seconds=0.5, trace=True, fault="flip-early-parity-byte")
+    path = run.loop.early_parity_path()
+    assert os.path.basename(os.path.dirname(path)) == "seal_0"
+    assert os.path.basename(path) == ("v1." if run.loop.early_volume else "") + "ec11"
+    assert result["notes"]["kept_seal_files"][0] == 4
+    assert result["correct"] is False
+    assert result["checks"]["shard_files_differing"] == {"value": 1, "limit": 0}
+    assert result["checks"]["seals_compared"]["value"] == 2
+
+
+def test_a_window_that_ends_before_the_early_seal_compares_the_last_alone():
+    # seed 77: the early seal would be the third; a traced window of two verbs
+    run, result = rehearse(spec_with(TWO, TWO_SEAL), "two.seal", seed=77,
+                           seconds=0.1, trace=True)
+    assert result["attempted"] == 2 and result["correct"] is True, result["checks"]
+    assert result["checks"]["seals_compared"]["value"] == 1
+    assert result["notes"]["kept_seal_files"] == [28]
+    with pytest.raises(cluster.RunError, match="no early seal"):
+        run.loop.early_parity_path()
 
 
 def test_a_verb_that_does_not_name_one_volume_is_a_failed_operation(monkeypatch):
@@ -187,7 +240,7 @@ def test_the_other_loops_refuse_more_than_one_volume(kind):
         loops.KINDS[run.traffic["loop"]](run)
 
 
-# --- the dry check: the next deployment is data alone ---------------------------------
+# --- the dry check: the deployment after PR 32 was data alone --------------------------
 def test_ec4x1g_seal_is_two_entries_and_a_file():
     spec = spec_with(EC4, EC4_SEAL)
     run = cellrun.Run(spec, "ec4x1g.seal", 5, 20.0, False, "real", 0.0)
@@ -195,10 +248,15 @@ def test_ec4x1g_seal_is_two_entries_and_a_file():
     assert run.size == {"needles": 1024, "needle_bytes": 1048576}
     assert run.traffic["verb"].format(vid=0, collection=run.collection) == (
         "lock\nec.encode -collection warm\nunlock\n")
+    # PR 33 added exactly what the dry check foresaw: the configuration's entry
+    # but for where its file lies, and the cell's but for its `why`
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         listed = json.load(f)
-    assert "ec4x1g.seal" not in {w["name"] for w in listed["workloads"]}
-    for entry in (EC4, EC4_SEAL):
+    config_entry = next(c for c in listed["configs"] if c["name"] == "ec4x1g-1m")
+    assert config_entry == {**EC4, "file": "benchmark/configs/ec4x1g-1m.json"}
+    cell = next(w for w in listed["workloads"] if w["name"] == "ec4x1g.seal")
+    assert {**cell, "why": ""} == {**EC4_SEAL, "why": ""}
+    for entry in (EC4, EC4_SEAL, config_entry, cell):
         assert all(len(str(v)) <= 200 for v in entry.values())
     with open(os.path.join(DATA, "ec4x1g-1m.json")) as f:
         config = json.load(f)

@@ -19,10 +19,10 @@ from test_cells import in_process, run_cli
 CELL = "ec4x1g.seal"
 LEASE = "SeaweedFS_volume_ec_device_lease_seconds"
 # of `ec1g.seal`'s per-layer metrics, those whose reading keeps its meaning
-# when four handlers run at once
+# when four handlers run at once (the last two since PR 34)
 SHARED = {"pipeline_stage_busy_share.seal", "write_drain_share.seal", "h2d_put_s.seal",
           "interpreter_busy_share.seal", "compiles_in_window.seal",
-          "device_idle_share.seal"}
+          "device_idle_share.seal", "read_stage_s.seal", "batch_buffer_kept_share.seal"}
 # they subtract or rank seconds summed over handlers that overlap, or divide
 # four volumes' work by one chip's time
 NOT_SHARED = {"verb_outside_pipeline_s.seal", "verb_client_s.seal",
