@@ -16,7 +16,9 @@ encode/decode and MD5/CRC32C hash kernels and feed Prometheus histograms
 BENCH run can compute GB/s per kernel from `/metrics` alone:
 `rate = <family>_bytes_total / <family>_seconds_sum`. `phase` is the same
 for paths too hot for a ring span: seconds, bytes and, where asked, the
-calling thread's CPU seconds.
+calling thread's CPU seconds. An EC pipeline's edges (its set-up, its head
+before the first program, its tail after the last, its finish) are phases of
+`SeaweedFS_volume_ec_pipeline_edge_seconds{verb,edge}`.
 
 While `stats.profiler.device_trace` runs, every span and phase also enters
 a `jax.profiler.TraceAnnotation` of its own name, so the program's spans
@@ -80,11 +82,24 @@ EC_LEASE_SECONDS = "SeaweedFS_volume_ec_device_lease_seconds"
 # were there already (encoder.BatchBuffers)
 EC_PIPELINE_BUFFERS = "SeaweedFS_volume_ec_pipeline_buffers_total"
 EC_PIPELINE_BUFFER_SOURCES = ("kept", "fresh")
+# the edges of one encode or rebuild pipeline's call (encoder._Edges), once
+# each a pipeline: `setup` from the start of the call's pipeline path to the
+# pipeline's, `head` from there to the first program's enqueue, `tail` from
+# the last enqueue to the writer's end, `finish` from the pipeline's return
+# to the call's
+EC_PIPELINE_EDGE_SECONDS = "SeaweedFS_volume_ec_pipeline_edge_seconds"
+EC_PIPELINE_VERBS = ("encode", "rebuild")
+EC_PIPELINE_EDGES = ("setup", "head", "tail", "finish")
+# thread CPU seconds of each stage thread of a pipeline, added once a
+# pipeline at the end of the thread's loop (encoder._run_pipeline)
+EC_PIPELINE_CPU_SECONDS = "SeaweedFS_volume_ec_pipeline_cpu_seconds_total"
+EC_PIPELINE_STAGES = ("read", "encode", "write")
 # families of phases whose labels are not `kernel` and that count no bytes; a
 # phase of a family with several labels gives `kernel` as a tuple of values
 _FAMILY_LABELS = {
     EC_ADMIN_SECONDS: ("op",),
     EC_LEASE_SECONDS: ("device", "state"),
+    EC_PIPELINE_EDGE_SECONDS: ("verb", "edge"),
 }
 
 # jax.profiler.TraceAnnotation between a device trace's start and stop
@@ -532,6 +547,15 @@ def pipeline_buffers_counter():
         "batch buffers handed to an EC pipeline's reader, by where their"
         " pages came from",
         ("source",))
+
+
+def pipeline_cpu_counter():
+    """`SeaweedFS_volume_ec_pipeline_cpu_seconds_total{stage}`: one update a
+    stage thread a pipeline, that thread's CPU seconds over its whole loop."""
+    return _plain_counter(
+        EC_PIPELINE_CPU_SECONDS,
+        "thread CPU seconds of an EC pipeline's stage threads",
+        ("stage",))
 
 
 def observe_kernel(family: str, kernel: str | tuple, seconds: float,

@@ -325,8 +325,56 @@ class _ShardWriters:
                 pass
 
 
+class _Edges:
+    """The edges of one encode or rebuild pipeline's call, each a
+    `trace.phase` of `SeaweedFS_volume_ec_pipeline_edge_seconds{verb, edge}`
+    (`ec.pipeline.<edge>` in a device trace), observed once a pipeline on the
+    caller's thread: `setup` from the start of the call's pipeline path to
+    `_run_pipeline`'s entry, `head` from there to the return of the first
+    `encode_job`, `tail` from the return of the last one to the writer's end,
+    `finish` from `_run_pipeline`'s return to the call's. `setup` + the
+    pipeline's wall + `finish` is the call but for the choice of a backend
+    (and, on the native one, a fused probe that fell through). A call that
+    runs no pipeline (nothing to rebuild, the fused native path) or raises
+    counts no edge it left open. `setup` and `finish` hold the device lease's
+    grant and return and the kernel span's start and end, so their
+    annotations cross those two. With `verb` None (a pipeline of the online
+    writer) it does nothing."""
+
+    def __init__(self, verb: str | None) -> None:
+        self.verb = verb
+        self._open: trace.phase | None = None
+        self.enter("setup")
+
+    def enter(self, edge: str) -> None:
+        """Leave the edge that is open, if any, and open `edge`."""
+        self.leave()
+        if self.verb is not None:
+            self._open = trace.phase(
+                "ec.pipeline." + edge, trace.EC_PIPELINE_EDGE_SECONDS,
+                (self.verb, edge)).__enter__()
+
+    def leave(self, counted: bool = True) -> None:
+        ph, self._open = self._open, None
+        if ph is not None:
+            if not counted:
+                ph.family = None  # the annotation closes, nothing is counted
+            ph.__exit__(None, None, None)
+
+    def __enter__(self) -> "_Edges":
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        ph = self._open
+        self.leave(ph is not None and exc_type is None
+                   and ph.kernel[1] == "finish")
+
+
+_NO_EDGES = _Edges(None)
+
+
 def _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes=None,
-                  dev: int | None = None) -> None:
+                  dev: int | None = None, edges: _Edges = _NO_EDGES) -> None:
     """reader thread -> encode (caller thread) -> writer thread, with
     bounded queues, `_QUEUE_DEPTH + 2` buffer slots that go round between
     writer and reader for backpressure, and a stop flag so a failure in any
@@ -342,7 +390,13 @@ def _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes=None,
     runs under a span, leaves one ring span per stage as that span's child
     (`ec.pipeline.read`, `.encode`, `.write`; attrs `batch`, `thread`, with
     `job_bytes(job)` `bytes`, and with `dev`, the index of the device the
-    pipeline borrowed, `device`), whichever thread did the work."""
+    pipeline borrowed, `device`), whichever thread did the work. Each stage
+    thread adds its CPU seconds over its whole loop to
+    `SeaweedFS_volume_ec_pipeline_cpu_seconds_total{stage}` once, at its end;
+    `edges` closes the call's `setup` and times the pipeline's `head` and
+    `tail` (`_Edges`)."""
+    edges.enter("head")
+    jobs = list(jobs)  # the caller knows which batch is the last
     parent = trace.current()  # worker threads carry no context of their own
     batches = {"read": 0, "encode": 0, "write": 0}  # each its own thread's
 
@@ -368,6 +422,7 @@ def _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes=None,
     stop = threading.Event()
     errors: list[BaseException] = []
     hist = _pipeline_hist()
+    cpu = trace.pipeline_cpu_counter()
     perf = time.perf_counter
 
     def _put(q: queue.Queue, item) -> bool:
@@ -380,6 +435,7 @@ def _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes=None,
         return False
 
     def reader():
+        c0 = time.thread_time()
         o_wait = hist.labels("read", "wait")
         o_busy = hist.labels("read", "busy")
         try:
@@ -407,8 +463,10 @@ def _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes=None,
             stop.set()
         finally:
             _put(read_q, None) or read_q.put(None)
+            cpu.labels("read").inc(time.thread_time() - c0)
 
     def writer():
+        c0 = time.thread_time()
         o_wait = hist.labels("write", "wait")
         o_busy = hist.labels("write", "busy")
         try:
@@ -434,13 +492,17 @@ def _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes=None,
                 if item is None:
                     return
                 free.put(None)
+        finally:
+            cpu.labels("write").inc(time.thread_time() - c0)
 
+    c0 = time.thread_time()
     rt = threading.Thread(target=reader, name="ec-reader", daemon=True)
     wt = threading.Thread(target=writer, name="ec-writer", daemon=True)
     rt.start()
     wt.start()
     o_wait = hist.labels("encode", "wait")
     o_busy = hist.labels("encode", "busy")
+    enqueued = 0
     try:
         while True:
             t0 = perf()
@@ -451,9 +513,16 @@ def _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes=None,
             job, buf = item
             handle = staged("encode", encode_job, job, buf)
             t2 = perf()
+            enqueued += 1
+            if enqueued == len(jobs):
+                edges.enter("tail")  # the device has its last program
+            elif enqueued == 1:
+                edges.leave()  # head: the device has its first program
             write_q.put((job, buf, handle))
             o_wait.observe((t1 - t0) + (perf() - t2))
             o_busy.observe(t2 - t1)
+        if not jobs:
+            edges.enter("tail")
     except BaseException as e:  # noqa: BLE001 - e.g. device error mid-encode
         errors.append(e)
         stop.set()
@@ -463,9 +532,11 @@ def _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes=None,
                 break
             free.put(item[1])  # read, never enqueued
     finally:
+        cpu.labels("encode").inc(time.thread_time() - c0)
         write_q.put(None)
         rt.join()
         wt.join()
+        edges.leave(counted=not errors)
         idle = []
         while not free.empty():
             buf = free.get()
@@ -474,6 +545,7 @@ def _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes=None,
         batch_buffers.give(idle)
     if errors:
         raise errors[0]
+    edges.enter("finish")
 
 
 def _write_ec_files_fused(
@@ -567,7 +639,9 @@ def write_ec_files(
     via the fused native single-pass kernel when the host supports it,
     else the 3-stage pipeline (see module docstring). Both paths run under
     a kernel-timing span feeding SeaweedFS_volume_ec_encode_seconds (+ the
-    bytes counter), so /metrics alone yields encode GB/s."""
+    bytes counter), so /metrics alone yields encode GB/s. The pipeline path
+    times its edges under `verb="encode"` (`_Edges`); the fused path has no
+    pipeline and records no edges."""
     dat_path = base_file_name + ".dat"
     total = os.path.getsize(dat_path)
     backend = codec.backend if codec else pick_pipeline_backend()
@@ -593,7 +667,8 @@ def write_ec_files(
                 sp.attrs["kernel"] = "fused-unavailable"
         if fused_ok:
             return
-    with _pipeline_codec(codec, backend) as (codec, dev):
+    with _Edges("encode") as edges, \
+            _pipeline_codec(codec, backend) as (codec, dev):
         if batch is None:
             batch = _default_batch(codec.backend)
         with trace.kernel_span(
@@ -603,7 +678,7 @@ def write_ec_files(
         ):
             _write_ec_files_pipeline(
                 base_file_name, codec, large_block_size, small_block_size,
-                batch, total, dev,
+                batch, total, dev, edges,
             )
 
 
@@ -614,7 +689,8 @@ def _write_ec_files_pipeline(
     small_block_size: int,
     batch: int,
     total: int,
-    dev: int | None = None,
+    dev: int | None,
+    edges: _Edges,
 ) -> None:
     dat_path = base_file_name + ".dat"
     shard_size = shard_file_size(total, large_block_size, small_block_size)
@@ -696,7 +772,8 @@ def _write_ec_files_pipeline(
             per_shard = job[3] * job[4] if job[0] == "rows" else job[5]
             return per_shard * DATA_SHARDS_COUNT
 
-        _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes, dev)
+        _run_pipeline(jobs, read_job, encode_job, write_job, job_bytes, dev,
+                      edges)
     except BaseException:
         writers.abort()
         raise
@@ -714,14 +791,18 @@ def rebuild_ec_files(
     """Regenerate missing .ecXX files from the surviving >= 10
     (`ec_encoder.go:61,237-291`), through the same three-stage pipeline —
     the GF transform is the inverted-submatrix product on the pipeline
-    backend (BASELINE config 2). Returns the rebuilt shard ids."""
+    backend (BASELINE config 2). Returns the rebuilt shard ids. The pipeline
+    times its edges under `verb="rebuild"` (`_Edges`); nothing to rebuild
+    and the fused native path run no pipeline and record no edges."""
     backend = codec.backend if codec else pick_pipeline_backend()
-    with _pipeline_codec(codec, backend) as (codec, dev):
+    with _Edges("rebuild") as edges, \
+            _pipeline_codec(codec, backend) as (codec, dev):
         with trace.kernel_span(
             "ec.rebuild", trace.EC_DECODE_SECONDS,
             "rebuild-" + codec.kernel_label, **_device_attr(dev),
         ) as sp:
-            return _rebuild_ec_files(base_file_name, codec, chunk, sp, dev)
+            return _rebuild_ec_files(base_file_name, codec, chunk, sp, dev,
+                                     edges)
 
 
 def _rebuild_ec_files(
@@ -729,7 +810,8 @@ def _rebuild_ec_files(
     codec: RSCodec,
     chunk: int | None,
     sp,
-    dev: int | None = None,
+    dev: int | None,
+    edges: _Edges,
 ) -> list[int]:
     from seaweedfs_tpu.ops import gf256
 
@@ -832,7 +914,7 @@ def _rebuild_ec_files(
 
             _run_pipeline(
                 jobs, read_job, encode_job, write_job,
-                lambda job: job[1] * DATA_SHARDS_COUNT, dev,
+                lambda job: job[1] * DATA_SHARDS_COUNT, dev, edges,
             )
         except BaseException:
             writers.abort()

@@ -474,6 +474,11 @@ class TestMetricNameLint:
         assert kinds["SeaweedFS_volume_ec_device_programs_total"] == "counter"
         # PR-38: device results' copies back, by when each began
         assert kinds["SeaweedFS_volume_ec_device_copies_total"] == "counter"
+        # an EC pipeline's edges and its stage threads' CPU
+        assert kinds["SeaweedFS_volume_ec_pipeline_edge_seconds"] == "histogram"
+        assert "SeaweedFS_volume_ec_pipeline_edge_bytes_total" not in kinds
+        assert kinds["SeaweedFS_volume_ec_pipeline_cpu_seconds_total"] \
+            == "counter"
         assert kinds["SeaweedFS_volume_ec_decode_cpu_seconds_total"] \
             == "counter"
         assert kinds["SeaweedFS_http_request_cpu_seconds_total"] == "counter"
@@ -494,6 +499,11 @@ class TestMetricNameLint:
         ("EC_READ_INTERVAL_SOURCES", ("local", "page-cache"), "never writes it"),
         ("EC_PIPELINE_BUFFER_SOURCES", ("kept", "kept"), "duplicate"),
         ("EC_PIPELINE_BUFFER_SOURCES", ("kept", "warm"), "never writes it"),
+        ("EC_PIPELINE_VERBS", ("encode", "repair"), "never writes it"),
+        ("EC_PIPELINE_EDGES", ("setup", "setup"), "duplicate"),
+        ("EC_PIPELINE_EDGES", ("head", "Tail"), "malformed"),
+        ("EC_PIPELINE_EDGES", ("head", "drain"), "never writes it"),
+        ("EC_PIPELINE_STAGES", ("read", "transfer"), "never writes it"),
     ])
     def test_phase_label_lint_catches_violations(
             self, monkeypatch, attr, value, complaint):

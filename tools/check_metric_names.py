@@ -70,13 +70,15 @@ def collect() -> tuple[dict[str, str], list[str]]:
     # force the lazily-registered families into the registry
     for fam in (trace.EC_ENCODE_SECONDS, trace.EC_DECODE_SECONDS,
                 trace.FILER_HASH_SECONDS, crc.VOLUME_CRC32C_SECONDS,
-                trace.EC_ADMIN_SECONDS, trace.EC_DEVICE_SECONDS):
+                trace.EC_ADMIN_SECONDS, trace.EC_DEVICE_SECONDS,
+                trace.EC_PIPELINE_EDGE_SECONDS):
         trace._kernel_metrics(fam)
     trace._cpu_counter(trace.EC_DECODE_SECONDS)  # ..._decode_cpu_seconds_total
     trace.device_programs_counter()  # SeaweedFS_volume_ec_device_programs_total
     trace.device_copies_counter()  # SeaweedFS_volume_ec_device_copies_total
     trace.read_interval_bytes_counter()  # ..._ec_read_interval_bytes_total
     trace.pipeline_buffers_counter()  # ..._ec_pipeline_buffers_total
+    trace.pipeline_cpu_counter()  # ..._ec_pipeline_cpu_seconds_total
     ec_encoder._pipeline_hist()  # SeaweedFS_volume_ec_pipeline_seconds
     from seaweedfs_tpu.storage.erasure_coding import online as ec_online
 
@@ -722,13 +724,17 @@ def phase_label_violations() -> list[str]:
     SeaweedFS_volume_ec_device_seconds, the `start` values of
     SeaweedFS_volume_ec_device_copies_total and the `source` values of
     SeaweedFS_volume_ec_read_interval_bytes_total and of
-    SeaweedFS_volume_ec_pipeline_buffers_total — unique, well-formed, each
-    written by the module that owns the seam, so a renamed value cannot
+    SeaweedFS_volume_ec_pipeline_buffers_total, the `verb` and `edge` values
+    of SeaweedFS_volume_ec_pipeline_edge_seconds and the `stage` values of
+    SeaweedFS_volume_ec_pipeline_cpu_seconds_total — unique, well-formed,
+    each written by the module that owns the seam, so a renamed value cannot
     silently empty the benchmark's per-layer metrics that read it."""
     from seaweedfs_tpu.stats import trace
 
     bad: list[str] = []
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    encoder_py = os.path.join("seaweedfs_tpu", "storage", "erasure_coding",
+                              "encoder.py")
     for what, names, regex, seam in (
         ("ec admin op", trace.EC_ADMIN_OPS, PHASE_OP_RE,
          os.path.join("seaweedfs_tpu", "server", "volume.py")),
@@ -740,8 +746,13 @@ def phase_label_violations() -> list[str]:
          PHASE_KERNEL_RE, os.path.join(
              "seaweedfs_tpu", "storage", "erasure_coding", "ec_volume.py")),
         ("ec pipeline buffer source", trace.EC_PIPELINE_BUFFER_SOURCES,
-         PHASE_KERNEL_RE, os.path.join(
-             "seaweedfs_tpu", "storage", "erasure_coding", "encoder.py")),
+         PHASE_KERNEL_RE, encoder_py),
+        ("ec pipeline verb", trace.EC_PIPELINE_VERBS, PHASE_KERNEL_RE,
+         encoder_py),
+        ("ec pipeline edge", trace.EC_PIPELINE_EDGES, PHASE_KERNEL_RE,
+         encoder_py),
+        ("ec pipeline stage", trace.EC_PIPELINE_STAGES, PHASE_KERNEL_RE,
+         encoder_py),
     ):
         with open(os.path.join(root, seam)) as f:
             src = f.read()
