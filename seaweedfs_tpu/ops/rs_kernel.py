@@ -56,31 +56,34 @@ def transform_kernel() -> str:
 @functools.lru_cache(maxsize=64)
 def _compiled_xla(rows: int, cols: int, matrix_bytes: bytes, tile: int):
     """The jitted XLA form of one (rows, cols) coefficient matrix:
-    fn((cols, n) uint8) -> (rows, n) uint8, for any n (`tile` is the Pallas
-    body's business: `rs_pallas.compiled` has the same signature)."""
+    fn((cols, w) uint32) -> (rows, w) uint32, the bytes of (cols, 4w) and
+    (rows, 4w) uint8 as little-endian words, for any w (`tile` is the Pallas
+    body's business: `rs_pallas.compiled` takes and returns the same bytes,
+    its result shaped (rows, w/128, 128))."""
     jax = device.jax()
     jnp = jax.numpy
     m = np.frombuffer(matrix_bytes, dtype=np.uint8).reshape(rows, cols)
     a = jnp.asarray(gf256.bit_matrix(m), dtype=jnp.int8)  # (cols*8, rows*8)
 
     @jax.jit
-    def transform(shards):  # (cols, n) uint8
-        n = shards.shape[1]
-        xt = shards.T  # (n, cols)
-        k = jnp.arange(8, dtype=jnp.uint8)
-        bits = (xt[:, :, None] >> k) & jnp.uint8(1)  # (n, cols, 8)
-        bits = bits.reshape(n, cols * 8).astype(jnp.int8)
-        y = jax.lax.dot_general(
-            bits,
-            a,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )  # (n, rows*8)
-        ybits = (y & 1).astype(jnp.uint8).reshape(n, rows, 8)
-        packed = jnp.sum(
-            ybits.astype(jnp.int32) << jnp.arange(8, dtype=jnp.int32), axis=-1
-        ).astype(jnp.uint8)
-        return packed.T  # (rows, n)
+    def transform(words):  # (cols, w) uint32
+        w = words.shape[1]
+        k = jnp.arange(8, dtype=jnp.uint32)
+        out = jnp.zeros((rows, w), jnp.uint32)
+        for p in range(4):  # byte p of each word: the same transform
+            xt = ((words >> (8 * p)) & 0xFF).T  # (w, cols)
+            bits = (xt[:, :, None] >> k) & 1  # (w, cols, 8)
+            bits = bits.reshape(w, cols * 8).astype(jnp.int8)
+            y = jax.lax.dot_general(
+                bits,
+                a,
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.int32,
+            )  # (w, rows*8)
+            ybits = (y & 1).astype(jnp.uint32).reshape(w, rows, 8)
+            packed = jnp.sum(ybits << k, axis=-1)  # (w, rows)
+            out = out | (packed.T << (8 * p))
+        return out
 
     return transform
 
@@ -119,18 +122,24 @@ def zero_tailed(rows, tile: int) -> np.ndarray:
 
 def _enqueue(matrix: np.ndarray, shards):
     """The door: out[r] = XOR_c matrix[r,c] x shards[c] enqueued on the
-    device, and beside the (rows, n) device result the number of programs
-    the call enqueued — 1 for the kernel, 1 more for a pad on the device,
-    1 more for a slice on the device.
+    device, and beside the device result the number of programs the call
+    enqueued — 1 for the kernel, 1 more for a pad on the device.
 
-    matrix: (rows, cols) uint8 host array; shards: (cols, n) uint8 on either
-    side of the transfer, any n. The zero tail is written where the bytes
-    are (zero bytes transform to zero bytes, so the result is exact). A host
-    array goes to the jitted program at a rung of the ladder (`zero_tailed`;
-    none is written where the width is a rung's already, as `_apply_jax`
-    hands it in) and the program does its own transfer: no put, and at a
-    rung's width no pad and no slice either. A device array is padded to a
-    tile multiple for the Pallas body, which takes nothing else; the XLA
+    matrix: (rows, cols) uint8 host array. shards: the (cols, n) bytes,
+    either a uint8 host array (or a sequence of cols (n,) rows), any n, or
+    a uint32 device array of (cols, w) little-endian words, n = 4w: bytes
+    cross the link as words, since the kernel reads them so. The result is
+    uint32 words too, (rows, >= n/4 words) in some shape whose row-major
+    bytes are the (rows, >= n) uint8 result (`_JaxHandle` views it so):
+    dense on the device, so a copy back carries the result's own bytes.
+
+    The zero tail is written where the bytes are (zero bytes transform to
+    zero bytes, so the result is exact, and the host drops it). A host
+    array goes to the jitted program at a rung of the ladder
+    (`zero_tailed`; none is written where the width is a rung's already,
+    as `_apply_jax` hands it in) as a free view of words, and the program
+    does its own transfer: no put and no pad. A device array is padded to
+    a tile multiple for the Pallas body, which takes nothing else; the XLA
     body takes any width. Nothing here names a device: a device array's
     program runs where the array lies (the puts below commit it there), a
     host array's on jax's default device."""
@@ -140,33 +149,33 @@ def _enqueue(matrix: np.ndarray, shards):
     matrix_bytes = matrix.tobytes()
     fn = (rs_pallas.compiled if pallas else _compiled_xla)(
         rows, cols, matrix_bytes, tile)
-    n = shards.shape[1]
-    on_host = isinstance(shards, np.ndarray)
     dev = 0  # the default device's index: where a host array's program runs
-    if on_host:
-        shards = zero_tailed(shards, tile)
+    programs = 1
+    if isinstance(shards, np.ndarray):
+        words = zero_tailed(shards, tile).view(np.uint32)
     else:
         jax = device.jax()
         jnp = jax.numpy
-        shards = jnp.asarray(shards, dtype=jnp.uint8)
+        if shards.dtype != jnp.uint32:
+            raise TypeError(
+                f"a device array reaches the door as uint32 words, not {shards.dtype}")
+        words = shards
         # an array inside a caller's own jit lies nowhere yet
-        if not isinstance(shards, jax.core.Tracer):
-            dev = next(iter(shards.devices())).id
-        # no named scope around the pad and the slice: two scopes cost a
-        # read a percent (PERF.md, PR 26); the trace knows the two programs
-        # as `jit__pad` and `jit_dynamic_slice`
-        if pallas and n % tile:
-            shards = jnp.pad(shards, ((0, 0), (0, (-n) % tile)))
-    device.note_kernel_shape(matrix_bytes, rows, cols, shards.shape[1], dev)
-    out = fn(shards)
-    if shards.shape[1] == n:
-        return out, 1
-    return out[:, :n], 2 if on_host else 3
+        if not isinstance(words, jax.core.Tracer):
+            dev = next(iter(words.devices())).id
+        # no named scope around the pad: scopes on the read path cost a
+        # read a percent (PERF.md); the trace knows it as `jit__pad`
+        w, step = words.shape[1], tile // 4
+        if pallas and w % step:
+            words = jnp.pad(words, ((0, 0), (0, (-w) % step)))
+            programs = 2
+    device.note_kernel_shape(matrix_bytes, rows, cols, 4 * words.shape[1], dev)
+    return fn(words), programs
 
 
 def gf_matmul_jax(matrix: np.ndarray, shards):
-    """`_enqueue`'s device result alone, for callers outside `ops/` (tests:
-    the codec and the pipelines go through `_dispatch`)."""
+    """`_enqueue`'s device result alone, uint32 words, for callers outside
+    `ops/` (tests: the codec and the pipelines go through `_dispatch`)."""
     return _enqueue(np.ascontiguousarray(matrix, dtype=np.uint8), shards)[0]
 
 
@@ -174,9 +183,9 @@ def _dispatch(matrix: np.ndarray, shards):
     """`_enqueue` as the codec calls it, with the host's seconds in the
     call counted under `dispatch` and the device programs it enqueued under
     `SeaweedFS_volume_ec_device_programs_total`: the kernel alone where
-    `shards` is a host array at a rung of the ladder (`_apply_jax`; the
-    program does the transfer) or a device array of tile-multiple width;
-    pad, kernel and slice where a device array's width is not."""
+    `shards` is a host array (`_apply_jax`; the program does the transfer)
+    or a device array of tile-multiple width; pad and kernel where a device
+    array's width is not."""
     with trace.phase("rs.dispatch", trace.EC_DEVICE_SECONDS, "dispatch"):
         out, programs = _enqueue(matrix, shards)
     trace.device_programs_counter().inc(programs)
@@ -313,9 +322,12 @@ class RSCodec:
     def apply2d_async(self, matrix: np.ndarray, data: np.ndarray):
         """data: C-contiguous (cols, n) uint8. Handle yields (rows, n)."""
         if self.backend == "jax":
+            n = data.shape[1]
+            if n % 4:  # the link carries words: a ragged width gets a tail
+                data = np.pad(data, ((0, 0), (0, -n % 4)))
             return _JaxHandle(
-                _dispatch(matrix, _device_put_2d(data, self.device)),
-                data.shape[1], early=True,
+                _dispatch(matrix, _device_put_2d(data.view(np.uint32), self.device)),
+                n, early=True,
             )
         if self.backend == "native":
             from seaweedfs_tpu.native import lib
@@ -333,12 +345,17 @@ class RSCodec:
         with row r's parity in columns [r*block, (r+1)*block) — i.e. exactly
         the bytes each parity shard file appends for those rows."""
         m = gf256.parity_rows(self.data_shards, self.parity_shards)
+        if self.backend == "jax" and block % 4:
+            # a block that words do not divide is transposed on the host
+            x = buf.reshape(row_count, self.data_shards, block).transpose(1, 0, 2)
+            return self.apply2d_async(m, np.ascontiguousarray(x).reshape(
+                self.data_shards, -1))
         if self.backend == "jax":
             jax = device.jax()
             jnp = jax.numpy
-            x = _device_put_1d(buf, self.device)
+            x = _device_put_1d(buf.view(np.uint32), self.device)
             with jax.named_scope("rs.rows_transpose"):
-                x = x.reshape(row_count, self.data_shards, block)
+                x = x.reshape(row_count, self.data_shards, block // 4)
                 x = jnp.transpose(x, (1, 0, 2)).reshape(self.data_shards, -1)
             return _JaxHandle(_dispatch(m, x), row_count * block, early=True)
         if self.backend == "native":
@@ -367,7 +384,9 @@ class _ReadyHandle:
 
 class _JaxHandle:
     def __init__(self, dev, n: int, early: bool = False) -> None:
-        self._dev = dev  # (rows, >= n): wider by the zero tail of a host input
+        # uint32 words whose row-major bytes are (rows, >= n) uint8: wider
+        # by the zero tail of the input
+        self._dev = dev
         self._n = n
         # `early`: the copy back is enqueued now, behind the program, for a
         # caller that drains later (the async pipeline API); otherwise it
@@ -377,12 +396,14 @@ class _JaxHandle:
             dev.copy_to_host_async()
 
     def result(self) -> np.ndarray:
-        """The host's copy, (rows, n): blocks until the device has drained
-        what was enqueued before it and the bytes have come back
-        (`d2h-wait`, whose bytes are the ones that crossed), then drops the
-        tail's columns, as a view. Counted once under
-        `SeaweedFS_volume_ec_device_copies_total{start}` by when the copy
-        began."""
+        """The host's copy, (rows, n) uint8: blocks until the device has
+        drained what was enqueued before it and the bytes have come back
+        (`d2h-wait`, whose bytes are the ones that crossed), then views the
+        words as bytes and drops the tail's columns, with no copy. Counted
+        once under `SeaweedFS_volume_ec_device_copies_total{start}` by when
+        the copy began, and under
+        `SeaweedFS_volume_ec_device_copy_bytes_total{side}` by the bytes the
+        result held on the device and the bytes that arrived."""
         with trace.phase(
             "rs.d2h_wait", trace.EC_DEVICE_SECONDS, "d2h-wait"
         ) as ph:
@@ -390,7 +411,10 @@ class _JaxHandle:
             ph.nbytes = out.nbytes
         trace.device_copies_counter().labels(
             "enqueue" if self._early else "wait").inc()
-        return out[:, : self._n]
+        copied = trace.device_copy_bytes_counter()
+        copied.labels("device").inc(self._dev.on_device_size_in_bytes())
+        copied.labels("host").inc(out.nbytes)
+        return out.view(np.uint8).reshape(out.shape[0], -1)[:, : self._n]
 
 
 # Host arrays above this size are put on the device in pieces of this size
@@ -404,13 +428,14 @@ def _device_put_1d(buf: np.ndarray, dev=None):
     jax = device.jax()
     jnp = jax.numpy
     flat = buf.reshape(-1)
+    step = H2D_CHUNK // flat.itemsize
     # `h2d`: the host's seconds in the puts and in dispatching the concat
     with trace.phase("rs.h2d", trace.EC_DEVICE_SECONDS, "h2d", flat.nbytes):
         if flat.nbytes <= H2D_CHUNK:
             return jax.device_put(flat, dev)
         pieces = [
-            jax.device_put(flat[i : i + H2D_CHUNK], dev)
-            for i in range(0, flat.nbytes, H2D_CHUNK)
+            jax.device_put(flat[i : i + step], dev)
+            for i in range(0, flat.size, step)
         ]
         with jax.named_scope("rs.h2d_concat"):
             return jnp.concatenate(pieces)
@@ -496,10 +521,10 @@ def _calibrate_pipeline_backend() -> dict:
         t0 = time.perf_counter()
         c.encode2d_async(sample).result()
         rates[name] = sample.nbytes / (time.perf_counter() - t0)
-    # the host->device half alone, as the pipeline puts it (warm: the jax
-    # candidate above already compiled the device concat)
+    # the host->device half alone, as the pipeline puts it: words (warm:
+    # the jax candidate above already compiled the device concat)
     t0 = time.perf_counter()
-    _device_put_2d(sample).block_until_ready()
+    _device_put_2d(sample.view(np.uint32)).block_until_ready()
     h2d_rate = sample.nbytes / (time.perf_counter() - t0)
     return {
         "backend": max(rates, key=rates.get),

@@ -1,10 +1,16 @@
 """Fused Pallas TPU kernel for GF(2^8) shard transforms.
 
-One grid step processes a (cols, TILE) byte block entirely in VMEM:
-unpack to bit planes (VPU) -> (8*rows, 8*cols)x(8*cols, TILE) int8 matmul
-(MXU) -> mod-2 + byte pack (VPU) -> (rows, TILE) output. The 8x bit
-expansion never touches HBM — that's the difference from the pure-jnp path
-in rs_kernel, where XLA materializes the bits tensor.
+One grid step processes a (cols, TILE) byte block entirely in VMEM, read as
+(cols, TILE/4) little-endian 32-bit words: byte p of every word is a
+problem of its own, (cols, TILE/4) bytes, since the transform works column
+by column. For each p: unpack to bit planes (VPU) -> (8*rows, 8*cols) x
+(8*cols, TILE/4) int8 matmul (MXU) -> mod-2 + byte pack (VPU); the four
+packed results are ORed back into words, stored as (rows, TILE/512, 128)
+— a dense 32-bit block whose row-major bytes are the (rows, TILE) result.
+The 8x bit expansion never touches HBM — that's the difference from the
+pure-jnp path in rs_kernel, where XLA materializes the bits tensor — and
+the result takes on the device just its own bytes, which the host sees as
+(rows, n) uint8 through a view.
 
 Bit-matrix row order here is (k, c) — plane-major — because the kernel
 builds the bit tensor by concatenating whole shifted planes along the
@@ -40,51 +46,60 @@ def _plane_major_bits(matrix_bytes: bytes, rows: int, cols: int) -> np.ndarray:
 @functools.lru_cache(maxsize=64)
 def compiled(rows: int, cols: int, matrix_bytes: bytes, tile: int):
     """The jitted kernel of one (rows, cols) coefficient matrix:
-    fn((cols, n) uint8) -> (rows, n) uint8, for n a multiple of `tile`."""
+    fn((cols, n/4) uint32) -> (rows, n/512, 128) uint32, the bytes of
+    (cols, n) and (rows, n) uint8 as little-endian words, for n a multiple
+    of `tile`."""
     jax = device.jax()
     jnp = jax.numpy
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     at_np = _plane_major_bits(matrix_bytes, rows, cols)
+    words = tile // 4  # a block's words a row
 
     def kernel(at_ref, x_ref, o_ref):
-        x = x_ref[:].astype(jnp.int32)  # (cols, tile)
-        planes = [((x >> k) & 1) for k in range(8)]
-        bits = jnp.concatenate(planes, axis=0).astype(jnp.int8)  # (8*cols, tile)
-        y = jax.lax.dot_general(
-            at_ref[:],
-            bits,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )  # (8*rows, tile)
-        yb = y & 1
-        out_rows = []
-        for r in range(rows):
-            acc = yb[r * 8]
-            for j in range(1, 8):
-                acc = acc | (yb[r * 8 + j] << j)
-            out_rows.append(acc.reshape(1, -1))
-        o_ref[:] = jnp.concatenate(out_rows, axis=0).astype(jnp.uint8)
+        x = x_ref[:].astype(jnp.int32)  # (cols, words)
+        packed = None
+        for p in range(4):  # byte p of each word
+            xp = (x >> (8 * p)) & 0xFF
+            planes = [((xp >> k) & 1) for k in range(8)]
+            bits = jnp.concatenate(planes, axis=0).astype(jnp.int8)  # (8*cols, words)
+            y = jax.lax.dot_general(
+                at_ref[:],
+                bits,
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.int32,
+            )  # (8*rows, words)
+            yb = y & 1
+            out_rows = []
+            for r in range(rows):
+                acc = yb[r * 8]
+                for j in range(1, 8):
+                    acc = acc | (yb[r * 8 + j] << j)
+                out_rows.append(acc.reshape(1, -1))
+            lane = jnp.concatenate(out_rows, axis=0) << (8 * p)
+            packed = lane if packed is None else packed | lane
+        o_ref[:] = packed.reshape(rows, words // 128, 128).astype(jnp.uint32)
 
     # one name for the jitted program and for the kernel inside it, so the
     # device trace calls them the same after any refactor
     @jax.jit
-    def rs_gf_matmul(x):  # (cols, n) with n % tile == 0
-        n = x.shape[1]
+    def rs_gf_matmul(x):  # (cols, n/4) with n % tile == 0
+        w = x.shape[1]
         return pl.pallas_call(
             kernel,
             name="rs_gf_matmul",
-            out_shape=jax.ShapeDtypeStruct((rows, n), jnp.uint8),
-            grid=(n // tile,),
+            out_shape=jax.ShapeDtypeStruct((rows, w // 128, 128), jnp.uint32),
+            grid=(w // words,),
             in_specs=[
                 pl.BlockSpec(
                     (rows * 8, cols * 8), lambda i: (0, 0), memory_space=pltpu.VMEM
                 ),
-                pl.BlockSpec((cols, tile), lambda i: (0, i), memory_space=pltpu.VMEM),
+                pl.BlockSpec((cols, words), lambda i: (0, i), memory_space=pltpu.VMEM),
             ],
             out_specs=pl.BlockSpec(
-                (rows, tile), lambda i: (0, i), memory_space=pltpu.VMEM
+                (rows, words // 128, 128), lambda i: (0, i, 0),
+                memory_space=pltpu.VMEM,
             ),
         )(jnp.asarray(at_np), x)
 

@@ -65,12 +65,17 @@ EC_ADMIN_OPS = (
 EC_DEVICE_SECONDS = "SeaweedFS_volume_ec_device_seconds"
 EC_DEVICE_KERNELS = ("h2d", "dispatch", "d2h-wait")
 # device programs those dispatches enqueued: 1 a call where the kernel runs
-# alone, 3 where a pad and a slice on the device go with it
+# alone, 2 where a pad on the device goes with it
 EC_DEVICE_PROGRAMS = "SeaweedFS_volume_ec_device_programs_total"
 # copies of a device result back to the host, by when each began: `enqueue`
 # with its program (the async pipeline API), `wait` in `result()`
 EC_DEVICE_COPIES = "SeaweedFS_volume_ec_device_copies_total"
 EC_DEVICE_COPY_STARTS = ("enqueue", "wait")
+# the bytes of those copies, once a copy each: `device` what the result held
+# on the device (`on_device_size_in_bytes()`, its tiles' padding included),
+# `host` what arrived
+EC_DEVICE_COPY_BYTES = "SeaweedFS_volume_ec_device_copy_bytes_total"
+EC_DEVICE_COPY_SIDES = ("device", "host")
 # bytes of EC read intervals by how each was served (EcVolume._read_interval)
 EC_READ_INTERVAL_BYTES = "SeaweedFS_volume_ec_read_interval_bytes_total"
 EC_READ_INTERVAL_SOURCES = ("local", "remote", "reconstruct")
@@ -526,6 +531,17 @@ def device_copies_counter():
         EC_DEVICE_COPIES,
         "device results copied back to the host, by when the copy began",
         ("start",))
+
+
+def device_copy_bytes_counter():
+    """`SeaweedFS_volume_ec_device_copy_bytes_total{side}`: one update of
+    each side a result the jax backend brought back to the host
+    (`rs_kernel._JaxHandle.result`): `device` the bytes the result held on
+    the device, `host` the bytes that arrived."""
+    return _plain_counter(
+        EC_DEVICE_COPY_BYTES,
+        "bytes of device results copied back to the host, on each side",
+        ("side",))
 
 
 def read_interval_bytes_counter():

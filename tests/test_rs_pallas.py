@@ -86,7 +86,7 @@ CASES = [
 def test_kernel_matches_numpy_oracle(pallas_door, matrix, n):
     rng = np.random.RandomState(n + matrix.shape[0] * 31 + matrix.shape[1])
     shards = rng.randint(0, 256, size=(matrix.shape[1], n), dtype=np.uint8)
-    got = np.asarray(rs_kernel.gf_matmul_jax(matrix, shards))
+    got = rs_kernel._JaxHandle(rs_kernel.gf_matmul_jax(matrix, shards), n).result()
     want = gf256.gf_matmul_bytes(matrix, shards)
     assert got.shape == want.shape and got.dtype == np.uint8
     assert np.array_equal(got, want)
@@ -103,17 +103,17 @@ def test_door_lengths_of_one_bucket_share_one_program(pallas_door):
     check_one_bucket_one_program(TILE + 40, 2 * TILE - 7)
 
 
-@pytest.mark.parametrize("form,programs", [("xla", 1), ("pallas", 3)])
+@pytest.mark.parametrize("form,programs", [("xla", 1), ("pallas", 2)])
 @pytest.mark.parametrize("entry", ["apply2d_async", "encode_rows_async"])
 def test_device_array_path_is_what_it_was(request, entry, form, programs):
-    """The pipelines put their bytes on the device themselves; a width that
-    is no tile multiple is then padded and sliced there, as before: pad,
-    kernel and slice in the Pallas form, the one program of any width in the
-    XLA form."""
+    """The pipelines put their bytes on the device themselves, as words; a
+    width that is no tile multiple is then padded there and its tail
+    dropped on the host: pad and kernel in the Pallas form, the one program
+    of any width in the XLA form."""
     if form == "pallas":
         request.getfixturevalue("pallas_door")
     codec = rs_kernel.RSCodec(backend="jax")
-    rows, block = 3, TILE // 2 + 11
+    rows, block = 3, TILE // 2 + 12
     rng = np.random.RandomState(block)
     buf = rng.randint(0, 256, size=rows * DATA * block, dtype=np.uint8)
     data = np.ascontiguousarray(

@@ -68,6 +68,14 @@ def _u8(shape, sharding):
     return jax.ShapeDtypeStruct(shape, jnp.uint8, sharding=sharding)
 
 
+def _u32(shape, sharding):
+    """Bytes as a device array reaches the door: uint32 words."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.ShapeDtypeStruct(shape, jnp.uint32, sharding=sharding)
+
+
 @pytest.fixture()
 def pallas_door(monkeypatch):
     """`ops.rs_kernel` with its door over the Pallas body, as on a TPU: here
@@ -88,7 +96,7 @@ PALLAS_CASES = [
     ("encode-4x10-device-batch", gf256.parity_rows(DATA, PARITY), DEVICE_BATCH),
     ("encode-4x10-last-batch", gf256.parity_rows(DATA, PARITY), 7 * MiB),
     ("encode-4x10-not-tile-multiple", gf256.parity_rows(DATA, PARITY),
-     MiB + 4321),
+     MiB + 4324),
     ("rebuild-1x10", _rebuild_matrix((3,)), DEVICE_BATCH),
     ("rebuild-2x10", _rebuild_matrix((3, 12)), DEVICE_BATCH),
     ("rebuild-3x10", _rebuild_matrix((0, 3, 12)), DEVICE_BATCH),
@@ -112,24 +120,28 @@ PALLAS_CASES = [
 def test_pallas_transform_compiles(one_chip, pallas_door, matrix, n):
     compiled = _compile(
         functools.partial(pallas_door.gf_matmul_jax, matrix),
-        _u8((matrix.shape[1], n), one_chip),
+        _u32((matrix.shape[1], n // 4), one_chip),
     )
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "%rs_gf_matmul" in text  # the name the device trace shows
+    # the result takes on the device just its own bytes, tail included
+    width = n + (-n) % pallas_door.TILE
+    assert compiled.memory_analysis().output_size_in_bytes == matrix.shape[0] * width
 
 
 def test_pipeline_encode_rows_compiles(one_chip, pallas_door):
     """The device half of RSCodec.encode_rows_async at the pipeline's batch:
-    32 rows of 10 x 1 MiB in .dat order -> reshape/transpose -> kernel."""
+    32 rows of 10 x 1 MiB in .dat order, as words -> reshape/transpose ->
+    kernel."""
     rows, block = DEVICE_BATCH // MiB, MiB
     m = gf256.parity_rows(DATA, PARITY)
 
     def device_half(flat):
-        x = flat.reshape(rows, DATA, block).transpose(1, 0, 2)
+        x = flat.reshape(rows, DATA, block // 4).transpose(1, 0, 2)
         return pallas_door.gf_matmul_jax(m, x.reshape(DATA, -1))
 
-    compiled = _compile(device_half, _u8((rows * DATA * block,), one_chip))
+    compiled = _compile(device_half, _u32((rows * DATA * block // 4,), one_chip))
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
@@ -142,7 +154,7 @@ def test_xla_transform_compiles(one_chip):
 
     m = gf256.parity_rows(DATA, PARITY)
     fn = rs_kernel._compiled_xla(PARITY, DATA, m.tobytes(), rs_kernel.TILE)
-    fn.lower(_u8((DATA, 4 * MiB), one_chip)).compile()
+    fn.lower(_u32((DATA, MiB), one_chip)).compile()
 
 
 @pytest.mark.parametrize("kernel", ["md5", "crc32c"])

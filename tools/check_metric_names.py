@@ -76,6 +76,7 @@ def collect() -> tuple[dict[str, str], list[str]]:
     trace._cpu_counter(trace.EC_DECODE_SECONDS)  # ..._decode_cpu_seconds_total
     trace.device_programs_counter()  # SeaweedFS_volume_ec_device_programs_total
     trace.device_copies_counter()  # SeaweedFS_volume_ec_device_copies_total
+    trace.device_copy_bytes_counter()  # ..._ec_device_copy_bytes_total
     trace.read_interval_bytes_counter()  # ..._ec_read_interval_bytes_total
     trace.pipeline_buffers_counter()  # ..._ec_pipeline_buffers_total
     trace.pipeline_cpu_counter()  # ..._ec_pipeline_cpu_seconds_total
@@ -722,7 +723,8 @@ def phase_label_violations() -> list[str]:
     SeaweedFS_volume_ec_admin_seconds (a handler, or `<handler>.<step>` with
     a declared handler), the `kernel` values of
     SeaweedFS_volume_ec_device_seconds, the `start` values of
-    SeaweedFS_volume_ec_device_copies_total and the `source` values of
+    SeaweedFS_volume_ec_device_copies_total, the `side` values of
+    SeaweedFS_volume_ec_device_copy_bytes_total and the `source` values of
     SeaweedFS_volume_ec_read_interval_bytes_total and of
     SeaweedFS_volume_ec_pipeline_buffers_total, the `verb` and `edge` values
     of SeaweedFS_volume_ec_pipeline_edge_seconds and the `stage` values of
@@ -741,6 +743,8 @@ def phase_label_violations() -> list[str]:
         ("ec device kernel", trace.EC_DEVICE_KERNELS, PHASE_KERNEL_RE,
          os.path.join("seaweedfs_tpu", "ops", "rs_kernel.py")),
         ("ec device copy start", trace.EC_DEVICE_COPY_STARTS, PHASE_KERNEL_RE,
+         os.path.join("seaweedfs_tpu", "ops", "rs_kernel.py")),
+        ("ec device copy side", trace.EC_DEVICE_COPY_SIDES, PHASE_KERNEL_RE,
          os.path.join("seaweedfs_tpu", "ops", "rs_kernel.py")),
         ("ec read interval source", trace.EC_READ_INTERVAL_SOURCES,
          PHASE_KERNEL_RE, os.path.join(
