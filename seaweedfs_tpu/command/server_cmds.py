@@ -22,6 +22,14 @@ def _load_security():
     return cfg
 
 
+def _pin_malloc() -> None:
+    """A process that serves volumes holds glibc's malloc thresholds fixed
+    (`util/malloc.py`): its reads allocate blocks of every size."""
+    from seaweedfs_tpu.util import malloc
+
+    malloc.pin_thresholds()
+
+
 def _wait_forever() -> int:
     stop = threading.Event()
 
@@ -189,6 +197,7 @@ def run_volume(args: list[str]) -> int:
     _add_faults_flag(p)
     opts = p.parse_args(args)
     _arm_faults(opts)
+    _pin_malloc()
     from seaweedfs_tpu.server.volume import VolumeServer
 
     sec = _load_security()
@@ -347,6 +356,7 @@ def run_server(args: list[str]) -> int:
     _add_qos_flag(p)
     opts = p.parse_args(args)
     _arm_faults(opts)
+    _pin_malloc()
 
     from seaweedfs_tpu.server.master import MasterServer
     from seaweedfs_tpu.server.volume import VolumeServer

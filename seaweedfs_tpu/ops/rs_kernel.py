@@ -120,7 +120,7 @@ def zero_tailed(rows, tile: int) -> np.ndarray:
     return np.frombuffer(buf, dtype=np.uint8).reshape(len(rows), width)
 
 
-def _enqueue(matrix: np.ndarray, shards):
+def _enqueue(matrix: np.ndarray, shards, n: int | None = None):
     """The door: out[r] = XOR_c matrix[r,c] x shards[c] enqueued on the
     device, and beside the device result the number of programs the call
     enqueued — 1 for the kernel, 1 more for a pad on the device.
@@ -142,7 +142,15 @@ def _enqueue(matrix: np.ndarray, shards):
     a tile multiple for the Pallas body, which takes nothing else; the XLA
     body takes any width. Nothing here names a device: a device array's
     program runs where the array lies (the puts below commit it there), a
-    host array's on jax's default device."""
+    host array's on jax's default device.
+
+    `n` is the caller's width in bytes where a zero tail was written before
+    the door (`_apply_jax`, a ragged `apply2d_async`); by default the
+    array's own. Each call counts the caller's `cols x n` bytes and the
+    zeros that brought them to the program's width under
+    `SeaweedFS_volume_ec_door_bytes_total{part}`, and itself under
+    `SeaweedFS_volume_ec_door_calls_total{tiles}` by the rung of that width
+    (`"wide"` beyond the ladder): three updates a call."""
     pallas = transform_kernel() == "pallas"
     tile = TILE
     rows, cols = matrix.shape
@@ -152,6 +160,7 @@ def _enqueue(matrix: np.ndarray, shards):
     dev = 0  # the default device's index: where a host array's program runs
     programs = 1
     if isinstance(shards, np.ndarray):
+        n = shards.shape[1] if n is None else n
         words = zero_tailed(shards, tile).view(np.uint32)
     else:
         jax = device.jax()
@@ -160,6 +169,7 @@ def _enqueue(matrix: np.ndarray, shards):
             raise TypeError(
                 f"a device array reaches the door as uint32 words, not {shards.dtype}")
         words = shards
+        n = 4 * words.shape[1] if n is None else n
         # an array inside a caller's own jit lies nowhere yet
         if not isinstance(words, jax.core.Tracer):
             dev = next(iter(words.devices())).id
@@ -169,7 +179,14 @@ def _enqueue(matrix: np.ndarray, shards):
         if pallas and w % step:
             words = jnp.pad(words, ((0, 0), (0, (-w) % step)))
             programs = 2
-    device.note_kernel_shape(matrix_bytes, rows, cols, 4 * words.shape[1], dev)
+    width = 4 * words.shape[1]
+    device.note_kernel_shape(matrix_bytes, rows, cols, width, dev)
+    door = trace.door_bytes_counter()
+    door.labels("payload").inc(cols * n)
+    door.labels("zero_tail").inc(cols * (width - n))
+    rung = ladder_width(width, tile) // tile
+    trace.door_calls_counter().labels(
+        str(rung) if rung <= LADDER_TILES[-1] else "wide").inc()
     return fn(words), programs
 
 
@@ -179,7 +196,7 @@ def gf_matmul_jax(matrix: np.ndarray, shards):
     return _enqueue(np.ascontiguousarray(matrix, dtype=np.uint8), shards)[0]
 
 
-def _dispatch(matrix: np.ndarray, shards):
+def _dispatch(matrix: np.ndarray, shards, n: int | None = None):
     """`_enqueue` as the codec calls it, with the host's seconds in the
     call counted under `dispatch` and the device programs it enqueued under
     `SeaweedFS_volume_ec_device_programs_total`: the kernel alone where
@@ -187,7 +204,7 @@ def _dispatch(matrix: np.ndarray, shards):
     or a device array of tile-multiple width; pad and kernel where a device
     array's width is not."""
     with trace.phase("rs.dispatch", trace.EC_DEVICE_SECONDS, "dispatch"):
-        out, programs = _enqueue(matrix, shards)
+        out, programs = _enqueue(matrix, shards, n)
     trace.device_programs_counter().inc(programs)
     return out
 
@@ -199,7 +216,7 @@ def _apply_jax(matrix: np.ndarray, rows) -> np.ndarray:
     and taken back on the host, so nothing compiles per length."""
     n = len(rows[0])
     rows = zero_tailed(rows, TILE)
-    return _JaxHandle(_dispatch(matrix, rows), n).result()
+    return _JaxHandle(_dispatch(matrix, rows, n), n).result()
 
 
 class RSCodec:
@@ -326,7 +343,7 @@ class RSCodec:
             if n % 4:  # the link carries words: a ragged width gets a tail
                 data = np.pad(data, ((0, 0), (0, -n % 4)))
             return _JaxHandle(
-                _dispatch(matrix, _device_put_2d(data.view(np.uint32), self.device)),
+                _dispatch(matrix, _device_put_2d(data.view(np.uint32), self.device), n),
                 n, early=True,
             )
         if self.backend == "native":

@@ -76,6 +76,17 @@ EC_DEVICE_COPY_STARTS = ("enqueue", "wait")
 # `host` what arrived
 EC_DEVICE_COPY_BYTES = "SeaweedFS_volume_ec_device_copy_bytes_total"
 EC_DEVICE_COPY_SIDES = ("device", "host")
+# what each call of the RS door (ops/rs_kernel._enqueue) carries: `payload`
+# the caller's cols x n bytes, `zero_tail` the zeros that bring them to the
+# program's width
+EC_DOOR_BYTES = "SeaweedFS_volume_ec_door_bytes_total"
+EC_DOOR_PARTS = ("payload", "zero_tail")
+# the door's calls, one each, by the program's width in tiles: a rung of
+# rs_kernel.LADDER_TILES (a width between rungs counts at the rung above
+# it), or `wide` beyond the ladder (the pipelines' batches)
+EC_DOOR_CALLS = "SeaweedFS_volume_ec_door_calls_total"
+EC_DOOR_TILES = ("1", "2", "3", "4", "5", "6", "7", "8", "9", "12", "16", "24",
+                 "32", "48", "64", "96", "128", "wide")
 # bytes of EC read intervals by how each was served (EcVolume._read_interval)
 EC_READ_INTERVAL_BYTES = "SeaweedFS_volume_ec_read_interval_bytes_total"
 EC_READ_INTERVAL_SOURCES = ("local", "remote", "reconstruct")
@@ -542,6 +553,27 @@ def device_copy_bytes_counter():
         EC_DEVICE_COPY_BYTES,
         "bytes of device results copied back to the host, on each side",
         ("side",))
+
+
+def door_bytes_counter():
+    """`SeaweedFS_volume_ec_door_bytes_total{part}`: one update of each part
+    a call of the RS door (`rs_kernel._enqueue`), `payload` the caller's
+    bytes, `zero_tail` the zeros added to reach the program's width."""
+    return _plain_counter(
+        EC_DOOR_BYTES,
+        "bytes the RS door carried to the kernel, the caller's and the zero"
+        " tail's",
+        ("part",))
+
+
+def door_calls_counter():
+    """`SeaweedFS_volume_ec_door_calls_total{tiles}`: one update a call of
+    the RS door, by the rung of the kernel's width ladder its program ran
+    at, or `wide`."""
+    return _plain_counter(
+        EC_DOOR_CALLS,
+        "calls of the RS door, by the program's width in tiles",
+        ("tiles",))
 
 
 def read_interval_bytes_counter():

@@ -77,6 +77,8 @@ def collect() -> tuple[dict[str, str], list[str]]:
     trace.device_programs_counter()  # SeaweedFS_volume_ec_device_programs_total
     trace.device_copies_counter()  # SeaweedFS_volume_ec_device_copies_total
     trace.device_copy_bytes_counter()  # ..._ec_device_copy_bytes_total
+    trace.door_bytes_counter()  # SeaweedFS_volume_ec_door_bytes_total
+    trace.door_calls_counter()  # SeaweedFS_volume_ec_door_calls_total
     trace.read_interval_bytes_counter()  # ..._ec_read_interval_bytes_total
     trace.pipeline_buffers_counter()  # ..._ec_pipeline_buffers_total
     trace.pipeline_cpu_counter()  # ..._ec_pipeline_cpu_seconds_total
@@ -724,7 +726,10 @@ def phase_label_violations() -> list[str]:
     a declared handler), the `kernel` values of
     SeaweedFS_volume_ec_device_seconds, the `start` values of
     SeaweedFS_volume_ec_device_copies_total, the `side` values of
-    SeaweedFS_volume_ec_device_copy_bytes_total and the `source` values of
+    SeaweedFS_volume_ec_device_copy_bytes_total, the `part` values of
+    SeaweedFS_volume_ec_door_bytes_total (and, in `door_tiles_violations`,
+    the `tiles` values of SeaweedFS_volume_ec_door_calls_total) and the
+    `source` values of
     SeaweedFS_volume_ec_read_interval_bytes_total and of
     SeaweedFS_volume_ec_pipeline_buffers_total, the `verb` and `edge` values
     of SeaweedFS_volume_ec_pipeline_edge_seconds and the `stage` values of
@@ -745,6 +750,8 @@ def phase_label_violations() -> list[str]:
         ("ec device copy start", trace.EC_DEVICE_COPY_STARTS, PHASE_KERNEL_RE,
          os.path.join("seaweedfs_tpu", "ops", "rs_kernel.py")),
         ("ec device copy side", trace.EC_DEVICE_COPY_SIDES, PHASE_KERNEL_RE,
+         os.path.join("seaweedfs_tpu", "ops", "rs_kernel.py")),
+        ("ec door part", trace.EC_DOOR_PARTS, PHASE_OP_RE,
          os.path.join("seaweedfs_tpu", "ops", "rs_kernel.py")),
         ("ec read interval source", trace.EC_READ_INTERVAL_SOURCES,
          PHASE_KERNEL_RE, os.path.join(
@@ -770,6 +777,30 @@ def phase_label_violations() -> list[str]:
             if f'"{name}"' not in src:
                 bad.append(f"{what} {name!r}: declared but {seam} never"
                            f" writes it")
+    bad += door_tiles_violations()
+    return bad
+
+
+def door_tiles_violations() -> list[str]:
+    """The `tiles` values of SeaweedFS_volume_ec_door_calls_total: the rungs
+    of `rs_kernel.LADDER_TILES`, in order, and `wide`, written by the door.
+    At most 18 values, so a new rung or width cannot grow the family's
+    series unseen."""
+    from seaweedfs_tpu.ops import rs_kernel
+    from seaweedfs_tpu.stats import trace
+
+    bad: list[str] = []
+    want = tuple(str(t) for t in rs_kernel.LADDER_TILES) + ("wide",)
+    if trace.EC_DOOR_TILES != want:
+        bad.append(f"ec door tiles {trace.EC_DOOR_TILES!r}: not the ladder's"
+                   f" rungs and 'wide' {want!r}")
+    if len(trace.EC_DOOR_TILES) > 18:
+        bad.append(f"ec door tiles: {len(trace.EC_DOOR_TILES)} values, more"
+                   " than 18")
+    with open(rs_kernel.__file__) as f:
+        if '"wide"' not in f.read():
+            bad.append("ec door tiles 'wide': declared but"
+                       " seaweedfs_tpu/ops/rs_kernel.py never writes it")
     return bad
 
 
