@@ -209,12 +209,14 @@ def _dispatch(matrix: np.ndarray, shards, n: int | None = None):
     return out
 
 
-def _apply_jax(matrix: np.ndarray, rows) -> np.ndarray:
+def _apply_jax(matrix: np.ndarray, rows, n: int | None = None) -> np.ndarray:
     """The transform of host bytes — a (cols, n) array or a sequence of cols
     (n,) arrays — as one device program and one copy back: the width is
     brought to a rung of the kernel's ladder (`LADDER_TILES`) on the host
-    and taken back on the host, so nothing compiles per length."""
-    n = len(rows[0])
+    and taken back on the host, so nothing compiles per length. `n`, where
+    given, is the caller's width in a (cols, `ladder_width(n)`) array whose
+    zero tail the caller wrote (`RSCodec.input_width`)."""
+    n = len(rows[0]) if n is None else n
     rows = zero_tailed(rows, TILE)
     return _JaxHandle(_dispatch(matrix, rows, n), n).result()
 
@@ -269,19 +271,34 @@ class RSCodec:
         return "native" if _native_lib() is not None else "numpy"
 
     # --- core ---------------------------------------------------------------
-    def apply_matrix(self, matrix: np.ndarray, shards: np.ndarray) -> np.ndarray:
+    def input_width(self, n: int) -> int:
+        """The width of the (cols, width) uint8 host array that
+        `apply_matrix(..., n)` takes as it is for rows of n bytes: a rung of
+        the kernel's ladder on the jax backend (`ladder_width`), n on the
+        others. A caller that fills such an array itself, zero beyond
+        column n, spares the door its copy (`EcVolume`'s survivor gather)."""
+        return ladder_width(n, TILE) if self.backend == "jax" else n
+
+    def apply_matrix(self, matrix: np.ndarray, shards: np.ndarray,
+                     n: int | None = None) -> np.ndarray:
         """Public arbitrary-matrix transform: out[r] = XOR_c matrix[r,c] x
         shards[c] on this codec's backend. The partial-sum repair path
         (erasure_coding/decoder.py) scales a holder's local shards with
-        exactly this call — the same kernel encode/reconstruct use."""
+        exactly this call — the same kernel encode/reconstruct use. `n`:
+        the rows' own width where `shards` is wider, zero beyond it
+        (`input_width`); the result is (rows, n) either way."""
         return self._apply(
             np.ascontiguousarray(matrix, dtype=np.uint8),
             np.ascontiguousarray(shards, dtype=np.uint8),
+            n,
         )
 
-    def _apply(self, matrix: np.ndarray, shards: np.ndarray) -> np.ndarray:
+    def _apply(self, matrix: np.ndarray, shards: np.ndarray,
+               n: int | None = None) -> np.ndarray:
         if self.backend == "jax":
-            return _apply_jax(matrix, shards)
+            return _apply_jax(matrix, shards, n)
+        if n is not None:
+            shards = shards[:, :n]
         if self.backend == "native":
             from seaweedfs_tpu.native import lib
 

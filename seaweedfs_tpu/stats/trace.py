@@ -90,6 +90,11 @@ EC_DOOR_TILES = ("1", "2", "3", "4", "5", "6", "7", "8", "9", "12", "16", "24",
 # bytes of EC read intervals by how each was served (EcVolume._read_interval)
 EC_READ_INTERVAL_BYTES = "SeaweedFS_volume_ec_read_interval_bytes_total"
 EC_READ_INTERVAL_SOURCES = ("local", "remote", "reconstruct")
+# reconstructs of an interval by the route that served it
+# (EcVolume._recover_interval): `gather` ten local survivors read in place,
+# `fanin` the remote partial fan-in, `ladder` the classic ladder
+EC_RECONSTRUCTS = "SeaweedFS_volume_ec_reconstructs_total"
+EC_RECONSTRUCT_ROUTES = ("gather", "fanin", "ladder")
 # one local device lent to one EC pipeline at a time (ops/device.lease):
 # `state` is `wait` (from the ask to the grant) or `held` (from the grant to
 # the return), `device` the index of the device that was granted
@@ -582,6 +587,15 @@ def read_interval_bytes_counter():
         EC_READ_INTERVAL_BYTES,
         "bytes of EC read intervals, by how the interval was served",
         ("source",))
+
+
+def reconstructs_counter():
+    """`SeaweedFS_volume_ec_reconstructs_total{via}`: one update a
+    reconstructed interval, by its route (`EC_RECONSTRUCT_ROUTES`)."""
+    return _plain_counter(
+        EC_RECONSTRUCTS,
+        "EC read intervals reconstructed, by the route that served them",
+        ("via",))
 
 
 def pipeline_buffers_counter():

@@ -17,6 +17,7 @@ import threading
 
 import numpy as np
 
+from seaweedfs_tpu.ops import gf256
 from seaweedfs_tpu.ops.rs_kernel import RSCodec
 from seaweedfs_tpu.stats import trace
 from seaweedfs_tpu.storage import idx as idx_mod
@@ -36,6 +37,7 @@ from . import encoder
 from .geometry import (
     DATA_SHARDS_COUNT,
     LARGE_BLOCK_SIZE,
+    PARITY_SHARDS_COUNT,
     SMALL_BLOCK_SIZE,
     TOTAL_SHARDS_COUNT,
     Interval,
@@ -201,6 +203,25 @@ class EcVolume:
         # needle CRC (or the scrubber's parity recompute) must catch it
         return _FP_SHARD_READ.mangle(data, volume=self.volume_id)
 
+    def _pread_row(self, shard_id: int, off: int, row: np.ndarray) -> bool:
+        """`_pread_shard` straight into `row`, a writable uint8 view of as
+        many bytes as the read wants: True where the shard filled it whole.
+        An armed `torn` seam reads as a short read, `corrupt` flips the
+        row's byte as it flips the payload's."""
+        try:
+            _FP_SHARD_READ.hit(volume=self.volume_id)
+        except (faults.FaultInjected, OSError):
+            return False
+        fd = self.shards.get(shard_id)
+        if fd is None or os.preadv(fd, [row], off) != len(row):
+            return False
+        if _FP_SHARD_READ.spec is not None:  # armed: the byte seams
+            data = _FP_SHARD_READ.mangle(row.tobytes(), volume=self.volume_id)
+            if len(data) != len(row):
+                return False
+            row[:] = np.frombuffer(data, dtype=np.uint8)
+        return True
+
     def _fetch_remote(self, shard_id: int, off: int, size: int) -> bytes | None:
         if self.shard_fetcher is None:
             return None
@@ -240,23 +261,27 @@ class EcVolume:
         return data
 
     def _recover_interval(self, missing_shard: int, off: int, size: int) -> bytes:
-        """Reconstruct one interval from >= 10 surviving shards, local first
-        then remote fan-in (`store_ec.go:339-395`
-        recoverOneRemoteEcShardInterval). When the server layer attached a
-        partial_fetcher, the remote fan-in moves one GF-scaled partial per
-        holder (~1x the interval per holder) instead of a full range per
-        shard (up to 10x) — byte-identical, any holder failing drops to
-        the classic ladder below."""
+        """Reconstruct one interval from >= 10 surviving shards
+        (`store_ec.go:339-395` recoverOneRemoteEcShardInterval, which reads
+        its local shards locally). Where ten survivors are on this node the
+        interval is decoded here from them (`_gather`); otherwise, when the
+        server layer attached a partial_fetcher, the remote fan-in moves one
+        GF-scaled partial per holder (~1x the interval per holder) instead
+        of a full range per shard (up to 10x) — byte-identical, any holder
+        failing drops to the classic ladder below. One update of
+        `SeaweedFS_volume_ec_reconstructs_total{via}` a reconstruct."""
+        survivors = len(self.shards) - (missing_shard in self.shards)
+        if survivors >= DATA_SHARDS_COUNT:
+            data = self._gather(missing_shard, off, size)
+            if data is not None:
+                return self._recovered(data, "gather", missing_shard)
         if self.partial_fetcher is not None:
             try:
                 data = self.partial_fetcher(missing_shard, off, size)
             except Exception:
                 data = None
             if data is not None and len(data) == size:
-                degraded_reads_counter().labels("ec_reconstruct").inc()
-                _emit_degraded(self.volume_id, missing_shard,
-                               "partial_fanin", self.collection)
-                return data
+                return self._recovered(data, "fanin", missing_shard)
         present: dict[int, np.ndarray] = {}
         for shard_id in self.shards:
             if shard_id == missing_shard:
@@ -282,10 +307,46 @@ class EcVolume:
                 f"cannot recover shard {missing_shard}: only {len(present)} present"
             )
         out = self.codec.reconstruct(present, targets=[missing_shard])
+        return self._recovered(out[missing_shard].tobytes(), "ladder",
+                               missing_shard)
+
+    def _gather(self, missing_shard: int, off: int, size: int) -> bytes | None:
+        """The interval decoded from the first ten local survivors in shard
+        order (the choice `gf256.decode_matrix` and
+        `decoder.repair_coefficients` make), each read straight into its row
+        of the codec's input at the width the codec takes as it is
+        (`RSCodec.input_width`), zero beyond `size`. A survivor that cannot
+        fill its row gives it to the next; None where fewer than ten can."""
+        width = self.codec.input_width(size)
+        rows = np.empty((DATA_SHARDS_COUNT, width), dtype=np.uint8)
+        if width > size:
+            # the tail through a memoryview, which keeps the interpreter
+            # lock: numpy's fill would give it up (see `zero_tailed`)
+            flat, zeros = memoryview(rows.reshape(-1)), bytes(width - size)
+            for i in range(DATA_SHARDS_COUNT):
+                flat[i * width + size:(i + 1) * width] = zeros
+        use: list[int] = []
+        for shard_id in sorted(self.shards):
+            if shard_id == missing_shard:
+                continue
+            if self._pread_row(shard_id, off, rows[len(use), :size]):
+                use.append(shard_id)
+                if len(use) == DATA_SHARDS_COUNT:
+                    break
+        if len(use) < DATA_SHARDS_COUNT:
+            return None
+        matrix = gf256.decode_matrix(DATA_SHARDS_COUNT, PARITY_SHARDS_COUNT,
+                                     tuple(use), (missing_shard,))
+        return self.codec.apply_matrix(matrix, rows, size)[0].tobytes()
+
+    def _recovered(self, data: bytes, via: str, missing_shard: int) -> bytes:
+        """Count and journal one reconstructed interval, and hand it back."""
+        trace.reconstructs_counter().labels(via).inc()
         degraded_reads_counter().labels("ec_reconstruct").inc()
-        _emit_degraded(self.volume_id, missing_shard, "full_decode",
+        _emit_degraded(self.volume_id, missing_shard,
+                       "partial_fanin" if via == "fanin" else "full_decode",
                        self.collection)
-        return out[missing_shard].tobytes()
+        return data
 
     def read_needle(self, needle_id: int, cookie: int | None = None) -> Needle:
         offset, size = self.find_needle_from_ecx(needle_id)

@@ -680,6 +680,11 @@ class TestClusterWhy:
             # sustained degraded reads (rate rule: > 0.5/s over 60s)
             alerts_mod.engine().configure(degraded_read_rate=0.01)
             hist = history_mod.default_history()
+            # earlier tests in this process may have left degraded reads
+            # in the ring, and so the alert firing with no edge left to
+            # see: start from an empty ring, as a fresh process does (the
+            # finally below clears it the same way)
+            hist.clear()
             # baseline scrape FIRST: a brand-new counter series only
             # zero-seeds (and thus rates from its first sample) when a
             # previous scrape exists — in a live system the 5s loop

@@ -80,6 +80,7 @@ def collect() -> tuple[dict[str, str], list[str]]:
     trace.door_bytes_counter()  # SeaweedFS_volume_ec_door_bytes_total
     trace.door_calls_counter()  # SeaweedFS_volume_ec_door_calls_total
     trace.read_interval_bytes_counter()  # ..._ec_read_interval_bytes_total
+    trace.reconstructs_counter()  # SeaweedFS_volume_ec_reconstructs_total
     trace.pipeline_buffers_counter()  # ..._ec_pipeline_buffers_total
     trace.pipeline_cpu_counter()  # ..._ec_pipeline_cpu_seconds_total
     ec_encoder._pipeline_hist()  # SeaweedFS_volume_ec_pipeline_seconds
@@ -730,7 +731,8 @@ def phase_label_violations() -> list[str]:
     SeaweedFS_volume_ec_door_bytes_total (and, in `door_tiles_violations`,
     the `tiles` values of SeaweedFS_volume_ec_door_calls_total) and the
     `source` values of
-    SeaweedFS_volume_ec_read_interval_bytes_total and of
+    SeaweedFS_volume_ec_read_interval_bytes_total, the `via` values of
+    SeaweedFS_volume_ec_reconstructs_total, the `source` values of
     SeaweedFS_volume_ec_pipeline_buffers_total, the `verb` and `edge` values
     of SeaweedFS_volume_ec_pipeline_edge_seconds and the `stage` values of
     SeaweedFS_volume_ec_pipeline_cpu_seconds_total — unique, well-formed,
@@ -754,6 +756,9 @@ def phase_label_violations() -> list[str]:
         ("ec door part", trace.EC_DOOR_PARTS, PHASE_OP_RE,
          os.path.join("seaweedfs_tpu", "ops", "rs_kernel.py")),
         ("ec read interval source", trace.EC_READ_INTERVAL_SOURCES,
+         PHASE_KERNEL_RE, os.path.join(
+             "seaweedfs_tpu", "storage", "erasure_coding", "ec_volume.py")),
+        ("ec reconstruct route", trace.EC_RECONSTRUCT_ROUTES,
          PHASE_KERNEL_RE, os.path.join(
              "seaweedfs_tpu", "storage", "erasure_coding", "ec_volume.py")),
         ("ec pipeline buffer source", trace.EC_PIPELINE_BUFFER_SOURCES,
