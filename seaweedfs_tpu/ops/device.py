@@ -11,8 +11,9 @@ threshold is dropped to zero.
 The module also holds what a process knows about its device side, for
 `GET /status` on the volume server (read by `benchmark/run.py` and the tests):
 the devices jax sees (only once jax has been started), the compile
-counters, how many kernel shapes the RS transform has run, and every
-failure that made a backend selection skip a candidate.
+counters, how many kernel shapes the RS transform has run and how many
+coefficient matrices it holds on devices, and every failure that made a
+backend selection skip a candidate.
 
 A process may have several local devices. An EC pipeline that builds its own
 codec borrows one for as long as it runs (`lease()`): one pipeline a device
@@ -42,11 +43,16 @@ _jax = None
 _cache: dict = {}
 _compiles = {"requests": 0, "seconds": 0.0, "cache_hits": 0}
 _selection_failures: dict[str, str] = {}
-# (coefficient matrix, rows, cols, width, device) of every call the RS
-# transform has made of its jitted programs. The bit matrix is baked into the
-# program and a program is built per device, so each is one program built (or
-# taken from the compile cache)
-_kernel_shapes: set[tuple[bytes, int, int, int, int]] = set()
+# (rows, cols, width, device) of every call the RS transform has made of its
+# jitted programs. The coefficient matrix is the program's operand and a
+# program is built per device, so each is one program built (or taken from
+# the compile cache)
+_kernel_shapes: set[tuple[int, int, int, int]] = set()
+# the coefficient matrices the RS door holds on devices as its programs'
+# operand: {(matrix bytes, body, device index): device array}, put there by
+# `ops/rs_kernel._operand`; the oldest is let go beyond MATRICES_HELD
+MATRICES_HELD = 256
+matrices: dict = {}
 _leases: "Leases | None" = None
 
 
@@ -127,12 +133,20 @@ def note_selection_failure(where: str, exc: BaseException) -> None:
         glog.warning("backend selection: %s failed: %s", where, cause)
 
 
-def note_kernel_shape(matrix: bytes, rows: int, cols: int, width: int,
-                      dev: int) -> None:
-    """The RS transform is about to run the program of its (rows, cols)
-    coefficient matrix at `width` on the device of index `dev` (the door,
+def note_kernel_shape(rows: int, cols: int, width: int, dev: int) -> None:
+    """The RS transform is about to run its program for (rows, cols)
+    coefficient matrices at `width` on the device of index `dev` (the door,
     `ops/rs_kernel._enqueue`): a set insert, on every call."""
-    _kernel_shapes.add((matrix, rows, cols, width, dev))
+    _kernel_shapes.add((rows, cols, width, dev))
+
+
+def hold_matrix(key: tuple, arr) -> None:
+    """Keep one matrix's device copy under `key` in `matrices`, letting the
+    oldest go where MATRICES_HELD are held already. The caller holds its
+    own lock around the check and the put (`rs_kernel._operand`)."""
+    if len(matrices) >= MATRICES_HELD:
+        matrices.pop(next(iter(matrices)), None)
+    matrices[key] = arr
 
 
 class Leases:
@@ -237,4 +251,5 @@ def report() -> dict:
         "seconds": round(compiles["seconds"], 3),
     }
     out["kernel_shapes"] = len(_kernel_shapes)
+    out["matrices"] = len(matrices)
     return out

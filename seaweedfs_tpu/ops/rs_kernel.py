@@ -42,7 +42,8 @@ TILE = 8192
 # SMALL_BLOCK_SIZE) reach the kernel at: every tile multiple up to nine (a
 # 64 KiB needle's record and below), then a step of a third or a half, so the
 # zero tail stays under a third of what crosses the link. A degraded read of
-# any length thus compiles at most these 17 programs per coefficient matrix.
+# any length thus compiles at most these 17 programs per (rows, cols), whatever
+# the coefficient matrix: the program takes the matrix as an operand.
 LADDER_TILES = (1, 2, 3, 4, 5, 6, 7, 8, 9, 12, 16, 24, 32, 48, 64, 96, 128)
 
 
@@ -54,19 +55,18 @@ def transform_kernel() -> str:
 
 
 @functools.lru_cache(maxsize=64)
-def _compiled_xla(rows: int, cols: int, matrix_bytes: bytes, tile: int):
-    """The jitted XLA form of one (rows, cols) coefficient matrix:
-    fn((cols, w) uint32) -> (rows, w) uint32, the bytes of (cols, 4w) and
-    (rows, 4w) uint8 as little-endian words, for any w (`tile` is the Pallas
-    body's business: `rs_pallas.compiled` takes and returns the same bytes,
-    its result shaped (rows, w/128, 128))."""
+def _compiled_xla(rows: int, cols: int):
+    """The jitted XLA form for every (rows, cols) coefficient matrix:
+    fn(a, (cols, w) uint32) -> (rows, w) uint32, `a` the matrix's
+    `gf256.bit_matrix` as int8 (cols*8, rows*8) and the words the bytes of
+    (cols, 4w) and (rows, 4w) uint8 as little-endian words, for any w
+    (`rs_pallas.compiled` takes and returns the same bytes, its operand
+    plane-major and its result shaped (rows, w/128, 128))."""
     jax = device.jax()
     jnp = jax.numpy
-    m = np.frombuffer(matrix_bytes, dtype=np.uint8).reshape(rows, cols)
-    a = jnp.asarray(gf256.bit_matrix(m), dtype=jnp.int8)  # (cols*8, rows*8)
 
     @jax.jit
-    def transform(words):  # (cols, w) uint32
+    def transform(a, words):  # (cols*8, rows*8) int8; (cols, w) uint32
         w = words.shape[1]
         k = jnp.arange(8, dtype=jnp.uint32)
         out = jnp.zeros((rows, w), jnp.uint32)
@@ -120,6 +120,45 @@ def zero_tailed(rows, tile: int) -> np.ndarray:
     return np.frombuffer(buf, dtype=np.uint8).reshape(len(rows), width)
 
 
+# held around a matrix's first put to a device, so it is put once
+_OPERAND_LOCK = threading.Lock()
+
+
+def _bits(matrix: np.ndarray, body: str) -> np.ndarray:
+    """The coefficient matrix as the body's program takes it: int8,
+    `rs_pallas.plane_major_bits` for "pallas", else `gf256.bit_matrix`."""
+    if body == "pallas":
+        return rs_pallas.plane_major_bits(matrix)
+    return gf256.bit_matrix(matrix).astype(np.int8)
+
+
+def _operand(matrix: np.ndarray, body: str, words, dev: int):
+    """`_bits(matrix, body)` where the program runs: put once on the device
+    of `words` (the default device for a host array), kept in
+    `device.matrices` by (matrix bytes, body, device index) and taken from
+    there by every later call. Each put is counted under
+    `SeaweedFS_volume_ec_door_matrix_puts_total{device}` and timed as phase
+    `rs.matrix_put`; a call that finds its matrix there updates nothing.
+    Inside a caller's own jit (`words` a tracer) the host array is returned,
+    a constant of the caller's program."""
+    jax = device.jax()
+    if isinstance(words, jax.core.Tracer):
+        return _bits(matrix, body)
+    key = (matrix.tobytes(), body, dev)
+    held = device.matrices.get(key)
+    if held is not None:
+        return held
+    target = None if isinstance(words, np.ndarray) else next(iter(words.devices()))
+    with _OPERAND_LOCK:
+        held = device.matrices.get(key)
+        if held is None:
+            with trace.phase("rs.matrix_put", trace.EC_DEVICE_SECONDS, "matrix-put"):
+                held = jax.device_put(_bits(matrix, body), target)
+            trace.door_matrix_puts_counter().labels(str(dev)).inc()
+            device.hold_matrix(key, held)
+    return held
+
+
 def _enqueue(matrix: np.ndarray, shards, n: int | None = None):
     """The door: out[r] = XOR_c matrix[r,c] x shards[c] enqueued on the
     device, and beside the device result the number of programs the call
@@ -132,6 +171,8 @@ def _enqueue(matrix: np.ndarray, shards, n: int | None = None):
     uint32 words too, (rows, >= n/4 words) in some shape whose row-major
     bytes are the (rows, >= n) uint8 result (`_JaxHandle` views it so):
     dense on the device, so a copy back carries the result's own bytes.
+    The program is one per (rows, cols) and width on a device; the matrix
+    is its operand (`_operand`), put on that device once.
 
     The zero tail is written where the bytes are (zero bytes transform to
     zero bytes, so the result is exact, and the host drops it). A host
@@ -151,12 +192,11 @@ def _enqueue(matrix: np.ndarray, shards, n: int | None = None):
     `SeaweedFS_volume_ec_door_bytes_total{part}`, and itself under
     `SeaweedFS_volume_ec_door_calls_total{tiles}` by the rung of that width
     (`"wide"` beyond the ladder): three updates a call."""
-    pallas = transform_kernel() == "pallas"
+    body = transform_kernel()
+    pallas = body == "pallas"
     tile = TILE
     rows, cols = matrix.shape
-    matrix_bytes = matrix.tobytes()
-    fn = (rs_pallas.compiled if pallas else _compiled_xla)(
-        rows, cols, matrix_bytes, tile)
+    fn = rs_pallas.compiled(rows, cols, tile) if pallas else _compiled_xla(rows, cols)
     dev = 0  # the default device's index: where a host array's program runs
     programs = 1
     if isinstance(shards, np.ndarray):
@@ -180,14 +220,14 @@ def _enqueue(matrix: np.ndarray, shards, n: int | None = None):
             words = jnp.pad(words, ((0, 0), (0, (-w) % step)))
             programs = 2
     width = 4 * words.shape[1]
-    device.note_kernel_shape(matrix_bytes, rows, cols, width, dev)
+    device.note_kernel_shape(rows, cols, width, dev)
     door = trace.door_bytes_counter()
     door.labels("payload").inc(cols * n)
     door.labels("zero_tail").inc(cols * (width - n))
     rung = ladder_width(width, tile) // tile
     trace.door_calls_counter().labels(
         str(rung) if rung <= LADDER_TILES[-1] else "wide").inc()
-    return fn(words), programs
+    return fn(_operand(matrix, body, words, dev), words), programs
 
 
 def gf_matmul_jax(matrix: np.ndarray, shards):

@@ -18,9 +18,11 @@ sublane axis (cheap block moves); gf256.bit_matrix's (c, k) order is
 permuted accordingly on the host.
 
 Works for any coefficient matrix (parity rows for encode, inverted
-sub-matrix rows for reconstruct/decode). The body only: at which width
-bytes reach it, on which side of the transfer a zero tail is written and
-when this form runs at all are the door's business (`ops/rs_kernel.py`).
+sub-matrix rows for reconstruct/decode), which the program takes as an
+operand: one program per (rows, cols) and width serves every matrix. The
+body only: at which width bytes reach it, where the matrix's device copy
+comes from, on which side of the transfer a zero tail is written and when
+this form runs at all are the door's business (`ops/rs_kernel.py`).
 """
 
 from __future__ import annotations
@@ -32,9 +34,10 @@ import numpy as np
 from . import device, gf256
 
 
-def _plane_major_bits(matrix_bytes: bytes, rows: int, cols: int) -> np.ndarray:
-    """(8*rows, 8*cols) int8: AT[o, k*cols + c] with o = output bit index."""
-    m = np.frombuffer(matrix_bytes, dtype=np.uint8).reshape(rows, cols)
+def plane_major_bits(m: np.ndarray) -> np.ndarray:
+    """The operand of a (rows, cols) uint8 coefficient matrix: (8*rows,
+    8*cols) int8, AT[o, k*cols + c] with o = output bit index."""
+    rows, cols = m.shape
     a = gf256.bit_matrix(m)  # (cols*8, rows*8), rows ordered (c, k)
     a2 = np.zeros_like(a)
     for c in range(cols):
@@ -44,17 +47,16 @@ def _plane_major_bits(matrix_bytes: bytes, rows: int, cols: int) -> np.ndarray:
 
 
 @functools.lru_cache(maxsize=64)
-def compiled(rows: int, cols: int, matrix_bytes: bytes, tile: int):
-    """The jitted kernel of one (rows, cols) coefficient matrix:
-    fn((cols, n/4) uint32) -> (rows, n/512, 128) uint32, the bytes of
-    (cols, n) and (rows, n) uint8 as little-endian words, for n a multiple
-    of `tile`."""
+def compiled(rows: int, cols: int, tile: int):
+    """The jitted kernel of every (rows, cols) coefficient matrix:
+    fn(at, (cols, n/4) uint32) -> (rows, n/512, 128) uint32, `at` the
+    matrix's `plane_major_bits` and the words the bytes of (cols, n) and
+    (rows, n) uint8 as little-endian words, for n a multiple of `tile`."""
     jax = device.jax()
     jnp = jax.numpy
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    at_np = _plane_major_bits(matrix_bytes, rows, cols)
     words = tile // 4  # a block's words a row
 
     def kernel(at_ref, x_ref, o_ref):
@@ -84,7 +86,7 @@ def compiled(rows: int, cols: int, matrix_bytes: bytes, tile: int):
     # one name for the jitted program and for the kernel inside it, so the
     # device trace calls them the same after any refactor
     @jax.jit
-    def rs_gf_matmul(x):  # (cols, n/4) with n % tile == 0
+    def rs_gf_matmul(at, x):  # (8*rows, 8*cols) int8; (cols, n/4), n % tile == 0
         w = x.shape[1]
         return pl.pallas_call(
             kernel,
@@ -101,6 +103,6 @@ def compiled(rows: int, cols: int, matrix_bytes: bytes, tile: int):
                 (rows, words // 128, 128), lambda i: (0, i, 0),
                 memory_space=pltpu.VMEM,
             ),
-        )(jnp.asarray(at_np), x)
+        )(at, x)
 
     return rs_gf_matmul

@@ -63,7 +63,9 @@ EC_ADMIN_OPS = (
 )
 # the host's side of the jax backend's transfers and dispatch (ops/rs_kernel)
 EC_DEVICE_SECONDS = "SeaweedFS_volume_ec_device_seconds"
-EC_DEVICE_KERNELS = ("h2d", "dispatch", "d2h-wait")
+# `matrix-put`: a coefficient matrix's first put to a device (the door's
+# operand cache), nested in the `dispatch` that needed it
+EC_DEVICE_KERNELS = ("h2d", "dispatch", "d2h-wait", "matrix-put")
 # device programs those dispatches enqueued: 1 a call where the kernel runs
 # alone, 2 where a pad on the device goes with it
 EC_DEVICE_PROGRAMS = "SeaweedFS_volume_ec_device_programs_total"
@@ -87,6 +89,10 @@ EC_DOOR_PARTS = ("payload", "zero_tail")
 EC_DOOR_CALLS = "SeaweedFS_volume_ec_door_calls_total"
 EC_DOOR_TILES = ("1", "2", "3", "4", "5", "6", "7", "8", "9", "12", "16", "24",
                  "32", "48", "64", "96", "128", "wide")
+# coefficient matrices the RS door put on a device as its programs' operand,
+# by the device's index: one update a put, none a call that finds its
+# matrix there already (ops/rs_kernel._operand)
+EC_DOOR_MATRIX_PUTS = "SeaweedFS_volume_ec_door_matrix_puts_total"
 # bytes of EC read intervals by how each was served (EcVolume._read_interval)
 EC_READ_INTERVAL_BYTES = "SeaweedFS_volume_ec_read_interval_bytes_total"
 EC_READ_INTERVAL_SOURCES = ("local", "remote", "reconstruct")
@@ -95,6 +101,9 @@ EC_READ_INTERVAL_SOURCES = ("local", "remote", "reconstruct")
 # `fanin` the remote partial fan-in, `ladder` the classic ladder
 EC_RECONSTRUCTS = "SeaweedFS_volume_ec_reconstructs_total"
 EC_RECONSTRUCT_ROUTES = ("gather", "fanin", "ladder")
+# needle reads of a sealed volume that reconstructed at least one interval
+# (EcVolume.read_needle): one update a read, whatever the intervals it rebuilt
+EC_DEGRADED_NEEDLE_READS = "SeaweedFS_volume_ec_degraded_needle_reads_total"
 # one local device lent to one EC pipeline at a time (ops/device.lease):
 # `state` is `wait` (from the ask to the grant) or `held` (from the grant to
 # the return), `device` the index of the device that was granted
@@ -581,6 +590,15 @@ def door_calls_counter():
         ("tiles",))
 
 
+def door_matrix_puts_counter():
+    """`SeaweedFS_volume_ec_door_matrix_puts_total{device}`: one update a
+    coefficient matrix put on a device as the RS door's operand."""
+    return _plain_counter(
+        EC_DOOR_MATRIX_PUTS,
+        "coefficient matrices the RS door put on a device, by its index",
+        ("device",))
+
+
 def read_interval_bytes_counter():
     """`SeaweedFS_volume_ec_read_interval_bytes_total{source}`."""
     return _plain_counter(
@@ -596,6 +614,14 @@ def reconstructs_counter():
         EC_RECONSTRUCTS,
         "EC read intervals reconstructed, by the route that served them",
         ("via",))
+
+
+def degraded_needle_reads_counter():
+    """`SeaweedFS_volume_ec_degraded_needle_reads_total`: one update a needle
+    read of a sealed volume that reconstructed at least one interval."""
+    return _plain_counter(
+        EC_DEGRADED_NEEDLE_READS,
+        "EC needle reads that reconstructed at least one interval")
 
 
 def pipeline_buffers_counter():

@@ -234,9 +234,13 @@ class EcVolume:
         return data
 
     def _read_interval(self, interval: Interval) -> bytes:
+        return self._serve_interval(interval)[0]
+
+    def _serve_interval(self, interval: Interval) -> tuple[bytes, bool]:
         """local shard -> remote shard -> reconstruct, the `store_ec.go`
-        readOneEcShardInterval ladder. The interval's bytes are counted
-        under the rung that served it: one counter update an interval."""
+        readOneEcShardInterval ladder: the interval's bytes, and whether they
+        were reconstructed. The bytes are counted under the rung that served
+        them: one counter update an interval."""
         shard_id, off = interval.to_shard_id_and_offset(
             self.large_block_size, self.small_block_size
         )
@@ -244,11 +248,11 @@ class EcVolume:
         data = self._pread_shard(shard_id, off, interval.size)
         if data is not None:
             served.labels("local").inc(interval.size)
-            return data
+            return data, False
         data = self._fetch_remote(shard_id, off, interval.size)
         if data is not None:
             served.labels("remote").inc(interval.size)
-            return data
+            return data, False
         # wall and this thread's CPU seconds of the reconstruction, and how
         # many bytes, under the kernel that reconstructs degraded reads here
         with trace.phase(
@@ -258,7 +262,7 @@ class EcVolume:
             data = self._recover_interval(shard_id, off, interval.size)
             ph.kernel = "reconstruct-" + self.codec.kernel_label
         served.labels("reconstruct").inc(interval.size)
-        return data
+        return data, True
 
     def _recover_interval(self, missing_shard: int, off: int, size: int) -> bytes:
         """Reconstruct one interval from >= 10 surviving shards
@@ -349,12 +353,17 @@ class EcVolume:
         return data
 
     def read_needle(self, needle_id: int, cookie: int | None = None) -> Needle:
+        """The needle's record from its intervals (`_serve_interval`); a
+        read that reconstructed any of them counts once under
+        `SeaweedFS_volume_ec_degraded_needle_reads_total`."""
         offset, size = self.find_needle_from_ecx(needle_id)
         if size_is_deleted(size):
             raise NeedleNotFound(f"needle {needle_id:x} deleted")
-        blob = b"".join(
-            self._read_interval(iv) for iv in self.locate_intervals(offset, size)
-        )
+        served = [self._serve_interval(iv)
+                  for iv in self.locate_intervals(offset, size)]
+        if any(rebuilt for _, rebuilt in served):
+            trace.degraded_needle_reads_counter().inc()
+        blob = b"".join(data for data, _ in served)
         n = Needle.from_bytes(blob, size=size, version=self.version)
         if cookie is not None and n.cookie != cookie:
             raise NeedleNotFound("cookie mismatch")

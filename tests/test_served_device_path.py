@@ -186,6 +186,9 @@ def test_status_says_where_the_bytes_ran(served):
     ec = served["status"]
     assert ec["pipeline"] == {"backend": "jax", "chosen_by": "override"}
     assert ec["jax"]["platform"] == device.platform() == "cpu"
-    # the encode's program, the rebuild's and a rung per reconstructed width
+    # a program a (rows, cols, width, device): the encode's (4 x 10), the
+    # rebuild's (2 x 10) and a rung per reconstructed width (1 x 10); each
+    # with its matrices put on the device as the operand
     assert ec["kernel_shapes"] >= 3
+    assert ec["matrices"] >= 3
     assert ec["selection_failures"] == served["failures_before"]

@@ -130,6 +130,23 @@ def test_pallas_transform_compiles(one_chip, pallas_door, matrix, n):
     assert compiled.memory_analysis().output_size_in_bytes == matrix.shape[0] * width
 
 
+@pytest.mark.parametrize("rows,tiles", [(1, 1), (3, 9), (4, 128)])
+def test_pallas_program_takes_the_matrix_as_an_operand(one_chip, rows, tiles):
+    """The served door's own program: the bit matrix an int8 operand on the
+    device beside the words, one program for every matrix of its shape."""
+    import jax
+    import jax.numpy as jnp
+
+    from seaweedfs_tpu.ops import rs_kernel, rs_pallas
+
+    tile = rs_kernel.TILE
+    at = jax.ShapeDtypeStruct((8 * rows, 8 * DATA), jnp.int8, sharding=one_chip)
+    compiled = rs_pallas.compiled(rows, DATA, tile).lower(
+        at, _u32((DATA, tiles * tile // 4), one_chip)).compile()
+    assert "%rs_gf_matmul" in compiled.as_text()
+    assert compiled.memory_analysis().output_size_in_bytes == rows * tiles * tile
+
+
 def test_pipeline_encode_rows_compiles(one_chip, pallas_door):
     """The device half of RSCodec.encode_rows_async at the pipeline's batch:
     32 rows of 10 x 1 MiB in .dat order, as words -> reshape/transpose ->
@@ -152,9 +169,12 @@ def test_pipeline_encode_rows_compiles(one_chip, pallas_door):
 def test_xla_transform_compiles(one_chip):
     from seaweedfs_tpu.ops import rs_kernel
 
-    m = gf256.parity_rows(DATA, PARITY)
-    fn = rs_kernel._compiled_xla(PARITY, DATA, m.tobytes(), rs_kernel.TILE)
-    fn.lower(_u32((DATA, MiB), one_chip)).compile()
+    import jax
+    import jax.numpy as jnp
+
+    fn = rs_kernel._compiled_xla(PARITY, DATA)
+    a = jax.ShapeDtypeStruct((DATA * 8, PARITY * 8), jnp.int8, sharding=one_chip)
+    fn.lower(a, _u32((DATA, MiB), one_chip)).compile()
 
 
 @pytest.mark.parametrize("kernel", ["md5", "crc32c"])

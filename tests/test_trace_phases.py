@@ -382,10 +382,17 @@ def test_status_has_memory_exactly_when_jax_is_started_and_reports_it(
 
 def test_status_counts_the_kernel_shapes_built(sealed):
     """`ec.kernel_shapes`: the programs the RS transform has run since boot,
-    one per coefficient matrix and width. Here at least the pipeline's encode
-    and one reconstruct of the dropped shard; six 30 KB blobs reach the
-    kernel at a handful of rungs, never one per length."""
-    from seaweedfs_tpu.ops import device
+    one per (rows, cols, width, device), whatever the coefficient matrix:
+    here at least the pipeline's encode, 4 x 10 at one batch's width (the
+    CPU's degraded reads take the native codec). `ec.matrices`: the
+    matrices held on devices as the programs' operand, the encode's parity
+    rows among them, in the XLA form on device 0."""
+    from seaweedfs_tpu.ops import device, gf256
 
-    shapes = sealed["status"]["ec"]["kernel_shapes"]
-    assert isinstance(shapes, int) and 2 <= shapes <= len(device._kernel_shapes)
+    ec = sealed["status"]["ec"]
+    shapes = ec["kernel_shapes"]
+    assert isinstance(shapes, int) and 1 <= shapes <= len(device._kernel_shapes)
+    assert all(len(key) == 4 for key in device._kernel_shapes)
+    assert (4, 10) in {key[:2] for key in device._kernel_shapes}
+    assert 1 <= ec["matrices"] <= len(device.matrices)
+    assert (gf256.parity_rows(10, 4).tobytes(), "xla", 0) in device.matrices

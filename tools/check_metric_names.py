@@ -81,6 +81,8 @@ def collect() -> tuple[dict[str, str], list[str]]:
     trace.door_calls_counter()  # SeaweedFS_volume_ec_door_calls_total
     trace.read_interval_bytes_counter()  # ..._ec_read_interval_bytes_total
     trace.reconstructs_counter()  # SeaweedFS_volume_ec_reconstructs_total
+    trace.door_matrix_puts_counter()  # ..._ec_door_matrix_puts_total
+    trace.degraded_needle_reads_counter()  # ..._ec_degraded_needle_reads_total
     trace.pipeline_buffers_counter()  # ..._ec_pipeline_buffers_total
     trace.pipeline_cpu_counter()  # ..._ec_pipeline_cpu_seconds_total
     ec_encoder._pipeline_hist()  # SeaweedFS_volume_ec_pipeline_seconds
